@@ -59,6 +59,9 @@ def main() -> None:
         help="write a Chrome trace (one span per bench module; Perfetto)",
     )
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import (
         bench_analysis,

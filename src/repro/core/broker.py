@@ -866,6 +866,15 @@ class DataBroker:
     def invalidate_snapshot(self) -> None:
         self._snap_state = None
 
+    def warm_snapshot(self, endpoints: Sequence[str]) -> Any:
+        """Build (or reuse) the batched-selection snapshot over
+        ``endpoints`` before traffic arrives — a server's start-up step,
+        so that the first flushes neither rebuild the snapshot nor change
+        its padded shape (which would recompile the kernel). Returns the
+        snapshot."""
+        with self.tracer.span("broker.snapshot", endpoints=len(endpoints)):
+            return self._snapshot_state(endpoints).snapshot
+
     def select_many(
         self,
         queries: Sequence[Tuple[str, Optional[ClassAd]]],

@@ -7,89 +7,91 @@ min/max/mean/std/last/EWMA after each batch of observations. That is a
 single HBM pass: ``4·N·W`` bytes in, ``6·N·4`` bytes out — memory-bound,
 so the kernel fuses all six statistics into one read of the history tile.
 
-Layout: the series axis N is tiled by the grid (BLOCK_N sublane-aligned);
-the window W is the lane axis (padded to 128). The EWMA is evaluated as a
-dot with decay weights computed in-register from the lane index — a VPU
-expression, not a sequential scan (state-space-style recurrences lowered
-to exponent arithmetic, the same trick our SSD layer uses at model scale).
+Layout: the window W is the sublane axis (padded to 128) and the series
+axis N the lane axis, tiled by the grid (BLOCK_N a multiple of 128), so
+every statistic is a lane-dense ``[1, BLOCK_N]`` row of one ``[8, N]``
+output. The EWMA is evaluated as a dot with decay weights computed
+in-register from the window index — a VPU expression, not a sequential
+scan (state-space-style recurrences lowered to exponent arithmetic, the
+same trick our SSD layer uses at model scale).
+
+Interpret mode is chosen by :func:`repro.kernels.resolve_interpret`.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 BIG = 3.4e38
+#: output rows: min, max, mean, std, last, ewma (+2 unused, one sublane tile)
+_STAT_ROWS = 8
 
 
 def _bwstats_kernel(
-    hist_ref,  # [BLOCK_N, W_PAD] f32
-    counts_ref,  # [BLOCK_N] i32
-    mn_ref, mx_ref, mean_ref, std_ref, last_ref, ewma_ref,  # [BLOCK_N] f32 each
+    hist_ref,  # [W_PAD, BLOCK_N] f32 — one series per lane
+    counts_ref,  # [1, BLOCK_N] i32
+    out_ref,  # [8, BLOCK_N] f32 — one statistic per row
     *,
-    w_pad: int,
     alpha: float,
 ):
     hist = hist_ref[...]
-    cnt = counts_ref[...][:, None]  # [B, 1] i32
-    lane = jax.lax.broadcasted_iota(jnp.int32, hist.shape, 1)  # [B, W]
-    m = lane < cnt
-    cntf = jnp.maximum(cnt.astype(jnp.float32), 1.0)[:, 0]
+    cnt = counts_ref[...]  # [1, N] i32
+    pos = jax.lax.broadcasted_iota(jnp.int32, hist.shape, 0)  # [W, N]
+    m = pos < cnt
+    cntf = jnp.maximum(cnt.astype(jnp.float32), 1.0)
 
-    mn = jnp.min(jnp.where(m, hist, BIG), axis=1)
-    mx = jnp.max(jnp.where(m, hist, -BIG), axis=1)
-    s1 = jnp.sum(jnp.where(m, hist, 0.0), axis=1)
+    mn = jnp.min(jnp.where(m, hist, BIG), axis=0, keepdims=True)
+    mx = jnp.max(jnp.where(m, hist, -BIG), axis=0, keepdims=True)
+    s1 = jnp.sum(jnp.where(m, hist, 0.0), axis=0, keepdims=True)
     mean = s1 / cntf
     # two-pass variance: E[x²]−E[x]² cancels catastrophically in f32 for
     # bandwidth-scale values (~1e9); the tile is already in VMEM so the
     # second pass is free
-    d = jnp.where(m, hist - mean[:, None], 0.0)
-    var = jnp.sum(d * d, axis=1) / cntf
+    d = jnp.where(m, hist - mean, 0.0)
+    var = jnp.sum(d * d, axis=0, keepdims=True) / cntf
     std = jnp.sqrt(var)
-    last = jnp.sum(jnp.where(lane == cnt - 1, hist, 0.0), axis=1)
+    last = jnp.sum(jnp.where(pos == cnt - 1, hist, 0.0), axis=0, keepdims=True)
 
-    expo = jnp.maximum((cnt - 1 - lane).astype(jnp.float32), 0.0)
+    expo = jnp.maximum((cnt - 1 - pos).astype(jnp.float32), 0.0)
     decay = jnp.power(jnp.float32(1.0 - alpha), expo)  # exact at alpha=1
-    wgt = jnp.where(lane == 0, decay, jnp.float32(alpha) * decay)
-    ewma = jnp.sum(jnp.where(m, hist * wgt, 0.0), axis=1)
+    wgt = jnp.where(pos == 0, decay, jnp.float32(alpha) * decay)
+    ewma = jnp.sum(jnp.where(m, hist * wgt, 0.0), axis=0, keepdims=True)
 
-    empty = counts_ref[...] <= 0
-    z = jnp.float32(0.0)
-    mn_ref[...] = jnp.where(empty, z, mn)
-    mx_ref[...] = jnp.where(empty, z, mx)
-    mean_ref[...] = jnp.where(empty, z, mean)
-    std_ref[...] = jnp.where(empty, z, std)
-    last_ref[...] = jnp.where(empty, z, last)
-    ewma_ref[...] = jnp.where(empty, z, ewma)
+    live = cnt > 0
+    for row, stat in enumerate((mn, mx, mean, std, last, ewma)):
+        out_ref[row : row + 1, :] = jnp.where(live, stat, 0.0)
 
 
 def bwstats_pallas(
-    hist: jnp.ndarray,  # [N, W_PAD] f32 (N % block_n == 0, W_PAD % 128 == 0)
+    hist_t: jnp.ndarray,  # [W_PAD, N] f32 (W_PAD % 128 == 0, N % block_n == 0)
     counts: jnp.ndarray,  # [N] i32
     *,
     alpha: float = 0.25,
     block_n: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, ...]:
-    n, w_pad = hist.shape
-    assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
-    kernel = functools.partial(_bwstats_kernel, w_pad=w_pad, alpha=alpha)
-    out_shape = tuple(jax.ShapeDtypeStruct((n,), jnp.float32) for _ in range(6))
-    in_specs = [
-        pl.BlockSpec((block_n, w_pad), lambda i: (i, 0)),
-        pl.BlockSpec((block_n,), lambda i: (i,)),
-    ]
-    out_specs = tuple(pl.BlockSpec((block_n,), lambda i: (i,)) for _ in range(6))
-    return pl.pallas_call(
+    """Six [N] statistics (min, max, mean, std, last, EWMA) of N series
+    held window-major (``hist_t[w, n]`` is observation w of series n).
+    ``interpret=None`` resolves from the platform."""
+    w_pad, n = hist_t.shape
+    assert n % block_n == 0 and block_n % 128 == 0, (n, block_n)
+    kernel = functools.partial(_bwstats_kernel, alpha=alpha)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(hist, counts)
+        grid=(n // block_n,),
+        in_specs=[
+            pl.BlockSpec((w_pad, block_n), lambda i: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((_STAT_ROWS, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((_STAT_ROWS, n), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(hist_t, jnp.reshape(counts, (1, n)))
+    return tuple(out[row] for row in range(6))

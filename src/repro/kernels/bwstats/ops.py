@@ -2,7 +2,8 @@
 
 ``bwstats`` takes the raw ``TransferMonitor.history_matrix`` output
 (arbitrary N, W) and returns the six statistics trimmed to N, as either
-the Pallas kernel (default) or the jnp reference. ``publish_fleet_stats``
+the Pallas kernel (default; interpret mode resolved from the platform)
+or the jnp reference. ``publish_fleet_stats``
 maps the result back onto GRIS attribute names — the fleet-scale version
 of ``TransferMonitor.summary_attrs``.
 """
@@ -29,12 +30,12 @@ def _round_up(x: int, m: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "block_n", "use_kernel", "interpret"))
-def _dispatch(hist, counts, *, alpha, block_n, use_kernel, interpret):
+def _dispatch(hist_t, counts, *, alpha, block_n, use_kernel, interpret):
     if use_kernel:
         return bwstats_pallas(
-            hist, counts, alpha=alpha, block_n=block_n, interpret=interpret
+            hist_t, counts, alpha=alpha, block_n=block_n, interpret=interpret
         )
-    return bwstats_ref(hist, counts, alpha=alpha)
+    return bwstats_ref(hist_t.T, counts, alpha=alpha)
 
 
 def bwstats(
@@ -44,7 +45,7 @@ def bwstats(
     alpha: float = 0.25,
     block_n: int = 256,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Dict[str, np.ndarray]:
     """→ {'min','max','mean','std','last','ewma'} each [N] f32."""
     n, w = hist.shape
@@ -52,12 +53,12 @@ def bwstats(
         return {k: np.zeros((0,), np.float32) for k in STAT_NAMES}
     n_pad = max(_round_up(n, block_n), block_n)
     w_pad = max(_round_up(w, 128), 128)
-    hist_p = np.zeros((n_pad, w_pad), dtype=np.float32)
-    hist_p[:n, :w] = hist
+    hist_t = np.zeros((w_pad, n_pad), dtype=np.float32)  # window-major
+    hist_t[:w, :n] = np.asarray(hist).T
     counts_p = np.zeros((n_pad,), dtype=np.int32)
     counts_p[:n] = counts
     outs = _dispatch(
-        jnp.asarray(hist_p), jnp.asarray(counts_p),
+        jnp.asarray(hist_t), jnp.asarray(counts_p),
         alpha=alpha, block_n=block_n, use_kernel=use_kernel, interpret=interpret,
     )
     return {k: np.asarray(v)[:n] for k, v in zip(STAT_NAMES, outs)}

@@ -1,276 +1,164 @@
-"""Pallas TPU kernel: fused matchmaking (mask + rank + running top-1).
+"""Pallas TPU kernel: fused matchmaking (mask + rank + running top-k).
 
 TPU adaptation of the Match Phase hot loop. Design notes:
 
-  * The candidate axis S is tiled by the grid; each step processes a
-    ``(BLOCK_S, A_PAD)`` attribute tile resident in VMEM. A_PAD is lane-
-    aligned (128); BLOCK_S is sublane-aligned (multiple of 8).
-  * Per-term attribute *gathers* are re-expressed as a one-hot matmul
-    ``attrs @ sel.T`` — the MXU eats a [BLOCK_S,128]×[128,T_PAD] matmul;
-    a lane gather would serialize on the VPU.
+  * The grid is ``(B // REQ_BLOCK, S // block_s)``: each step scores
+    ``REQ_BLOCK`` (= 8, one f32 sublane tile) requests against one
+    ``(block_s, A_PAD)`` candidate tile resident in VMEM, so the shared
+    attribute table streams once per 8 requests. A_PAD is lane-aligned
+    (128); block_s is a multiple of 128.
+  * Results are laid out candidate-major on lanes: every per-request row
+    (admit, mask, score) is a lane-dense ``[8, block_s]`` tile.
+  * Per-term attribute *gathers* are a one-hot matmul
+    ``sel · attrsᵀ`` on the MXU (``[8·T_PAD, A_PAD] × [A_PAD, block_s]``)
+    at full f32 precision, so selected values are exact; a lane gather
+    would serialize on the VPU. The per-term plan rides in columns
+    (``[8·T_PAD, 1]``) that broadcast along the candidate lanes.
   * All six comparison ops are evaluated vectorized and the per-term op
-    is chosen with ``jnp.where`` chains — branch-free VPU code.
-  * The running top-1 (score, index) is carried across grid steps in SMEM
-    scratch; the final step publishes it. This makes the kernel a single
-    pass over HBM: matchmaking is memory-bound (≈4·S·A bytes in, S out),
-    so one fused pass is the roofline-optimal schedule.
+    is chosen by boolean masks — branch-free VPU code.
+  * The per-request top-k is carried across S-blocks in the resident
+    top-k output tile ``[8, K_PAD]``; each of the k rounds is a masked
+    ``max`` (best score) then a masked ``min`` (lowest global row among
+    the ties), so the kernel needs no dynamic indexing and keeps
+    ``lax.top_k``'s tie-break exactly. One pass over HBM per 8 requests.
 
-Weights/thresholds/opcodes ride in VMEM as small aligned arrays; the
-kernel is correctness-validated in ``interpret=True`` mode on CPU and
-shape/dtype-swept against :mod:`.ref` (see tests/test_kernel_matchrank.py).
+Interpret mode is chosen by :func:`repro.kernels.resolve_interpret`: the
+kernels compile through Mosaic on a TPU and run in the Pallas interpreter
+on the CPU backend, where they are swept against :mod:`.ref` (see
+tests/test_kernel_matchrank.py); tests/test_tpu_compile.py compiles them
+for a TPU v5e ahead of time.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = float("-inf")
 
-__all__ = ["matchrank_pallas", "matchrank_batched_pallas"]
+#: requests per grid step (one f32 sublane tile)
+REQ_BLOCK = 8
+#: lane width of the top-k carry/output tile (bounds k)
+K_PAD = 128
+#: index held by carry slots no candidate has filled yet (above every row)
+_NO_ROW = 2**31 - 1
+
+__all__ = ["REQ_BLOCK", "K_PAD", "matchrank_pallas", "matchrank_batched_pallas"]
 
 
-def _matchrank_kernel(
-    # inputs (VMEM tiles)
-    attrs_ref,  # [BLOCK_S, A_PAD] f32
-    valid_ref,  # [BLOCK_S, A_PAD] f32
-    admit_ref,  # [BLOCK_S] f32
-    sel_ref,  # [T_PAD, A_PAD] f32
-    ops_ref,  # [T_PAD] i32
-    th_ref,  # [T_PAD] f32
-    act_ref,  # [T_PAD] f32
-    w_ref,  # [A_PAD] f32
-    bias_ref,  # [1] f32
-    # outputs
-    mask_ref,  # [BLOCK_S] f32
-    score_ref,  # [BLOCK_S] f32
-    best_score_ref,  # [1] f32
-    best_idx_ref,  # [1] i32
-    # scratch (SMEM carries across grid steps)
-    carry_score_ref,  # [1] f32
-    carry_idx_ref,  # [1] i32
-    *,
-    block_s: int,
-):
-    pi = pl.program_id(0)
-    nblocks = pl.num_programs(0)
-
-    attrs = attrs_ref[...]
-    validf = valid_ref[...]
-
-    # ---- per-term values: one-hot matmul instead of a lane gather ----
-    sel_t = sel_ref[...].T  # [A_PAD, T_PAD]
-    vals = jnp.dot(attrs, sel_t, preferred_element_type=jnp.float32)  # [S, T]
-    vok = jnp.dot(validf, sel_t, preferred_element_type=jnp.float32) > 0.5
-
-    th = th_ref[...][None, :]
-    opc = ops_ref[...][None, :]
-    # branch-free op select
-    r = jnp.where(opc == 0, vals < th, False)
-    r = jnp.where(opc == 1, vals <= th, r)
-    r = jnp.where(opc == 2, vals > th, r)
-    r = jnp.where(opc == 3, vals >= th, r)
-    r = jnp.where(opc == 4, vals == th, r)
-    r = jnp.where(opc == 5, vals != th, r)
-
-    act = act_ref[...][None, :] > 0.5
-    term_pass = jnp.where(act, jnp.logical_and(r, vok), True)
-    mask = jnp.all(term_pass, axis=-1)  # [S]
-    mask = jnp.logical_and(mask, admit_ref[...] > 0.5)
-
-    # ---- linear rank with validity gating ----
-    w = w_ref[...]
-    score_raw = jnp.dot(attrs, w, preferred_element_type=jnp.float32) + bias_ref[0]
-    wactive = (jnp.abs(w) > 0).astype(jnp.float32)
-    bad = jnp.dot(1.0 - validf, wactive, preferred_element_type=jnp.float32)
-    rank = jnp.where(bad > 0, 0.0, score_raw)
-
-    score = jnp.where(mask, rank, NEG_INF)
-    mask_ref[...] = mask.astype(jnp.float32)
-    score_ref[...] = score
-
-    # ---- running top-1 across grid steps (SMEM carry) ----
-    local_idx = jnp.argmax(score)
-    local_best = score[local_idx]
-    global_idx = (pi * block_s + local_idx).astype(jnp.int32)
-
-    @pl.when(pi == 0)
-    def _init():
-        carry_score_ref[0] = NEG_INF
-        carry_idx_ref[0] = jnp.int32(0)
-
-    prev_score = carry_score_ref[0]
-    prev_idx = carry_idx_ref[0]
-    take_new = local_best > prev_score  # strict: ties keep earliest index
-    carry_score_ref[0] = jnp.where(take_new, local_best, prev_score)
-    carry_idx_ref[0] = jnp.where(take_new, global_idx, prev_idx)
-
-    @pl.when(pi == nblocks - 1)
-    def _publish():
-        best_score_ref[0] = carry_score_ref[0]
-        best_idx_ref[0] = carry_idx_ref[0]
-
-
-def matchrank_pallas(
-    attrs: jnp.ndarray,  # [S, A_PAD] f32 (S % block_s == 0, A_PAD % 128 == 0)
-    valid: jnp.ndarray,  # [S, A_PAD] f32
-    admit: jnp.ndarray,  # [S] f32
-    sel: jnp.ndarray,  # [T_PAD, A_PAD] f32
-    op_codes: jnp.ndarray,  # [T_PAD] i32
-    thresholds: jnp.ndarray,  # [T_PAD] f32
-    term_active: jnp.ndarray,  # [T_PAD] f32
-    weights: jnp.ndarray,  # [A_PAD] f32
-    bias: jnp.ndarray,  # [1] f32
-    *,
-    block_s: int = 512,
-    interpret: bool = True,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Invoke the fused kernel. Inputs must be pre-padded (ops.py does it)."""
-    s, a_pad = attrs.shape
-    t_pad = sel.shape[0]
-    assert s % block_s == 0, (s, block_s)
-    nblocks = s // block_s
-
-    kernel = functools.partial(_matchrank_kernel, block_s=block_s)
-    grid = (nblocks,)
-
-    out_shapes = (
-        jax.ShapeDtypeStruct((s,), jnp.float32),  # mask
-        jax.ShapeDtypeStruct((s,), jnp.float32),  # score
-        jax.ShapeDtypeStruct((1,), jnp.float32),  # best score
-        jax.ShapeDtypeStruct((1,), jnp.int32),  # best idx
+def _dot_t(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``x · yᵀ`` ([M, A] × [N, A] → [M, N]) at full f32 precision."""
+    return jax.lax.dot_general(
+        x,
+        y,
+        (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    in_specs = [
-        pl.BlockSpec((block_s, a_pad), lambda i: (i, 0)),  # attrs
-        pl.BlockSpec((block_s, a_pad), lambda i: (i, 0)),  # valid
-        pl.BlockSpec((block_s,), lambda i: (i,)),  # admit
-        pl.BlockSpec((t_pad, a_pad), lambda i: (0, 0)),  # sel (replicated)
-        pl.BlockSpec((t_pad,), lambda i: (0,)),  # ops
-        pl.BlockSpec((t_pad,), lambda i: (0,)),  # thresholds
-        pl.BlockSpec((t_pad,), lambda i: (0,)),  # active
-        pl.BlockSpec((a_pad,), lambda i: (0,)),  # weights
-        pl.BlockSpec((1,), lambda i: (0,)),  # bias
-    ]
-    out_specs = (
-        pl.BlockSpec((block_s,), lambda i: (i,)),
-        pl.BlockSpec((block_s,), lambda i: (i,)),
-        pl.BlockSpec((1,), lambda i: (0,)),
-        pl.BlockSpec((1,), lambda i: (0,)),
-    )
-    # SMEM scratch for the cross-block top-1 carry
-    from jax.experimental.pallas import tpu as pltpu
-
-    scratch_shapes = [
-        pltpu.SMEM((1,), jnp.float32),
-        pltpu.SMEM((1,), jnp.int32),
-    ]
-
-    mask, score, best_s, best_i = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        scratch_shapes=scratch_shapes,
-        interpret=interpret,
-    )(attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias)
-    return mask > 0.5, score, best_s, best_i
 
 
 def _matchrank_batched_kernel(
     # inputs (VMEM tiles)
     attrs_ref,  # [BLOCK_S, A_PAD] f32 (shared across the batch)
     valid_ref,  # [BLOCK_S, A_PAD] f32
-    admit_ref,  # [1, BLOCK_S] f32 (request b's pre-mask slice)
-    sel_ref,  # [1, T_PAD, A_PAD] f32
-    ops_ref,  # [1, T_PAD] i32
-    th_ref,  # [1, T_PAD] f32
-    act_ref,  # [1, T_PAD] f32
-    w_ref,  # [1, A_PAD] f32
-    bias_ref,  # [1] f32
+    admit_ref,  # [R, BLOCK_S] f32 — the block's pre-masks
+    sel_ref,  # [R·T_PAD, A_PAD] f32 — request-major one-hot term rows
+    ops_ref,  # [R·T_PAD, 1] i32
+    th_ref,  # [R·T_PAD, 1] f32
+    act_ref,  # [R·T_PAD, 1] f32
+    w_ref,  # [R, A_PAD] f32
+    bias_ref,  # [R, 1] f32
     # outputs
-    mask_ref,  # [1, BLOCK_S] f32
-    score_ref,  # [1, BLOCK_S] f32
-    topk_score_ref,  # [1, K] f32
-    topk_idx_ref,  # [1, K] i32
-    # scratch (SMEM carries across the S-block grid steps of request b)
-    carry_score_ref,  # [K] f32
-    carry_idx_ref,  # [K] i32
+    mask_ref,  # [R, BLOCK_S] f32
+    score_ref,  # [R, BLOCK_S] f32
+    topk_s_ref,  # [R, K_PAD] f32 — resident across S-blocks (the carry)
+    topk_i_ref,  # [R, K_PAD] i32
     *,
     block_s: int,
+    t_pad: int,
     k: int,
 ):
-    si = pl.program_id(1)  # S-block index (innermost: sequential per request)
-    nblocks = pl.num_programs(1)
+    si = pl.program_id(1)  # S-block index (innermost: sequential per block)
+    r = REQ_BLOCK
 
     attrs = attrs_ref[...]
     validf = valid_ref[...]
 
-    # ---- per-term values for THIS request: one-hot matmul on the MXU ----
-    sel_t = sel_ref[0].T  # [A_PAD, T_PAD]
-    vals = jnp.dot(attrs, sel_t, preferred_element_type=jnp.float32)  # [S, T]
-    vok = jnp.dot(validf, sel_t, preferred_element_type=jnp.float32) > 0.5
+    # ---- per-term values: one-hot matmul on the MXU, candidates on lanes ----
+    sel = sel_ref[...]
+    vals = _dot_t(sel, attrs)  # [R·T, S]
+    vok = _dot_t(sel, validf) > 0.5
 
-    th = th_ref[0][None, :]
-    opc = ops_ref[0][None, :]
-    r = jnp.where(opc == 0, vals < th, False)
-    r = jnp.where(opc == 1, vals <= th, r)
-    r = jnp.where(opc == 2, vals > th, r)
-    r = jnp.where(opc == 3, vals >= th, r)
-    r = jnp.where(opc == 4, vals == th, r)
-    r = jnp.where(opc == 5, vals != th, r)
-
-    act = act_ref[0][None, :] > 0.5
-    term_pass = jnp.where(act, jnp.logical_and(r, vok), True)
-    mask = jnp.all(term_pass, axis=-1)  # [S]
-    mask = jnp.logical_and(mask, admit_ref[0] > 0.5)
+    th = th_ref[...]
+    opc = jnp.broadcast_to(ops_ref[...], vals.shape)
+    cmp = (
+        ((opc == 0) & (vals < th))
+        | ((opc == 1) & (vals <= th))
+        | ((opc == 2) & (vals > th))
+        | ((opc == 3) & (vals >= th))
+        | ((opc == 4) & (vals == th))
+        | ((opc == 5) & (vals != th))
+    )
+    act = jnp.broadcast_to(act_ref[...], vals.shape) > 0.5
+    term_ok = jnp.logical_not(act) | (cmp & vok)
+    # a request matches when all of its T_PAD term rows pass
+    passed = term_ok.astype(jnp.float32).reshape(r, t_pad, block_s)
+    mask = jnp.logical_and(jnp.min(passed, axis=1) > 0.5, admit_ref[...] > 0.5)
 
     # ---- linear rank with validity gating ----
-    w = w_ref[0]
-    score_raw = jnp.dot(attrs, w, preferred_element_type=jnp.float32) + bias_ref[0]
+    w = w_ref[...]
+    score_raw = _dot_t(w, attrs) + bias_ref[...]  # [R, S]
     wactive = (jnp.abs(w) > 0).astype(jnp.float32)
-    bad = jnp.dot(1.0 - validf, wactive, preferred_element_type=jnp.float32)
-    rank = jnp.where(bad > 0, 0.0, score_raw)
+    n_bad = _dot_t(wactive, 1.0 - validf)  # invalid weighted attrs per row
+    rank = jnp.where(n_bad > 0, 0.0, score_raw)
 
     score = jnp.where(mask, rank, NEG_INF)
-    mask_ref[0, :] = mask.astype(jnp.float32)
-    score_ref[0, :] = score
+    mask_ref[...] = mask.astype(jnp.float32)
+    score_ref[...] = score
 
     # ---- fused per-request top-k carry across S-blocks ----
-    # The carry holds the best k (score, global index) seen so far for
-    # request b, sorted descending. Merge = k knockout-argmax rounds over
-    # [carry ++ this block]; carry entries come first, so on score ties the
-    # earlier block (lower global index) wins — interpreter tiebreak.
+    # The carry holds each request's best k (score, global row) so far.
+    # Each round takes the best remaining score over [carry ++ block] and,
+    # among equal scores, the lowest global row — lax.top_k's tie-break
+    # (-inf included), without dynamic indexing. Rows are unique, so the
+    # winner is knocked out of both sides by its row alone.
     @pl.when(si == 0)
     def _init():
-        for j in range(k):
-            carry_score_ref[j] = NEG_INF
-            carry_idx_ref[j] = jnp.int32(0)
+        topk_s_ref[...] = jnp.full((r, K_PAD), NEG_INF, jnp.float32)
+        topk_i_ref[...] = jnp.full((r, K_PAD), _NO_ROW, jnp.int32)
 
-    global_idx = (si * block_s + jnp.arange(block_s)).astype(jnp.int32)
-    ext_scores = jnp.concatenate([carry_score_ref[...], score])
-    ext_idx = jnp.concatenate([carry_idx_ref[...], global_idx])
-    positions = jnp.arange(k + block_s)
-    new_scores = []
-    new_idx = []
-    for _ in range(k):
-        j = jnp.argmax(ext_scores)  # first max ⇒ lowest index on ties
-        new_scores.append(ext_scores[j])
-        new_idx.append(ext_idx[j])
-        ext_scores = jnp.where(positions == j, NEG_INF, ext_scores)
+    row = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (r, block_s), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (r, K_PAD), 1)
+    carry_s = topk_s_ref[...]
+    carry_i = topk_i_ref[...]
+    carry_live = slot < k
+    block_live = jnp.ones((r, block_s), dtype=jnp.bool_)
+    new_s = jnp.full((r, K_PAD), NEG_INF, jnp.float32)
+    new_i = jnp.full((r, K_PAD), _NO_ROW, jnp.int32)
     for j in range(k):
-        carry_score_ref[j] = new_scores[j]
-        carry_idx_ref[j] = new_idx[j]
-
-    @pl.when(si == nblocks - 1)
-    def _publish():
-        for j in range(k):
-            topk_score_ref[0, j] = carry_score_ref[j]
-            topk_idx_ref[0, j] = carry_idx_ref[j]
+        best = jnp.maximum(
+            jnp.max(jnp.where(carry_live, carry_s, NEG_INF), axis=1, keepdims=True),
+            jnp.max(jnp.where(block_live, score, NEG_INF), axis=1, keepdims=True),
+        )
+        take_c = jnp.logical_and(carry_live, carry_s == best)
+        take_b = jnp.logical_and(block_live, score == best)
+        pick = jnp.minimum(
+            jnp.min(jnp.where(take_c, carry_i, _NO_ROW), axis=1, keepdims=True),
+            jnp.min(jnp.where(take_b, row, _NO_ROW), axis=1, keepdims=True),
+        )
+        new_s = jnp.where(slot == j, best, new_s)
+        new_i = jnp.where(slot == j, pick, new_i)
+        carry_live = jnp.logical_and(carry_live, carry_i != pick)
+        block_live = jnp.logical_and(block_live, row != pick)
+    topk_s_ref[...] = new_s
+    topk_i_ref[...] = new_i
 
 
 def matchrank_batched_pallas(
@@ -286,66 +174,108 @@ def matchrank_batched_pallas(
     *,
     block_s: int = 512,
     k: int = 1,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Multi-request fused match+rank+top-k over ONE candidate block.
 
-    Grid is ``(B, S//block_s)`` with the candidate axis innermost, so the
-    shared ``attrs``/``valid`` tiles stream once per request while each
-    request's small plan tensors stay resident. The per-request top-k is
-    carried across S-blocks in SMEM and published on the last block —
-    still a single pass over HBM per request.
+    The request axis is padded to a multiple of ``REQ_BLOCK`` with
+    requests that admit nothing; the grid is ``(B_pad // 8, S // block_s)``
+    with the candidate axis innermost, so each block of 8 requests keeps
+    its plan tiles resident while the shared ``attrs``/``valid`` tiles
+    stream past once. ``interpret=None`` resolves from the platform
+    (:func:`repro.kernels.resolve_interpret`).
 
     Returns (mask [B,S] bool, score [B,S] f32, topk_scores [B,k] f32,
-    topk_idx [B,k] i32).
+    topk_idx [B,k] i32); top-k slots past a request's match count hold
+    -inf with the lowest unmatched rows, as ``lax.top_k`` gives them.
     """
     s, a_pad = attrs.shape
     b, t_pad, a_pad2 = sel.shape
     assert a_pad == a_pad2, (a_pad, a_pad2)
-    assert s % block_s == 0, (s, block_s)
+    assert s % block_s == 0 and block_s % 128 == 0, (s, block_s)
     assert admit.shape == (b, s), (admit.shape, b, s)
-    nblocks = s // block_s
+    assert t_pad % 8 == 0, t_pad
+    assert 1 <= k <= min(K_PAD, block_s), (k, block_s)
+    r = REQ_BLOCK
+    b_pad = -(-b // r) * r
 
-    kernel = functools.partial(_matchrank_batched_kernel, block_s=block_s, k=k)
-    grid = (b, nblocks)
+    def rows(x):  # pad the request axis with zeros (requests admitting nothing)
+        return jnp.pad(x, [(0, b_pad - b)] + [(0, 0)] * (x.ndim - 1))
 
-    out_shapes = (
-        jax.ShapeDtypeStruct((b, s), jnp.float32),  # mask
-        jax.ShapeDtypeStruct((b, s), jnp.float32),  # score
-        jax.ShapeDtypeStruct((b, k), jnp.float32),  # top-k scores
-        jax.ShapeDtypeStruct((b, k), jnp.int32),  # top-k indices
+    # the per-term plan as [B_pad·T_PAD, ·] rows: request-major term rows
+    sel_rows = rows(sel.astype(jnp.float32)).reshape(b_pad * t_pad, a_pad)
+    ops_col = rows(op_codes.astype(jnp.int32)).reshape(b_pad * t_pad, 1)
+    th_col = rows(thresholds.astype(jnp.float32)).reshape(b_pad * t_pad, 1)
+    act_col = rows(term_active.astype(jnp.float32)).reshape(b_pad * t_pad, 1)
+    w_rows = rows(weights.astype(jnp.float32))
+    bias_col = rows(bias.astype(jnp.float32)).reshape(b_pad, 1)
+    admit_rows = rows(admit.astype(jnp.float32))
+
+    kernel = functools.partial(
+        _matchrank_batched_kernel, block_s=block_s, t_pad=t_pad, k=k
     )
+    rt = r * t_pad
     in_specs = [
         pl.BlockSpec((block_s, a_pad), lambda bi, si: (si, 0)),  # attrs (shared)
         pl.BlockSpec((block_s, a_pad), lambda bi, si: (si, 0)),  # valid (shared)
-        pl.BlockSpec((1, block_s), lambda bi, si: (bi, si)),  # admit
-        pl.BlockSpec((1, t_pad, a_pad), lambda bi, si: (bi, 0, 0)),  # sel
-        pl.BlockSpec((1, t_pad), lambda bi, si: (bi, 0)),  # ops
-        pl.BlockSpec((1, t_pad), lambda bi, si: (bi, 0)),  # thresholds
-        pl.BlockSpec((1, t_pad), lambda bi, si: (bi, 0)),  # active
-        pl.BlockSpec((1, a_pad), lambda bi, si: (bi, 0)),  # weights
-        pl.BlockSpec((1,), lambda bi, si: (bi,)),  # bias
+        pl.BlockSpec((r, block_s), lambda bi, si: (bi, si)),  # admit
+        pl.BlockSpec((rt, a_pad), lambda bi, si: (bi, 0)),  # sel
+        pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # ops
+        pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # thresholds
+        pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # active
+        pl.BlockSpec((r, a_pad), lambda bi, si: (bi, 0)),  # weights
+        pl.BlockSpec((r, 1), lambda bi, si: (bi, 0)),  # bias
     ]
     out_specs = (
-        pl.BlockSpec((1, block_s), lambda bi, si: (bi, si)),
-        pl.BlockSpec((1, block_s), lambda bi, si: (bi, si)),
-        pl.BlockSpec((1, k), lambda bi, si: (bi, 0)),
-        pl.BlockSpec((1, k), lambda bi, si: (bi, 0)),
+        pl.BlockSpec((r, block_s), lambda bi, si: (bi, si)),  # mask
+        pl.BlockSpec((r, block_s), lambda bi, si: (bi, si)),  # score
+        pl.BlockSpec((r, K_PAD), lambda bi, si: (bi, 0)),  # top-k scores
+        pl.BlockSpec((r, K_PAD), lambda bi, si: (bi, 0)),  # top-k rows
     )
-    from jax.experimental.pallas import tpu as pltpu
-
-    scratch_shapes = [
-        pltpu.SMEM((k,), jnp.float32),
-        pltpu.SMEM((k,), jnp.int32),
-    ]
-
+    out_shapes = (
+        jax.ShapeDtypeStruct((b_pad, s), jnp.float32),
+        jax.ShapeDtypeStruct((b_pad, s), jnp.float32),
+        jax.ShapeDtypeStruct((b_pad, K_PAD), jnp.float32),
+        jax.ShapeDtypeStruct((b_pad, K_PAD), jnp.int32),
+    )
     mask, score, topk_s, topk_i = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b_pad // r, s // block_s),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        scratch_shapes=scratch_shapes,
-        interpret=interpret,
-    )(attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias)
-    return mask > 0.5, score, topk_s, topk_i
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=resolve_interpret(interpret),
+    )(
+        attrs, valid, admit_rows, sel_rows, ops_col, th_col, act_col, w_rows,
+        bias_col,
+    )
+    return mask[:b] > 0.5, score[:b], topk_s[:b, :k], topk_i[:b, :k]
+
+
+def matchrank_pallas(
+    attrs: jnp.ndarray,  # [S, A_PAD] f32 (S % block_s == 0, A_PAD % 128 == 0)
+    valid: jnp.ndarray,  # [S, A_PAD] f32
+    admit: jnp.ndarray,  # [S] f32
+    sel: jnp.ndarray,  # [T_PAD, A_PAD] f32
+    op_codes: jnp.ndarray,  # [T_PAD] i32
+    thresholds: jnp.ndarray,  # [T_PAD] f32
+    term_active: jnp.ndarray,  # [T_PAD] f32
+    weights: jnp.ndarray,  # [A_PAD] f32
+    bias: jnp.ndarray,  # [1] f32
+    *,
+    block_s: int = 512,
+    interpret: Optional[bool] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One request through the batched kernel (a batch of one). Returns
+    (mask [S] bool, score [S] f32, best_score [1] f32, best_idx [1] i32);
+    with no match, best is (-inf, 0). Inputs must be pre-padded (ops.py
+    does it)."""
+    mask, score, best_s, best_i = matchrank_batched_pallas(
+        attrs, valid, admit[None], sel[None], op_codes[None], thresholds[None],
+        term_active[None], weights[None], jnp.reshape(bias, (1,)),
+        block_s=block_s, k=1, interpret=interpret,
+    )
+    return mask[0], score[0], best_s[0], best_i[0]

@@ -5,7 +5,9 @@
 request into kernel operands via the conjunctive-threshold / linear-rank
 extractors of :mod:`repro.core.compile` — the bridge from the paper's
 language to the TPU hot loop. ``matchrank_topk`` composes the fused scores
-with ``lax.top_k`` for k > 1.
+with ``lax.top_k`` for k > 1. Every wrapper's ``interpret=None`` resolves
+from the platform (:func:`repro.kernels.resolve_interpret`): the kernels
+compile on a TPU and run in the Pallas interpreter on the CPU backend.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ __all__ = [
     "matchrank_topk",
     "matchrank_batched",
     "matchrank_batched_topk",
+    "lower_matchrank_batched",
     "pad_columns",
 ]
 
@@ -219,7 +222,7 @@ def pad_columns(
 )
 def _dispatch(
     attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
-    *, block_s: int, use_kernel: bool, interpret: bool,
+    *, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     if use_kernel:
         return matchrank_pallas(
@@ -236,7 +239,7 @@ def _dispatch(
 )
 def _dispatch_topk(
     attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
-    *, k: int, block_s: int, use_kernel: bool, interpret: bool,
+    *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     """Fused scores + top-k in one jitted program — no host round-trip."""
     mask, score, _, _ = _dispatch(
@@ -252,7 +255,7 @@ def _dispatch_topk(
 )
 def _dispatch_batched(
     attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
-    *, k: int, block_s: int, use_kernel: bool, interpret: bool,
+    *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     if use_kernel:
         return matchrank_batched_pallas(
@@ -411,7 +414,7 @@ def matchrank(
     n_rows: Optional[int] = None,  # real row count when pre-padded
     block_s: int = 512,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray, float, int]:
     """Run the fused match+rank+top-1. Returns (mask[S], score[S],
     best_score, best_idx) trimmed back to the unpadded S.
@@ -454,7 +457,7 @@ def matchrank_topk(
     n_rows: Optional[int] = None,
     block_s: int = 512,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Top-k selection: fused scores + ``lax.top_k`` inside ONE jitted
     program (scores never leave the device before the top-k). Returns
@@ -489,7 +492,7 @@ def matchrank_batched(
     k: int = 1,
     block_s: int = 512,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Batched fused match+rank+top-k: B requests against ONE candidate
     block in a single kernel launch.
@@ -500,28 +503,16 @@ def matchrank_batched(
     there, as in :func:`matchrank_topk`).
     """
     batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
-    b = batched.b
     if not use_kernel:
         # grouped host evaluation — the jnp ref's [B,S,T] einsums are kept
         # as a parity oracle only (see _matchrank_batched_dense_host)
         s = attrs.shape[0] if n_rows is None else int(n_rows)
         return _matchrank_batched_dense_host(attrs, valid, batched, admit, s, k)
-    attrs_p, valid_p, s, s_pad = _prepare_columns(
-        attrs, valid, batched.a_pad, block_s, n_rows
+    operands, static, s = _batched_launch(
+        attrs, valid, batched, admit, n_rows, k, block_s
     )
-    admit_p = np.zeros((b, s_pad), dtype=np.float32)
-    if admit is None:
-        admit_p[:, :s] = 1.0
-    else:
-        admit_p[:, :s] = np.asarray(admit, dtype=np.float32)[:, :s]
-
     mask, score, topk_s, topk_i = _dispatch_batched(
-        attrs_p, valid_p, jnp.asarray(admit_p),
-        jnp.asarray(batched.sel), jnp.asarray(batched.op_codes),
-        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_active),
-        jnp.asarray(batched.weights), jnp.asarray(batched.bias),
-        k=min(k, s), block_s=block_s, use_kernel=use_kernel,
-        interpret=interpret,
+        *operands, **static, interpret=interpret
     )
     return (
         np.asarray(mask)[:, :s],
@@ -529,6 +520,49 @@ def matchrank_batched(
         np.asarray(topk_i),
         np.asarray(topk_s),
     )
+
+
+def _batched_launch(
+    attrs, valid, batched: BatchedPlan, admit, n_rows, k: int, block_s: int
+) -> Tuple[Tuple[Any, ...], Dict[str, Any], int]:
+    """→ (operands, static arguments, live rows) of the kernel launch that
+    :func:`matchrank_batched` makes."""
+    attrs_p, valid_p, s, s_pad = _prepare_columns(
+        attrs, valid, batched.a_pad, block_s, n_rows
+    )
+    admit_p = np.zeros((batched.b, s_pad), dtype=np.float32)
+    if admit is None:
+        admit_p[:, :s] = 1.0
+    else:
+        admit_p[:, :s] = np.asarray(admit, dtype=np.float32)[:, :s]
+    operands = (
+        attrs_p, valid_p, jnp.asarray(admit_p),
+        jnp.asarray(batched.sel), jnp.asarray(batched.op_codes),
+        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_active),
+        jnp.asarray(batched.weights), jnp.asarray(batched.bias),
+    )
+    return operands, dict(k=min(k, s), block_s=block_s, use_kernel=True), s
+
+
+def lower_matchrank_batched(
+    attrs: np.ndarray,
+    valid: np.ndarray,
+    plans: "BatchedPlan | Sequence[KernelPlan]",
+    *,
+    admit: Optional[np.ndarray] = None,
+    n_rows: Optional[int] = None,
+    k: int = 1,
+    block_s: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.stages.Lowered:
+    """The program :func:`matchrank_batched` launches with ``use_kernel``
+    for these operands, lowered and not run — what the backend compiles
+    (a Mosaic ``tpu_custom_call`` on a TPU, the interpreter elsewhere)."""
+    batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
+    operands, static, _ = _batched_launch(
+        attrs, valid, batched, admit, n_rows, k, block_s
+    )
+    return _dispatch_batched.lower(*operands, **static, interpret=interpret)
 
 
 def matchrank_batched_topk(
@@ -543,7 +577,7 @@ def matchrank_batched_topk(
     use_sparse: Optional[bool] = None,
     block_s: int = 512,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Batched top-k *selection*: B requests → (topk_idx [B,k],
     topk_scores [B,k]); slots past a request's match count hold -inf

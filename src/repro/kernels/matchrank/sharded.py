@@ -11,8 +11,8 @@ axis; on one device it runs as a batched loop — same results either way.
 
 Stage 2 — merge: per-shard candidate lists are globalized (local index +
 shard row offset), flattened **shard-major** into ``[B, G·k]`` and merged
-into the global top-k by a small Pallas kernel (k knockout-argmax rounds
-per request, grid ``(B,)``).
+into the global top-k by a small Pallas kernel (k knockout rounds of
+masked max/min reductions, 8 requests per grid step).
 
 Tie-break contract (property-tested): every per-shard list is
 rank-descending with ties at the lowest local index, and the shard-major
@@ -38,7 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .kernel import NEG_INF, matchrank_batched_pallas
+from repro.kernels import resolve_interpret
+
+from .kernel import NEG_INF, REQ_BLOCK, matchrank_batched_pallas
 from .ops import BatchedPlan, KernelPlan, stack_plans
 from .ref import matchrank_batched_ref, merge_topk_ref
 from .sparse import IntervalBatch, topk_in_rank_order
@@ -59,27 +61,33 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _merge_topk_kernel(
-    scores_ref,  # [1, C_PAD] f32 — request b's flattened per-shard candidates
-    idx_ref,  # [1, C_PAD] i32 — matching global row indices
-    out_s_ref,  # [1, MERGE_K_PAD] f32
-    out_i_ref,  # [1, MERGE_K_PAD] i32
+    scores_ref,  # [R, C_PAD] f32 — the block's flattened per-shard candidates
+    idx_ref,  # [R, C_PAD] i32 — matching global row indices
+    out_s_ref,  # [R, MERGE_K_PAD] f32
+    out_i_ref,  # [R, MERGE_K_PAD] i32
     *,
     k: int,
 ):
-    s = scores_ref[0, :]
-    idx = idx_ref[0, :]
-    pos = jnp.arange(s.shape[0])
-    out_s = jnp.full((MERGE_K_PAD,), NEG_INF, dtype=jnp.float32)
-    out_i = jnp.zeros((MERGE_K_PAD,), dtype=jnp.int32)
-    # k knockout-argmax rounds; first max ⇒ lowest position on ties, and
-    # position order == global-row order within ties (shard-major layout)
+    s = scores_ref[...]
+    idx = idx_ref[...]
+    r, c_pad = s.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (r, c_pad), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (r, MERGE_K_PAD), 1)
+    out_s = jnp.full((r, MERGE_K_PAD), NEG_INF, dtype=jnp.float32)
+    out_i = jnp.zeros((r, MERGE_K_PAD), dtype=jnp.int32)
+    # k knockout rounds of masked reductions: the best score, then the
+    # lowest position holding it (position order == global-row order
+    # within ties, by the shard-major layout), then that position's row
     for j in range(k):
-        m = jnp.argmax(s)
-        out_s = out_s.at[j].set(s[m])
-        out_i = out_i.at[j].set(idx[m])
-        s = jnp.where(pos == m, NEG_INF, s)
-    out_s_ref[0, :] = out_s
-    out_i_ref[0, :] = out_i
+        best = jnp.max(s, axis=1, keepdims=True)
+        m = jnp.min(jnp.where(s == best, pos, c_pad), axis=1, keepdims=True)
+        at_m = pos == m
+        row = jnp.max(jnp.where(at_m, idx, jnp.iinfo(jnp.int32).min), axis=1, keepdims=True)
+        out_s = jnp.where(slot == j, best, out_s)
+        out_i = jnp.where(slot == j, row, out_i)
+        s = jnp.where(at_m, NEG_INF, s)
+    out_s_ref[...] = out_s
+    out_i_ref[...] = out_i
 
 
 def merge_topk_pallas(
@@ -87,45 +95,48 @@ def merge_topk_pallas(
     cand_idx: jnp.ndarray,  # [B, C] i32
     k: int,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Merge per-shard candidate lists into the global top-k.
 
-    Pads the candidate axis to the lane width with (-inf, 0) and returns
-    (scores [B, k] f32, idx [B, k] i32); slots past a request's match
-    count hold -inf (index meaningless there, as in the fused kernel).
+    Pads the candidate axis to the lane width with (-inf, 0) and the
+    request axis to ``REQ_BLOCK`` rows, one block of 8 requests per grid
+    step, and returns (scores [B, k] f32, idx [B, k] i32); slots past a
+    request's match count hold -inf (index meaningless there, as in the
+    fused kernel). ``interpret=None`` resolves from the platform
+    (:func:`repro.kernels.resolve_interpret`).
     """
     assert 1 <= k <= MERGE_K_PAD, (k, MERGE_K_PAD)
     b, c = cand_scores.shape
+    b_pad = _round_up(b, REQ_BLOCK)
     c_pad = max(_round_up(c, 128), 128)
-    scores = jnp.full((b, c_pad), NEG_INF, dtype=jnp.float32)
-    scores = scores.at[:, :c].set(cand_scores.astype(jnp.float32))
-    idx = jnp.zeros((b, c_pad), dtype=jnp.int32)
-    idx = idx.at[:, :c].set(cand_idx.astype(jnp.int32))
+    scores = jnp.full((b_pad, c_pad), NEG_INF, dtype=jnp.float32)
+    scores = scores.at[:b, :c].set(cand_scores.astype(jnp.float32))
+    idx = jnp.zeros((b_pad, c_pad), dtype=jnp.int32)
+    idx = idx.at[:b, :c].set(cand_idx.astype(jnp.int32))
 
     kernel = functools.partial(_merge_topk_kernel, k=k)
-    grid = (b,)
     out_shapes = (
-        jax.ShapeDtypeStruct((b, MERGE_K_PAD), jnp.float32),
-        jax.ShapeDtypeStruct((b, MERGE_K_PAD), jnp.int32),
+        jax.ShapeDtypeStruct((b_pad, MERGE_K_PAD), jnp.float32),
+        jax.ShapeDtypeStruct((b_pad, MERGE_K_PAD), jnp.int32),
     )
     in_specs = [
-        pl.BlockSpec((1, c_pad), lambda bi: (bi, 0)),  # scores
-        pl.BlockSpec((1, c_pad), lambda bi: (bi, 0)),  # idx
+        pl.BlockSpec((REQ_BLOCK, c_pad), lambda bi: (bi, 0)),  # scores
+        pl.BlockSpec((REQ_BLOCK, c_pad), lambda bi: (bi, 0)),  # idx
     ]
     out_specs = (
-        pl.BlockSpec((1, MERGE_K_PAD), lambda bi: (bi, 0)),
-        pl.BlockSpec((1, MERGE_K_PAD), lambda bi: (bi, 0)),
+        pl.BlockSpec((REQ_BLOCK, MERGE_K_PAD), lambda bi: (bi, 0)),
+        pl.BlockSpec((REQ_BLOCK, MERGE_K_PAD), lambda bi: (bi, 0)),
     )
     out_s, out_i = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b_pad // REQ_BLOCK,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scores, idx)
-    return out_s[:, :k], out_i[:, :k]
+    return out_s[:b, :k], out_i[:b, :k]
 
 
 @functools.partial(
@@ -134,7 +145,7 @@ def merge_topk_pallas(
 def _stage1_sharded(
     attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
     offsets,
-    *, k: int, block_s: int, use_kernel: bool, interpret: bool,
+    *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     """Per-shard fused matchrank+top-k, vmapped over the shard axis.
     → (cand_scores [B, G·k] f32, cand_idx [B, G·k] i32) in shard-major
@@ -196,7 +207,7 @@ def sharded_matchrank_topk(
     admit: Optional[np.ndarray] = None,  # [B, n] global pre-mask
     block_s: int = 512,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     merge_kernel: bool = True,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Device-parallel hierarchical top-k: per-shard fused kernel (vmap

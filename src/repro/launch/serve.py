@@ -41,6 +41,9 @@ def main(argv=None) -> int:
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="dump Chrome trace-event JSON (load in Perfetto)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     rng = jax.random.PRNGKey(args.seed)

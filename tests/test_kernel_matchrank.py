@@ -213,6 +213,29 @@ class TestBatched:
             np.testing.assert_array_equal(topk_i[i][matched], idx[matched])
             np.testing.assert_allclose(topk_s[i][matched], vals[matched], rtol=1e-6)
 
+    def test_topk_empty_slots_follow_lax_top_k(self):
+        """Fewer matches than k, across several S-blocks: slots past the
+        match count hold -inf at the lowest unmatched rows, exactly as
+        ``lax.top_k`` (and the host evaluator) order them."""
+        rng = np.random.default_rng(21)
+        attrs, valid = random_cols(rng, 700, invalid_frac=0.0)
+        admit = np.zeros((3, 700), np.float32)
+        admit[0, [650, 300]] = 1  # two matches, both past the first block
+        admit[2, 5] = 1
+        plan = lower_request(
+            parse_classad("requirements = true; rank = other.loadfactor"), NAMES
+        )
+        plans = [plan] * 3
+        _, _, ki, ks = matchrank_batched(
+            attrs, valid, plans, admit=admit, k=5, block_s=256, use_kernel=True
+        )
+        _, _, hi, hs = matchrank_batched(
+            attrs, valid, plans, admit=admit, k=5, block_s=256, use_kernel=False
+        )
+        np.testing.assert_array_equal(ks, hs)
+        np.testing.assert_array_equal(ki, hi)
+        assert list(ki[1]) == [0, 1, 2, 3, 4]  # nothing admitted
+
     def test_batched_admit_premask(self):
         rng = np.random.default_rng(4)
         attrs, valid = random_cols(rng, 64, invalid_frac=0.0)
