@@ -1,0 +1,10 @@
+"""Column packing, the device push and the host table in snapshot
+rebuilds, per second of window: the ``broker.snapshot.columns`` spans that start
+in the window. Nothing where the window has no such span."""
+
+SPAN = "broker.snapshot.columns"
+
+
+def read(run):
+    durs = [t1 - t0 for n, t0, t1, *_ in run.spans if n == SPAN and t0 < run.seconds]
+    return sum(durs) * 1e3 / run.seconds if durs else None

@@ -709,29 +709,32 @@ class DataBroker:
                 known.append(ep)
         rows: List[str] = []
         entries: List[Entry] = []
-        ads: List[ClassAd] = []
-        for ep in known:
-            gris = self.gris_resolver(ep)
-            if gris is None:
-                continue  # endpoint died: drop its row this epoch
-            entry = gris.flattened_view(source=self.client_url)
-            entry.setdefault("endpoint", ep)
-            rows.append(ep)
-            entries.append(entry)
-            ads.append(entry_to_classad(entry))
+        with self.tracer.span("broker.snapshot.gris", endpoints=len(known)):
+            for ep in known:
+                gris = self.gris_resolver(ep)
+                if gris is None:
+                    continue  # endpoint died: drop its row this epoch
+                entry = gris.flattened_view(source=self.client_url)
+                entry.setdefault("endpoint", ep)
+                rows.append(ep)
+                entries.append(entry)
+        with self.tracer.span("broker.snapshot.ads", rows=len(entries)):
+            ads = [entry_to_classad(entry) for entry in entries]
         prev = st.snapshot if st is not None else None
-        snapshot = (
-            prev.new_epoch(entries, reuse_vocab=False)
-            if prev is not None
-            else ReplicaSnapshot(entries)
-        )
+        with self.tracer.span("broker.snapshot.columns", rows=len(entries)):
+            snapshot = (
+                prev.new_epoch(entries, reuse_vocab=False)
+                if prev is not None
+                else ReplicaSnapshot(entries)
+            )
+            table = snapshot.table()
         st = _SnapshotState(
             snapshot=snapshot,
             endpoints=tuple(rows),
             row_of={ep: i for i, ep in enumerate(rows)},
             entries=entries,
             ads=ads,
-            table=snapshot.table(),
+            table=table,
             built_at=now,
         )
         self._snap_state = st
@@ -779,15 +782,16 @@ class DataBroker:
                 known.append(ep)
         by_shard: Dict[str, List[str]] = {}
         shard_entries: Dict[str, List[Entry]] = {}
-        for ep in known:
-            gris = self.gris_resolver(ep)
-            if gris is None:
-                continue  # endpoint died: drop its row this epoch
-            entry = gris.flattened_view(source=self.client_url)
-            entry.setdefault("endpoint", ep)
-            name = self._shard_name(ep)
-            by_shard.setdefault(name, []).append(ep)
-            shard_entries.setdefault(name, []).append(entry)
+        with self.tracer.span("broker.snapshot.gris", endpoints=len(known)):
+            for ep in known:
+                gris = self.gris_resolver(ep)
+                if gris is None:
+                    continue  # endpoint died: drop its row this epoch
+                entry = gris.flattened_view(source=self.client_url)
+                entry.setdefault("endpoint", ep)
+                name = self._shard_name(ep)
+                by_shard.setdefault(name, []).append(ep)
+                shard_entries.setdefault(name, []).append(entry)
         if not shard_entries:
             # every endpoint unreachable: an empty flat snapshot keeps the
             # n == 0 handling in select_many uniform
@@ -811,51 +815,54 @@ class DataBroker:
         prev = st.snapshot if st is not None else None
         snapshot = None
         changed: Optional[List[str]] = None
-        if (
-            isinstance(prev, ShardedSnapshot)
-            and prev.shard_names == shard_names
-            and all(
-                len(shard_entries[nm]) == len(prev.entries_by_shard[nm])
-                for nm in shard_names
-            )
-        ):
-            rows_before = prev.pushed_rows
-            try:
-                changed = prev.refresh(shard_entries)
-                snapshot = prev
-            except ValueError:
-                snapshot = None  # vocab/shape drift: fall through to rebuild
-            if snapshot is not None:
-                self._ctr["snapshot_delta_refreshes"].inc()
-                self._ctr_shard_rows.inc(int(snapshot.pushed_rows - rows_before))
-        if snapshot is None:
-            snapshot = ShardedSnapshot(
-                shard_entries, epoch=prev.epoch + 1 if prev is not None else 0
-            )
+        with self.tracer.span("broker.snapshot.columns", shards=len(shard_names)):
+            if (
+                isinstance(prev, ShardedSnapshot)
+                and prev.shard_names == shard_names
+                and all(
+                    len(shard_entries[nm]) == len(prev.entries_by_shard[nm])
+                    for nm in shard_names
+                )
+            ):
+                rows_before = prev.pushed_rows
+                try:
+                    changed = prev.refresh(shard_entries)
+                    snapshot = prev
+                except ValueError:
+                    snapshot = None  # vocab/shape drift: fall through to rebuild
+                if snapshot is not None:
+                    self._ctr["snapshot_delta_refreshes"].inc()
+                    self._ctr_shard_rows.inc(int(snapshot.pushed_rows - rows_before))
+            if snapshot is None:
+                snapshot = ShardedSnapshot(
+                    shard_entries, epoch=prev.epoch + 1 if prev is not None else 0
+                )
+            table = snapshot.table()
 
         rows = [ep for nm in shard_names for ep in by_shard[nm]]
         entries = [e for nm in shard_names for e in shard_entries[nm]]
-        if changed is not None and st is not None:
-            # delta: re-convert ads only for shards whose entries moved
-            changed_set = set(changed)
-            ads: List[ClassAd] = []
-            pos = 0
-            for nm in shard_names:
-                cnt = len(shard_entries[nm])
-                if nm in changed_set:
-                    ads.extend(entry_to_classad(e) for e in shard_entries[nm])
-                else:
-                    ads.extend(st.ads[pos : pos + cnt])
-                pos += cnt
-        else:
-            ads = [entry_to_classad(e) for e in entries]
+        with self.tracer.span("broker.snapshot.ads", rows=len(entries)):
+            if changed is not None and st is not None:
+                # delta: re-convert ads only for shards whose entries moved
+                changed_set = set(changed)
+                ads: List[ClassAd] = []
+                pos = 0
+                for nm in shard_names:
+                    cnt = len(shard_entries[nm])
+                    if nm in changed_set:
+                        ads.extend(entry_to_classad(e) for e in shard_entries[nm])
+                    else:
+                        ads.extend(st.ads[pos : pos + cnt])
+                    pos += cnt
+            else:
+                ads = [entry_to_classad(e) for e in entries]
         st = _SnapshotState(
             snapshot=snapshot,
             endpoints=tuple(rows),
             row_of={ep: i for i, ep in enumerate(rows)},
             entries=entries,
             ads=ads,
-            table=snapshot.table(),
+            table=table,
             built_at=now,
         )
         self._snap_state = st
@@ -1001,26 +1008,33 @@ class DataBroker:
             subset and request i must go to the interpreter."""
             import numpy as np
 
+            rid = recs[i].request_id
             admit = np.ones((st.snapshot.n,), dtype=np.float32)
             groups: Dict[str, List[int]] = {}
-            for r, ad in enumerate(st.ads):
-                pexpr = ad.lookup_expr("requirements")
-                if pexpr is None:
-                    continue
-                groups.setdefault(repr(pexpr), []).append(r)
+            with self.tracer.span("broker.lowering.policy_groups", request_id=rid):
+                for r, ad in enumerate(st.ads):
+                    pexpr = ad.lookup_expr("requirements")
+                    if pexpr is None:
+                        continue
+                    groups.setdefault(repr(pexpr), []).append(r)
             for src, rows in groups.items():
-                try:
-                    fn = self.plan_cache.policy_fn(src, reqs[i], vocab, env=self.env)
-                except CompileError:
-                    return None
-                t = fn(st.table, np)
-                ok = t.ok if t.ok is not True else np.ones((st.snapshot.n,), bool)
-                pol = np.broadcast_to(np.asarray(t.val), (st.snapshot.n,)) & np.broadcast_to(
-                    np.asarray(ok), (st.snapshot.n,)
-                )
-                for r in rows:
-                    if not pol[r]:
-                        admit[r] = 0.0
+                with self.tracer.span("broker.lowering.policy_compile", request_id=rid) as sp:
+                    misses = self.plan_cache.stats["misses"]
+                    try:
+                        fn = self.plan_cache.policy_fn(src, reqs[i], vocab, env=self.env)
+                    except CompileError:
+                        return None
+                    finally:
+                        sp.set(hit=self.plan_cache.stats["misses"] == misses)
+                with self.tracer.span("broker.lowering.policy_eval", request_id=rid):
+                    t = fn(st.table, np)
+                    ok = t.ok if t.ok is not True else np.ones((st.snapshot.n,), bool)
+                    pol = np.broadcast_to(np.asarray(t.val), (st.snapshot.n,)) & np.broadcast_to(
+                        np.asarray(ok), (st.snapshot.n,)
+                    )
+                    for r in rows:
+                        if not pol[r]:
+                            admit[r] = 0.0
             return admit
 
         import numpy as np
@@ -1044,16 +1058,21 @@ class DataBroker:
                     interp.append(i)
                 else:
                     admits[i] = admit
-                    try:
-                        plan = self.plan_cache.kernel_plan(req, vocab, env=self.env)
-                        kernel_batch.append(i)
-                        kernel_plans.append(plan)
-                    except CompileError:
+                    with self.tracer.span(
+                        "broker.lowering.plan", request_id=recs[i].request_id
+                    ) as sp:
+                        misses = pcs["misses"]
                         try:
-                            self.plan_cache.columnar_program(req, vocab, env=self.env)
-                            columnar.append(i)
+                            plan = self.plan_cache.kernel_plan(req, vocab, env=self.env)
+                            kernel_batch.append(i)
+                            kernel_plans.append(plan)
                         except CompileError:
-                            interp.append(i)
+                            try:
+                                self.plan_cache.columnar_program(req, vocab, env=self.env)
+                                columnar.append(i)
+                            except CompileError:
+                                interp.append(i)
+                        sp.set(hit=pcs["misses"] == misses)
                 pcs = self.plan_cache.stats
                 if pcs["misses"] > pc_before[1]:
                     recs[i].plan_cache = "miss"
@@ -1062,11 +1081,7 @@ class DataBroker:
 
         # ---- tier 1: one stacked kernel launch for the whole sub-batch ----
         if kernel_batch:
-            from repro.kernels.matchrank.ops import (
-                matchrank_batched,
-                matchrank_batched_topk,
-                stack_plans,
-            )
+            from repro.kernels.matchrank.ops import matchrank_batched, matchrank_batched_topk
 
             attrs, valid, n_rows = st.snapshot.device_columns()
             admit_mat = np.zeros((len(kernel_batch), n_rows), dtype=np.float32)
@@ -1134,10 +1149,11 @@ class DataBroker:
                     mask, score, _, _ = matchrank_batched(
                         attrs,
                         valid,
-                        stack_plans(kernel_plans),
+                        kernel_plans,
                         admit=admit_mat,
                         n_rows=n_rows,
                         use_kernel=use_kernel,
+                        tracer=self.tracer,
                     )
                 for bi, i in enumerate(kernel_batch):
                     results[i] = self._ranked_from_scores(

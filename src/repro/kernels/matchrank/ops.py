@@ -12,6 +12,7 @@ compile on a TPU and run in the Pallas interpreter on the CPU backend.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -493,6 +494,7 @@ def matchrank_batched(
     block_s: int = 512,
     use_kernel: bool = True,
     interpret: Optional[bool] = None,
+    tracer: Optional[Any] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Batched fused match+rank+top-k: B requests against ONE candidate
     block in a single kernel launch.
@@ -501,25 +503,39 @@ def matchrank_batched(
     topk_scores [B,k] f32), trimmed to the live row count. Top-k slots
     beyond a request's match count hold score -inf (index is meaningless
     there, as in :func:`matchrank_topk`).
+
+    With a ``tracer`` (:class:`repro.obs.Tracer`), two spans time the
+    launch's host side: ``broker.kernel_launch.copy_in`` (plan stacking,
+    operand padding and the host-to-device puts) and
+    ``broker.kernel_launch.fetch`` (the outputs' copy back, which waits
+    for the device). The dispatch is what lies between them.
     """
-    batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
+    span = tracer.span if tracer is not None else _no_span
+    with span("broker.kernel_launch.copy_in"):
+        batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
+        if use_kernel:
+            operands, static, s = _batched_launch(
+                attrs, valid, batched, admit, n_rows, k, block_s
+            )
     if not use_kernel:
         # grouped host evaluation — the jnp ref's [B,S,T] einsums are kept
         # as a parity oracle only (see _matchrank_batched_dense_host)
         s = attrs.shape[0] if n_rows is None else int(n_rows)
         return _matchrank_batched_dense_host(attrs, valid, batched, admit, s, k)
-    operands, static, s = _batched_launch(
-        attrs, valid, batched, admit, n_rows, k, block_s
-    )
     mask, score, topk_s, topk_i = _dispatch_batched(
         *operands, **static, interpret=interpret
     )
-    return (
-        np.asarray(mask)[:, :s],
-        np.asarray(score)[:, :s],
-        np.asarray(topk_i),
-        np.asarray(topk_s),
-    )
+    with span("broker.kernel_launch.fetch"):
+        return (
+            np.asarray(mask)[:, :s],
+            np.asarray(score)[:, :s],
+            np.asarray(topk_i),
+            np.asarray(topk_s),
+        )
+
+
+def _no_span(name: str):
+    return contextlib.nullcontext()
 
 
 def _batched_launch(

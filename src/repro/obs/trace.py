@@ -11,6 +11,10 @@ Around kernel dispatch the tracer can additionally enter a
 (``jax_annotations=True``); the passthrough is best-effort and degrades
 to a no-op when the profiler is unavailable.
 
+Every full (generation-2) garbage collection is recorded as a parentless
+``broker.gc`` span (:data:`GC_SPAN`): a process event that stalls whatever
+span happens to be open, not a child of it.
+
 The span buffer is bounded (``max_spans``): a serving process tracing
 every batch keeps the most recent window instead of growing without
 bound.
@@ -19,13 +23,18 @@ bound.
 from __future__ import annotations
 
 import functools
+import gc
 import json
 import time
+import weakref
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Callable, Deque, Dict, Iterator, List, Optional
+from typing import Any, Callable, Deque, Dict, Iterator, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer"]
+__all__ = ["GC_SPAN", "Span", "Tracer"]
+
+#: name of the span a full garbage collection is recorded as
+GC_SPAN = "broker.gc"
 
 
 class Span:
@@ -73,6 +82,25 @@ def _jsonable(v: Any) -> Any:
     return repr(v)
 
 
+def _gc_hook(ref: "weakref.ref[Tracer]") -> Callable[[str, Dict[str, Any]], None]:
+    """A ``gc.callbacks`` entry that holds its tracer weakly, so that the
+    registration does not keep the tracer alive."""
+
+    def on_gc(phase: str, info: Dict[str, Any]) -> None:
+        tracer = ref()
+        if tracer is not None and info["generation"] == 2:
+            tracer._on_full_gc(phase, info)
+
+    return on_gc
+
+
+def _unhook(callback: Callable) -> None:
+    try:
+        gc.callbacks.remove(callback)
+    except ValueError:
+        pass
+
+
 class Tracer:
     """Span factory + bounded buffer of finished spans.
 
@@ -87,6 +115,9 @@ class Tracer:
         ``jax.profiler`` traces.
     max_spans:
         Finished-span ring-buffer capacity.
+
+    Full garbage collections land in the buffer as :data:`GC_SPAN` spans
+    with no parent; the ``gc.callbacks`` hook goes with the tracer.
     """
 
     def __init__(
@@ -98,11 +129,23 @@ class Tracer:
     ):
         self.time_fn = time_fn or time.perf_counter  # lint: allow-wallclock
         self.jax_annotations = bool(jax_annotations)
+        self._annotation: Optional[Callable[[str], Any]] = None
+        if self.jax_annotations:
+            try:
+                from jax.profiler import TraceAnnotation
+
+                self._annotation = TraceAnnotation
+            except ImportError:
+                pass
         self._spans: Deque[Span] = deque(maxlen=int(max_spans))
         self._stack: List[Span] = []
         self._next_id = 1
         self.epoch = self.time_fn()
         self.dropped = 0
+        self._gc_open: Optional[Tuple[Span, Any]] = None
+        hook = _gc_hook(weakref.ref(self))
+        gc.callbacks.append(hook)
+        weakref.finalize(self, _unhook, hook)
 
     # ------------------------------------------------------------- scoping
     @contextmanager
@@ -118,15 +161,7 @@ class Tracer:
         )
         self._next_id += 1
         self._stack.append(s)
-        annotation = None
-        if self.jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                annotation = TraceAnnotation(name)
-                annotation.__enter__()
-            except Exception:
-                annotation = None
+        annotation = self._annotate(name)
         try:
             yield s
         finally:
@@ -134,9 +169,36 @@ class Tracer:
                 annotation.__exit__(None, None, None)
             s.t1 = self.time_fn()
             self._stack.pop()
-            if len(self._spans) == self._spans.maxlen:
-                self.dropped += 1
-            self._spans.append(s)
+            self._finish(s)
+
+    def _annotate(self, name: str) -> Any:
+        """The entered ``TraceAnnotation`` for ``name``, or None."""
+        if self._annotation is None:
+            return None
+        annotation = self._annotation(name)
+        annotation.__enter__()
+        return annotation
+
+    def _finish(self, s: Span) -> None:
+        if len(self._spans) == self._spans.maxlen:
+            self.dropped += 1
+        self._spans.append(s)
+
+    def _on_full_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """Open a :data:`GC_SPAN` at a full collection's start, close it
+        at its stop; it takes no parent from the spans open around it."""
+        if phase == "start":
+            s = Span(GC_SPAN, self._next_id, None, 0, self.time_fn(), {})
+            self._next_id += 1
+            self._gc_open = (s, self._annotate(GC_SPAN))
+        elif self._gc_open is not None:
+            s, annotation = self._gc_open
+            self._gc_open = None
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            s.t1 = self.time_fn()
+            s.args["collected"] = info.get("collected", 0)
+            self._finish(s)
 
     def trace(self, name: Optional[str] = None) -> Callable:
         """Decorator form: ``@tracer.trace("phase")``."""

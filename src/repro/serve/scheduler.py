@@ -15,6 +15,12 @@ queue through :meth:`DataBroker.select_many` — one stacked
 plus an explicit :meth:`flush`, and an implicit one when a caller forces
 a ticket's :meth:`~SelectionTicket.result` (a synchronous caller never
 deadlocks waiting on its own unflushed batch).
+
+Each ticket's wait in the queue (submit to the start of its flush, on the
+tracer's span clock) adds to ``stats["queue_wait_s"]`` over
+``stats["queue_waited"]`` tickets, and to the registry's
+``scheduler_queue_wait_seconds`` histogram: the batching delay and any
+head-of-line blocking behind a long flush, as the server saw them.
 """
 
 from __future__ import annotations
@@ -31,9 +37,10 @@ __all__ = ["SelectionTicket", "BatchScheduler"]
 class SelectionTicket:
     """A pending selection: filled by the scheduler at flush time."""
 
-    def __init__(self, scheduler: "BatchScheduler", lfn: str):
+    def __init__(self, scheduler: "BatchScheduler", lfn: str, submitted_at: float):
         self._scheduler = scheduler
         self.lfn = lfn
+        self.submitted_at = submitted_at  # the scheduler's tracer clock
         self._outcome: Any = None
         self._done = False
 
@@ -83,6 +90,8 @@ class BatchScheduler:
             "latency_flushes": 0,
             "size_flushes": 0,
             "max_batch_seen": 0,
+            "queue_wait_s": 0.0,
+            "queue_waited": 0,
         }
         # obs: share the broker's registry/tracer unless told otherwise;
         # self.stats stays the source of truth for exact-count consumers
@@ -100,16 +109,14 @@ class BatchScheduler:
         self._g_queue = self.metrics.gauge(
             "scheduler_queue_depth", "selections currently queued"
         )
-        self._h_batch = self.metrics.histogram(
-            "scheduler_coalesced_batch_size",
-            "selections per select_many flush",
-            buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, float("inf")),
+        self._h_wait = self.metrics.histogram(
+            "scheduler_queue_wait_seconds", "submit to the start of the selection's flush"
         )
 
     # ----------------------------------------------------------- submission
     def submit(self, lfn: str, request: Optional[ClassAd] = None) -> SelectionTicket:
         """Queue one selection; may trigger a size flush."""
-        ticket = SelectionTicket(self, lfn)
+        ticket = SelectionTicket(self, lfn, self.tracer.time_fn())
         if not self._pending:
             self._oldest_at = self.clock.now()
         self._pending.append((lfn, request, ticket))
@@ -147,12 +154,17 @@ class BatchScheduler:
         "forced") in the metrics registry; submit/poll pass theirs."""
         if not self._pending:
             return
+        t = self.tracer.time_fn()
         batch, self._pending = self._pending, []
         self._oldest_at = None
         self.stats["batches"] += 1
         self.stats["max_batch_seen"] = max(self.stats["max_batch_seen"], len(batch))
+        for _, _, ticket in batch:
+            wait = t - ticket.submitted_at
+            self.stats["queue_wait_s"] += wait
+            self._h_wait.observe(wait)
+        self.stats["queue_waited"] += len(batch)
         self._c_flush.get(reason, self._c_flush["forced"]).inc()
-        self._h_batch.observe(len(batch))
         self._g_queue.set(0)
         with self.tracer.span("scheduler.flush", batch=len(batch), reason=reason):
             outcomes = self.broker.select_many(
