@@ -119,6 +119,24 @@ class _SnapshotState:
     ads: List[ClassAd]
     table: Any  # core.compile.ColumnTable (f64, live rows)
     built_at: float
+    #: usage-policy source (``repr`` of a row ad's ``requirements``) → the
+    #: rows carrying it; built by the first request lowered against this
+    #: epoch and dropped with the state (:func:`_policy_index`)
+    policy_index: Optional[Dict[str, Any]] = None
+
+
+def _policy_index(ads: Sequence[ClassAd]) -> Dict[str, Any]:
+    """Group snapshot rows by their usage-policy source: one ``np.intp``
+    row array per distinct ``requirements``. A row carries at most one
+    policy, so the groups are disjoint."""
+    import numpy as np
+
+    groups: Dict[str, List[int]] = {}
+    for r, ad in enumerate(ads):
+        pexpr = ad.lookup_expr("requirements")
+        if pexpr is not None:
+            groups.setdefault(repr(pexpr), []).append(r)
+    return {src: np.asarray(rows, dtype=np.intp) for src, rows in groups.items()}
 
 
 def _rows_of(
@@ -459,6 +477,8 @@ class DataBroker:
                 ("snapshot_builds", "GRIS snapshot (re)builds"),
                 ("snapshot_reuses", "GRIS snapshot TTL reuses"),
                 ("snapshot_delta_refreshes", "sharded snapshots refreshed in place (dirty shards only)"),
+                ("policy_index_builds", "usage-policy row indexes built, one per snapshot epoch lowered against"),
+                ("policy_index_reuses", "requests lowered from an existing policy index"),
                 ("ad_findings", "request-ad analyzer findings recorded"),
             )
         }
@@ -1010,13 +1030,14 @@ class DataBroker:
 
             rid = recs[i].request_id
             admit = np.ones((st.snapshot.n,), dtype=np.float32)
-            groups: Dict[str, List[int]] = {}
-            with self.tracer.span("broker.lowering.policy_groups", request_id=rid):
-                for r, ad in enumerate(st.ads):
-                    pexpr = ad.lookup_expr("requirements")
-                    if pexpr is None:
-                        continue
-                    groups.setdefault(repr(pexpr), []).append(r)
+            with self.tracer.span("broker.lowering.policy_groups", request_id=rid) as sp:
+                groups = st.policy_index
+                sp.set(hit=groups is not None)
+                if groups is None:
+                    groups = st.policy_index = _policy_index(st.ads)
+                    self._ctr["policy_index_builds"].inc()
+                else:
+                    self._ctr["policy_index_reuses"].inc()
             for src, rows in groups.items():
                 with self.tracer.span("broker.lowering.policy_compile", request_id=rid) as sp:
                     misses = self.plan_cache.stats["misses"]
@@ -1032,9 +1053,7 @@ class DataBroker:
                     pol = np.broadcast_to(np.asarray(t.val), (st.snapshot.n,)) & np.broadcast_to(
                         np.asarray(ok), (st.snapshot.n,)
                     )
-                    for r in rows:
-                        if not pol[r]:
-                            admit[r] = 0.0
+                    admit[rows[np.logical_not(pol[rows])]] = 0.0
             return admit
 
         import numpy as np
