@@ -7,7 +7,12 @@ and four size flushes of 64 kernel-tier requests through
 ``BatchScheduler`` → ``DataBroker.select_many`` → the compiled matchrank
 kernel — and checks that every request took ``batched_kernel``, that the
 tier-1 program holds a Mosaic ``tpu_custom_call``, and that every ranking
-equals the paper-faithful interpreter's.
+equals the paper-faithful interpreter's. A second phase publishes
+per-source transfer history, site averages and circuit breakers, then
+flushes four batches of 64 requests sent with no ad (the broker's default
+read ad, with its guarded clauses and fallback rank chain) through the
+same compiled kernel, with the same check, and requires each branch of
+the rank chain to rank at least 10 % of the rows.
 
 Usage (from the checkout's root, on a machine with a TPU)::
 

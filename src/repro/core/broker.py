@@ -470,6 +470,11 @@ class DataBroker:
                 ("vectorized_matches", "sequential matches on the columnar engine"),
                 ("batch_selects", "select_many batches"),
                 ("batched_kernel_requests", "requests answered by the stacked kernel"),
+                (
+                    "batched_kernel_guarded_requests",
+                    "requests answered by the stacked kernel whose plan has"
+                    " guarded terms or fallback rank alternatives",
+                ),
                 ("batched_sparse_requests", "requests answered by sparse top-k"),
                 ("batched_sharded_requests", "requests answered by the sharded walk+merge"),
                 ("batched_columnar_requests", "requests answered columnar per-request"),
@@ -960,9 +965,14 @@ class DataBroker:
         seen = set()
         from .catalog import CatalogError
 
+        default_ad: Optional[ClassAd] = None  # parsed once a batch, shared
         with self.tracer.span("broker.batch_search", batch=n):
             for i, (lfn, req) in enumerate(queries):
-                reqs[i] = req if req is not None else default_read_request(self.client_url)
+                if req is None:
+                    if default_ad is None:
+                        default_ad = default_read_request(self.client_url)
+                    req = default_ad
+                reqs[i] = req
                 try:
                     self._check_request_ad(reqs[i], recs[i])
                 except AdValidationError as e:
@@ -1081,10 +1091,12 @@ class DataBroker:
                         "broker.lowering.plan", request_id=recs[i].request_id
                     ) as sp:
                         misses = pcs["misses"]
+                        sp.set(guarded=False)
                         try:
                             plan = self.plan_cache.kernel_plan(req, vocab, env=self.env)
                             kernel_batch.append(i)
                             kernel_plans.append(plan)
+                            sp.set(guarded=not plan.plain)
                         except CompileError:
                             try:
                                 self.plan_cache.columnar_program(req, vocab, env=self.env)
@@ -1159,11 +1171,13 @@ class DataBroker:
                         self._ctr["batched_sparse_requests"].inc()
                     sparse_done = True
             if not sparse_done:
+                guarded = [not p.plain for p in kernel_plans]
                 with self.tracer.span(
                     "broker.kernel_launch",
                     batch=len(kernel_batch),
                     rows=n_rows,
                     use_kernel=use_kernel,
+                    guarded=sum(guarded),
                 ):
                     mask, score, _, _ = matchrank_batched(
                         attrs,
@@ -1183,6 +1197,8 @@ class DataBroker:
                         recs[i], st, results[i], mask=mask[bi], score=score[bi]
                     )
                     self._ctr["batched_kernel_requests"].inc()
+                    if guarded[bi]:
+                        self._ctr["batched_kernel_guarded_requests"].inc()
 
         # ---- tier 2: columnar programs over the shared snapshot table ----
         for i in columnar:

@@ -62,7 +62,9 @@ __all__ = [
     "vectorized_match",
     "extract_conjunctive_terms",
     "extract_linear_rank",
+    "extract_rank_alternatives",
     "ConjTerm",
+    "RankAlternative",
 ]
 
 
@@ -675,17 +677,151 @@ class ConjTerm:
     attr: str
     op: str  # one of OPCODES
     threshold: float
+    #: the term passes where the attribute is Undefined — lowered from
+    #: ``isUndefined(other.attr) || other.attr OP c``; otherwise an
+    #: Undefined attribute fails it, as a requirement fails closed
+    undefined_passes: bool = False
+
+
+@dataclass(frozen=True)
+class RankAlternative:
+    """One branch of a rank ``ifThenElse`` chain: where every ``gate`` term
+    holds (an empty gate always holds), the rank is ``num / den`` — two
+    linear forms ``{attr: weight, "": bias}``, ``den`` None for 1."""
+
+    gate: Tuple[ConjTerm, ...]
+    num: Dict[str, float]
+    den: Optional[Dict[str, float]] = None
+
+
+def _candidate_attr(expr: Expr, request: ClassAd) -> Optional[str]:
+    """The candidate attribute ``expr`` names: ``other.a``, or an
+    unqualified ``a`` that the request does not define (the interpreter
+    looks an unqualified name up in the request first)."""
+    if isinstance(expr, AttrRef) and (
+        expr.scope == "other" or (expr.scope is None and expr.name.lower() not in request)
+    ):
+        return expr.name.lower()
+    return None
+
+
+def _request_only(expr: Expr, request: ClassAd, env, seen: Optional[set] = None) -> bool:
+    """True when ``expr`` reads nothing of the candidate, so that it folds
+    to one value per request: only literals, the request's own attributes
+    (themselves request-only) and unqualified environment names."""
+    seen = set() if seen is None else seen
+    if isinstance(expr, Literal):
+        return True
+    if isinstance(expr, AttrRef):
+        name = expr.name.lower()
+        if expr.scope == "other":
+            return False
+        if name in request:
+            if name in seen:
+                return True
+            seen.add(name)
+            return _request_only(request.lookup_expr(name), request, env, seen)
+        return expr.scope is None and name in {k.lower() for k in (env or {})}
+    if isinstance(expr, UnaryOp):
+        return _request_only(expr.operand, request, env, seen)
+    if isinstance(expr, BinOp):
+        return _request_only(expr.left, request, env, seen) and _request_only(
+            expr.right, request, env, seen
+        )
+    if isinstance(expr, Ternary):
+        return all(_request_only(e, request, env, seen) for e in (expr.cond, expr.then, expr.other))
+    if isinstance(expr, FuncCall):
+        return all(_request_only(a, request, env, seen) for a in expr.args)
+    return False
+
+
+def _request_value(expr: Expr, request: ClassAd, env) -> Any:
+    """A request-only expression's value; ``None`` when it reads the candidate."""
+    if not _request_only(expr, request, env):
+        return None
+    try:
+        return evaluate(expr, request, None, env)
+    except Exception:
+        return Error
 
 
 def _scalar_of(expr: Expr, request: ClassAd, env) -> Optional[float]:
     """Evaluate an expression that involves only the request/env to a float."""
-    try:
-        v = evaluate(expr, request, None, env)
-    except Exception:
-        return None
+    v = _request_value(expr, request, env)
     if isinstance(v, bool) or not isinstance(v, (int, float)):
         return None
     return float(v)
+
+
+def _threshold_term(e: Expr, request: ClassAd, env) -> Optional[ConjTerm]:
+    """``other.attr OP scalar`` or ``scalar OP other.attr`` → a term."""
+    if not (isinstance(e, BinOp) and e.op in OPCODES):
+        return None
+    for attr_side, const_side, flip in ((e.left, e.right, False), (e.right, e.left, True)):
+        attr = _candidate_attr(attr_side, request)
+        if attr is None:
+            continue
+        c = _scalar_of(const_side, request, env)
+        if c is None:
+            continue
+        op = e.op
+        if flip:
+            op = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "==": "==", "!=": "!="}[op]
+        return ConjTerm(attr, op, c)
+    return None
+
+
+def _flatten(e: Expr, op: str) -> List[Expr]:
+    if isinstance(e, BinOp) and e.op == op:
+        return _flatten(e.left, op) + _flatten(e.right, op)
+    return [e]
+
+
+def _is_undefined_of(e: Expr, request: ClassAd) -> Optional[str]:
+    """``isUndefined(other.a)`` → ``a``."""
+    if isinstance(e, FuncCall) and e.name == "isundefined" and len(e.args) == 1:
+        return _candidate_attr(e.args[0], request)
+    return None
+
+
+#: a disjunction that folds to true for this request: no term at all
+_ALWAYS = ConjTerm("", "<", 0.0)
+
+
+def _guarded_clause(e: Expr, request: ClassAd, env) -> Optional[ConjTerm]:
+    """A disjunction ``isUndefined(other.a) || <request constants> ||
+    other.a OP c`` (any order, any nesting) → one term on ``a`` that an
+    Undefined ``a`` passes. A constant disjunct that folds to true makes
+    the clause always true (``_ALWAYS``); one that does not cannot make
+    it true and drops out. No guard: the plain term. A guard alone: a
+    term only an Undefined ``a`` passes. Anything else — two terms, two
+    guarded attributes, a guard on another attribute — is None."""
+    guards: set = set()
+    terms: List[ConjTerm] = []
+    for d in _flatten(e, "||"):
+        g = _is_undefined_of(d, request)
+        if g is not None:
+            guards.add(g)
+            continue
+        t = _threshold_term(d, request, env)
+        if t is not None:
+            terms.append(t)
+            continue
+        v = _request_value(d, request, env)
+        if v is None:
+            return None
+        if v is True:
+            return _ALWAYS
+    if len(terms) > 1 or len(guards) > 1:
+        return None
+    if not guards:
+        return terms[0] if terms else None
+    (attr,) = guards
+    if not terms:
+        return ConjTerm(attr, "<", float("-inf"), undefined_passes=True)
+    if terms[0].attr != attr:
+        return None
+    return ConjTerm(attr, terms[0].op, terms[0].threshold, undefined_passes=True)
 
 
 def extract_conjunctive_terms(
@@ -695,26 +831,28 @@ def extract_conjunctive_terms(
     return the terms for the Pallas kernel path; else None.
 
     ``const`` may be any request-side scalar expression (e.g.
-    ``my.reqdSpace * 2``) — it is folded at extraction time.
+    ``my.reqdSpace * 2``) — it is folded at extraction time. A conjunct
+    may also be a guarded disjunction, ``isUndefined(other.a) || <request
+    constants> || other.a OP c``, which becomes one term with
+    ``undefined_passes`` (or drops out when a constant folds to true).
+    A general ``||`` is not lowered.
     """
     terms: List[ConjTerm] = []
 
     def walk(e: Expr) -> bool:
         if isinstance(e, BinOp) and e.op == "&&":
             return walk(e.left) and walk(e.right)
-        if isinstance(e, BinOp) and e.op in OPCODES:
-            # other.attr OP scalar   |   scalar OP other.attr
-            for attr_side, const_side, flip in ((e.left, e.right, False), (e.right, e.left, True)):
-                if isinstance(attr_side, AttrRef) and attr_side.scope in ("other", None):
-                    c = _scalar_of(const_side, request, env)
-                    if c is None:
-                        continue
-                    op = e.op
-                    if flip:
-                        op = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "==": "==", "!=": "!="}[op]
-                    terms.append(ConjTerm(attr_side.name.lower(), op, c))
-                    return True
-            return False
+        if isinstance(e, BinOp) and e.op == "||":
+            t = _guarded_clause(e, request, env)
+            if t is None:
+                return False
+            if t is not _ALWAYS:
+                terms.append(t)
+            return True
+        t = _threshold_term(e, request, env)
+        if t is not None:
+            terms.append(t)
+            return True
         if isinstance(e, Literal) and e.value is True:
             return True
         return False
@@ -733,8 +871,9 @@ def extract_linear_rank(
         weights[attr] = weights.get(attr, 0.0) + w
 
     def walk(e: Expr, scale: float) -> bool:
-        if isinstance(e, AttrRef) and e.scope in ("other", None):
-            add(e.name.lower(), scale)
+        attr = _candidate_attr(e, request)
+        if attr is not None:
+            add(attr, scale)
             return True
         if isinstance(e, BinOp) and e.op == "+":
             return walk(e.left, scale) and walk(e.right, scale)
@@ -762,3 +901,92 @@ def extract_linear_rank(
         return False
 
     return weights if walk(expr, 1.0) else None
+
+
+#: a gate that folds to false for this request: its branch is never taken
+_NEVER: Tuple[ConjTerm, ...] = (ConjTerm("", "<", 0.0),)
+
+
+def _guarded_gate(e: Expr, request: ClassAd, env) -> Optional[Tuple[ConjTerm, ...]]:
+    """An ``ifThenElse`` condition → its terms, all of which must pass.
+
+    The condition must be a conjunction of ``!isUndefined(other.a)``
+    guards, thresholds on guarded attributes and request constants. The
+    guards keep the condition from ever being Undefined — which would make
+    the whole rank Undefined instead of taking the else branch — so it is
+    exactly the conjunction of fail-closed terms. A guard with no
+    threshold of its own becomes the term ``a >= -inf``, which only a
+    defined ``a`` passes. A constant that folds to false gives ``_NEVER``;
+    one that folds to anything but a boolean, an unguarded threshold or
+    any other shape gives None."""
+    guards: set = set()
+    terms: List[ConjTerm] = []
+    for c in _flatten(e, "&&"):
+        if isinstance(c, UnaryOp) and c.op == "!":
+            g = _is_undefined_of(c.operand, request)
+            if g is not None:
+                guards.add(g)
+                continue
+        t = _threshold_term(c, request, env)
+        if t is not None:
+            terms.append(t)
+            continue
+        v = _request_value(c, request, env)
+        if v is False:
+            return _NEVER
+        if v is not True:
+            return None
+    if any(t.attr not in guards for t in terms):
+        return None
+    bare = sorted(guards - {t.attr for t in terms})
+    return tuple(terms) + tuple(ConjTerm(a, ">=", float("-inf")) for a in bare)
+
+
+def _rank_value(
+    e: Expr, request: ClassAd, env
+) -> Optional[Tuple[Dict[str, float], Optional[Dict[str, float]]]]:
+    """A rank branch's value: a linear form, or a quotient of two."""
+    lin = extract_linear_rank(e, request, env=env)
+    if lin is not None:
+        return lin, None
+    if isinstance(e, BinOp) and e.op == "/":
+        num = extract_linear_rank(e.left, request, env=env)
+        den = extract_linear_rank(e.right, request, env=env)
+        if num is not None and den is not None:
+            return num, den
+    return None
+
+
+def extract_rank_alternatives(
+    expr: Expr, request: ClassAd, *, env=None
+) -> Optional[List[RankAlternative]]:
+    """If ``rank`` is a linear form, a quotient of two, or an ``ifThenElse``
+    chain of them whose conditions are guarded conjunctions (see
+    :func:`_guarded_gate`), return its branches in order — the rank is the
+    first branch whose gate holds; the last branch's gate is empty. Else
+    None. Branches that can never be taken are left out; branches after
+    one that is always taken are unreachable and left out too."""
+    alts: List[RankAlternative] = []
+    e = expr
+    while True:
+        if isinstance(e, FuncCall) and e.name == "ifthenelse" and len(e.args) == 3:
+            cond, then, other = e.args
+        elif isinstance(e, Ternary):
+            cond, then, other = e.cond, e.then, e.other
+        else:
+            value = _rank_value(e, request, env)
+            if value is None:
+                return None
+            alts.append(RankAlternative((), *value))
+            return alts
+        gate = _guarded_gate(cond, request, env)
+        if gate is None:
+            return None
+        if gate is not _NEVER:
+            value = _rank_value(then, request, env)
+            if value is None:
+                return None
+            alts.append(RankAlternative(gate, *value))
+            if not gate:
+                return alts
+        e = other

@@ -15,7 +15,15 @@ TPU adaptation of the Match Phase hot loop. Design notes:
     would serialize on the VPU. The per-term plan rides in columns
     (``[8·T_PAD, 1]``) that broadcast along the candidate lanes.
   * All six comparison ops are evaluated vectorized and the per-term op
-    is chosen by boolean masks — branch-free VPU code.
+    is chosen by boolean masks — branch-free VPU code. A term on an
+    invalid attribute takes its opcode's ``UNDEF_PASSES`` flag instead.
+  * Each term row carries a role: a requirement, or a gate term of one of
+    ``RANK_SLOTS`` rank alternatives. The requirements and every gate are
+    one masked ``min`` over the request's term rows. Every alternative's
+    numerator and denominator are computed (a vectorized kernel has no
+    short-circuit) and the first alternative whose gate holds is selected
+    per candidate, so guarded, fallback and plain plans share one program
+    per batch size.
   * The per-request top-k is carried across S-blocks in the resident
     top-k output tile ``[8, K_PAD]``; each of the k rounds is a masked
     ``max`` (best score) then a masked ``min`` (lowest global row among
@@ -40,6 +48,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
+
+from .ref import RANK_SLOTS, UNDEF_PASSES, rank_value
 
 NEG_INF = float("-inf")
 
@@ -70,11 +80,11 @@ def _matchrank_batched_kernel(
     valid_ref,  # [BLOCK_S, A_PAD] f32
     admit_ref,  # [R, BLOCK_S] f32 — the block's pre-masks
     sel_ref,  # [R·T_PAD, A_PAD] f32 — request-major one-hot term rows
-    ops_ref,  # [R·T_PAD, 1] i32
+    ops_ref,  # [R·T_PAD, 1] i32 — opcode, | UNDEF_PASSES
     th_ref,  # [R·T_PAD, 1] f32
-    act_ref,  # [R·T_PAD, 1] f32
-    w_ref,  # [R, A_PAD] f32
-    bias_ref,  # [R, 1] f32
+    role_ref,  # [R·T_PAD, 1] f32 — 0 padding, 1 requirement, 2 + j gate j
+    w_ref,  # [2·RANK_SLOTS·R, A_PAD] f32 — slot-major rank forms
+    bias_ref,  # [2·RANK_SLOTS·R, 1] f32
     # outputs
     mask_ref,  # [R, BLOCK_S] f32
     score_ref,  # [R, BLOCK_S] f32
@@ -97,7 +107,9 @@ def _matchrank_batched_kernel(
     vok = _dot_t(sel, validf) > 0.5
 
     th = th_ref[...]
-    opc = jnp.broadcast_to(ops_ref[...], vals.shape)
+    opw = jnp.broadcast_to(ops_ref[...], vals.shape)
+    undef = opw >= UNDEF_PASSES
+    opc = jnp.where(undef, opw - UNDEF_PASSES, opw)
     cmp = (
         ((opc == 0) & (vals < th))
         | ((opc == 1) & (vals <= th))
@@ -106,18 +118,30 @@ def _matchrank_batched_kernel(
         | ((opc == 4) & (vals == th))
         | ((opc == 5) & (vals != th))
     )
-    act = jnp.broadcast_to(act_ref[...], vals.shape) > 0.5
-    term_ok = jnp.logical_not(act) | (cmp & vok)
-    # a request matches when all of its T_PAD term rows pass
-    passed = term_ok.astype(jnp.float32).reshape(r, t_pad, block_s)
-    mask = jnp.logical_and(jnp.min(passed, axis=1) > 0.5, admit_ref[...] > 0.5)
+    # a valid attribute takes the comparison, an Undefined one the flag
+    term_ok = ((vok & cmp) | (jnp.logical_not(vok) & undef)).astype(jnp.float32)
+    role = jnp.broadcast_to(role_ref[...], vals.shape)
 
-    # ---- linear rank with validity gating ----
+    def all_of(j):  # [R, S]: every term row of role j passes (none: True)
+        rows = jnp.where(role == j, term_ok, 1.0).reshape(r, t_pad, block_s)
+        return jnp.min(rows, axis=1) > 0.5
+
+    mask = jnp.logical_and(all_of(1), admit_ref[...] > 0.5)
+
+    # ---- rank: the first alternative whose gate holds, validity-gated ----
     w = w_ref[...]
-    score_raw = _dot_t(w, attrs) + bias_ref[...]  # [R, S]
+    lin = _dot_t(w, attrs) + bias_ref[...]  # [2·RANK_SLOTS·R, S]
     wactive = (jnp.abs(w) > 0).astype(jnp.float32)
     n_bad = _dot_t(wactive, 1.0 - validf)  # invalid weighted attrs per row
-    rank = jnp.where(n_bad > 0, 0.0, score_raw)
+
+    def slot(x, q):  # slot q's R rows (static, sublane-aligned slice)
+        return x[q * r : (q + 1) * r]
+
+    rank = jnp.zeros((r, block_s), jnp.float32)
+    for j in reversed(range(RANK_SLOTS)):
+        den = slot(lin, RANK_SLOTS + j)
+        bad = (slot(n_bad, j) + slot(n_bad, RANK_SLOTS + j)) > 0
+        rank = jnp.where(all_of(2 + j), rank_value(slot(lin, j), den, bad), rank)
 
     score = jnp.where(mask, rank, NEG_INF)
     mask_ref[...] = mask.astype(jnp.float32)
@@ -135,10 +159,10 @@ def _matchrank_batched_kernel(
         topk_i_ref[...] = jnp.full((r, K_PAD), _NO_ROW, jnp.int32)
 
     row = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (r, block_s), 1)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (r, K_PAD), 1)
+    slot_i = jax.lax.broadcasted_iota(jnp.int32, (r, K_PAD), 1)
     carry_s = topk_s_ref[...]
     carry_i = topk_i_ref[...]
-    carry_live = slot < k
+    carry_live = slot_i < k
     block_live = jnp.ones((r, block_s), dtype=jnp.bool_)
     new_s = jnp.full((r, K_PAD), NEG_INF, jnp.float32)
     new_i = jnp.full((r, K_PAD), _NO_ROW, jnp.int32)
@@ -153,8 +177,8 @@ def _matchrank_batched_kernel(
             jnp.min(jnp.where(take_c, carry_i, _NO_ROW), axis=1, keepdims=True),
             jnp.min(jnp.where(take_b, row, _NO_ROW), axis=1, keepdims=True),
         )
-        new_s = jnp.where(slot == j, best, new_s)
-        new_i = jnp.where(slot == j, pick, new_i)
+        new_s = jnp.where(slot_i == j, best, new_s)
+        new_i = jnp.where(slot_i == j, pick, new_i)
         carry_live = jnp.logical_and(carry_live, carry_i != pick)
         block_live = jnp.logical_and(block_live, row != pick)
     topk_s_ref[...] = new_s
@@ -168,9 +192,9 @@ def matchrank_batched_pallas(
     sel: jnp.ndarray,  # [B, T_PAD, A_PAD] f32
     op_codes: jnp.ndarray,  # [B, T_PAD] i32
     thresholds: jnp.ndarray,  # [B, T_PAD] f32
-    term_active: jnp.ndarray,  # [B, T_PAD] f32
-    weights: jnp.ndarray,  # [B, A_PAD] f32
-    bias: jnp.ndarray,  # [B] f32
+    term_role: jnp.ndarray,  # [B, T_PAD] f32
+    weights: jnp.ndarray,  # [B, 2R, A_PAD] f32: numerators, then denominators
+    bias: jnp.ndarray,  # [B, 2R] f32
     *,
     block_s: int = 512,
     k: int = 1,
@@ -182,8 +206,10 @@ def matchrank_batched_pallas(
     requests that admit nothing; the grid is ``(B_pad // 8, S // block_s)``
     with the candidate axis innermost, so each block of 8 requests keeps
     its plan tiles resident while the shared ``attrs``/``valid`` tiles
-    stream past once. ``interpret=None`` resolves from the platform
-    (:func:`repro.kernels.resolve_interpret`).
+    stream past once. The rank forms ride slot-major within a block of
+    requests (``[2·RANK_SLOTS, 8]`` rows), so each alternative's numerator
+    and denominator are static 8-row slices. ``interpret=None`` resolves
+    from the platform (:func:`repro.kernels.resolve_interpret`).
 
     Returns (mask [B,S] bool, score [B,S] f32, topk_scores [B,k] f32,
     topk_idx [B,k] i32); top-k slots past a request's match count hold
@@ -198,6 +224,8 @@ def matchrank_batched_pallas(
     assert 1 <= k <= min(K_PAD, block_s), (k, block_s)
     r = REQ_BLOCK
     b_pad = -(-b // r) * r
+    nb = b_pad // r
+    q = 2 * RANK_SLOTS
 
     def rows(x):  # pad the request axis with zeros (requests admitting nothing)
         return jnp.pad(x, [(0, b_pad - b)] + [(0, 0)] * (x.ndim - 1))
@@ -206,9 +234,13 @@ def matchrank_batched_pallas(
     sel_rows = rows(sel.astype(jnp.float32)).reshape(b_pad * t_pad, a_pad)
     ops_col = rows(op_codes.astype(jnp.int32)).reshape(b_pad * t_pad, 1)
     th_col = rows(thresholds.astype(jnp.float32)).reshape(b_pad * t_pad, 1)
-    act_col = rows(term_active.astype(jnp.float32)).reshape(b_pad * t_pad, 1)
-    w_rows = rows(weights.astype(jnp.float32))
-    bias_col = rows(bias.astype(jnp.float32)).reshape(b_pad, 1)
+    role_col = rows(term_role.astype(jnp.float32)).reshape(b_pad * t_pad, 1)
+    # the rank forms as [B_pad/8 · 2R · 8, ·] rows: slot-major per block
+    w_rows = (
+        rows(weights.astype(jnp.float32)).reshape(nb, r, q, a_pad).transpose(0, 2, 1, 3)
+        .reshape(nb * q * r, a_pad)
+    )
+    bias_col = rows(bias.astype(jnp.float32)).reshape(nb, r, q).transpose(0, 2, 1).reshape(nb * q * r, 1)
     admit_rows = rows(admit.astype(jnp.float32))
 
     kernel = functools.partial(
@@ -222,9 +254,11 @@ def matchrank_batched_pallas(
         pl.BlockSpec((rt, a_pad), lambda bi, si: (bi, 0)),  # sel
         pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # ops
         pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # thresholds
-        pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # active
-        pl.BlockSpec((r, a_pad), lambda bi, si: (bi, 0)),  # weights
-        pl.BlockSpec((r, 1), lambda bi, si: (bi, 0)),  # bias
+        pl.BlockSpec((rt, 1), lambda bi, si: (bi, 0)),  # roles
+        pl.BlockSpec(
+            (2 * RANK_SLOTS * REQ_BLOCK, a_pad), lambda bi, si: (bi, 0)
+        ),  # rank weights
+        pl.BlockSpec((2 * RANK_SLOTS * REQ_BLOCK, 1), lambda bi, si: (bi, 0)),  # biases
     ]
     out_specs = (
         pl.BlockSpec((r, block_s), lambda bi, si: (bi, si)),  # mask
@@ -240,7 +274,7 @@ def matchrank_batched_pallas(
     )
     mask, score, topk_s, topk_i = pl.pallas_call(
         kernel,
-        grid=(b_pad // r, s // block_s),
+        grid=(nb, s // block_s),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
@@ -249,7 +283,7 @@ def matchrank_batched_pallas(
         ),
         interpret=resolve_interpret(interpret),
     )(
-        attrs, valid, admit_rows, sel_rows, ops_col, th_col, act_col, w_rows,
+        attrs, valid, admit_rows, sel_rows, ops_col, th_col, role_col, w_rows,
         bias_col,
     )
     return mask[:b] > 0.5, score[:b], topk_s[:b, :k], topk_i[:b, :k]
@@ -262,9 +296,9 @@ def matchrank_pallas(
     sel: jnp.ndarray,  # [T_PAD, A_PAD] f32
     op_codes: jnp.ndarray,  # [T_PAD] i32
     thresholds: jnp.ndarray,  # [T_PAD] f32
-    term_active: jnp.ndarray,  # [T_PAD] f32
-    weights: jnp.ndarray,  # [A_PAD] f32
-    bias: jnp.ndarray,  # [1] f32
+    term_role: jnp.ndarray,  # [T_PAD] f32
+    weights: jnp.ndarray,  # [2R, A_PAD] f32
+    bias: jnp.ndarray,  # [2R] f32
     *,
     block_s: int = 512,
     interpret: Optional[bool] = None,
@@ -275,7 +309,7 @@ def matchrank_pallas(
     does it)."""
     mask, score, best_s, best_i = matchrank_batched_pallas(
         attrs, valid, admit[None], sel[None], op_codes[None], thresholds[None],
-        term_active[None], weights[None], jnp.reshape(bias, (1,)),
+        term_role[None], weights[None], bias[None],
         block_s=block_s, k=1, interpret=interpret,
     )
     return mask[0], score[0], best_s[0], best_i[0]

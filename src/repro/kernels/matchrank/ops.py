@@ -25,12 +25,20 @@ from repro.core.classads import ClassAd
 from repro.core.compile import (
     OPCODES,
     CompileError,
+    ConjTerm,
+    RankAlternative,
     extract_conjunctive_terms,
-    extract_linear_rank,
+    extract_rank_alternatives,
 )
 
 from .kernel import matchrank_batched_pallas, matchrank_pallas
-from .ref import NEG_INF, matchrank_batched_ref, matchrank_ref
+from .ref import (
+    NEG_INF,
+    RANK_SLOTS,
+    UNDEF_PASSES,
+    matchrank_batched_ref,
+    matchrank_ref,
+)
 
 __all__ = [
     "KernelPlan",
@@ -62,17 +70,55 @@ def _round_up(x: int, m: int) -> int:
 @dataclass
 class KernelPlan:
     """Kernel operands lowered from a ClassAd request over a fixed
-    attribute vocabulary (column order)."""
+    attribute vocabulary (column order).
+
+    Terms are rows of ``sel``/``op_codes``/``thresholds``/``term_role``.
+    ``term_role`` says what a row is: 0 padding, 1 a requirement, ``2 + j``
+    a gate term of rank alternative j. ``op_codes`` holds the comparison
+    (:data:`repro.core.compile.OPCODES`), plus :data:`UNDEF_PASSES` where
+    an Undefined attribute passes the term. The rank is the first of
+    :data:`RANK_SLOTS` alternatives whose gate terms all pass (a slot with
+    no gate terms always does): ``weights[j]·x + bias[j]`` over
+    ``weights[R + j]·x + bias[R + j]``. Every plan has these shapes, so a
+    batch of any mix of plans stacks to the same operands."""
 
     attr_names: List[str]  # column order, len = A (pre-pad)
     sel: np.ndarray  # [T_PAD, A_PAD]
     op_codes: np.ndarray  # [T_PAD] i32
     thresholds: np.ndarray  # [T_PAD] f32
-    term_active: np.ndarray  # [T_PAD] f32
-    weights: np.ndarray  # [A_PAD] f32
-    bias: np.ndarray  # [1] f32
+    term_role: np.ndarray  # [T_PAD] f32
+    weights: np.ndarray  # [2R, A_PAD] f32: numerators, then denominators
+    bias: np.ndarray  # [2R] f32
     a_pad: int
     t_pad: int
+
+    @functools.cached_property
+    def plain(self) -> bool:
+        """Fail-closed requirement terms and one linear rank — the plans
+        the interval walk and the snapshot's rank orders can answer.
+        Plans are shared read-only through the plan cache, so this is
+        worked out once per plan."""
+        return (
+            not (self.op_codes & UNDEF_PASSES).any()
+            and not (self.term_role > 1.5).any()
+            and not self.weights[RANK_SLOTS].any()
+            and self.bias[RANK_SLOTS] == 1.0
+        )
+
+
+def _encode_linear(lin: Dict[str, float], index: Dict[str, int], w: np.ndarray) -> float:
+    """Write a linear form's weights into one row ``w``; → its bias. A
+    weight on an attribute outside the vocabulary goes to the last padding
+    column, which no row holds: the form is Undefined everywhere."""
+    bias = 0.0
+    for attr, wt in lin.items():
+        if attr == "":
+            bias += wt
+        elif attr in index:
+            w[index[attr]] += np.float32(wt)
+        elif wt != 0:
+            w[-1] += np.float32(wt)
+    return bias
 
 
 def lower_request(
@@ -84,64 +130,71 @@ def lower_request(
 ) -> KernelPlan:
     """Lower (requirements, rank) to kernel operands, or raise CompileError.
 
-    This is the 'predicate pushdown' contract: the request must be a
-    conjunction of threshold comparisons and a linear rank — the common
-    case for storage selection (space/bandwidth gates, bandwidth rank).
-    Anything richer takes the columnar-JAX or interpreter path instead.
+    This is the 'predicate pushdown' contract
+    (:func:`repro.core.compile.extract_conjunctive_terms`,
+    :func:`~repro.core.compile.extract_rank_alternatives`): requirements
+    that are a conjunction of threshold comparisons, each possibly guarded
+    as ``isUndefined(other.a) || <request constants> || other.a OP c``;
+    a rank that is a linear form, a quotient of two, or an ``ifThenElse``
+    chain of at most :data:`RANK_SLOTS` of them gated by guarded
+    conjunctions — the broker's default read ad among them. Anything
+    richer (a general ``||``, an unguarded gate, a product of attributes)
+    takes the columnar-JAX or interpreter path instead.
     """
     names = [n.lower() for n in attr_names]
     index = {n: i for i, n in enumerate(names)}
     a = len(names)
     a_pad = max(_round_up(a, 128), 128)
 
+    rows: List[Tuple[ConjTerm, int]] = []  # (term, role)
     req = request.lookup_expr("requirements")
-    terms = []
     if req is not None:
         extracted = extract_conjunctive_terms(req, request, env=env)
         if extracted is None:
             raise CompileError("requirements not conjunctive-threshold")
-        terms = extracted
-    if len(terms) > t_pad:
-        t_pad = _round_up(len(terms), 8)
+        rows += [(t, 1) for t in extracted]
+    alts = [RankAlternative((), {})]  # no rank: 0.0
+    rank_expr = request.lookup_expr("rank")
+    if rank_expr is not None:
+        alts = extract_rank_alternatives(rank_expr, request, env=env)
+        if alts is None:
+            raise CompileError("rank not a chain of guarded linear alternatives")
+        if len(alts) > RANK_SLOTS:
+            raise CompileError(f"rank chain of {len(alts)} > {RANK_SLOTS} alternatives")
+    for j, alt in enumerate(alts):
+        rows += [(t, 2 + j) for t in alt.gate]
+    # an attribute outside the vocabulary is Undefined for every candidate:
+    # a term that Undefined passes always passes, any other never does
+    rows = [(t, role) for t, role in rows if t.attr in index or not t.undefined_passes]
+    if len(rows) > t_pad:
+        t_pad = _round_up(len(rows), 8)
 
     sel = np.zeros((t_pad, a_pad), dtype=np.float32)
     op_codes = np.zeros((t_pad,), dtype=np.int32)
     thresholds = np.zeros((t_pad,), dtype=np.float32)
-    term_active = np.zeros((t_pad,), dtype=np.float32)
-    for t, term in enumerate(terms):
+    term_role = np.zeros((t_pad,), dtype=np.float32)
+    for t, (term, role) in enumerate(rows):
+        term_role[t] = role
         if term.attr not in index:
-            # attribute absent from the vocabulary: every candidate is
-            # Undefined on it ⇒ nothing can match. Encode as an
-            # always-false active term on column 0.
+            # never passes: an always-false term on column 0
             sel[t, 0] = 1.0
             op_codes[t] = OPCODES["<"]
             thresholds[t] = float("-inf")
-            term_active[t] = 1.0
             continue
         sel[t, index[term.attr]] = 1.0
-        op_codes[t] = OPCODES[term.op]
+        op_codes[t] = OPCODES[term.op] | (UNDEF_PASSES if term.undefined_passes else 0)
         thresholds[t] = np.float32(term.threshold)
-        term_active[t] = 1.0
 
-    rank_expr = request.lookup_expr("rank")
-    weights = np.zeros((a_pad,), dtype=np.float32)
-    bias = np.zeros((1,), dtype=np.float32)
-    if rank_expr is not None:
-        lin = extract_linear_rank(rank_expr, request, env=env)
-        if lin is None:
-            raise CompileError("rank not linear")
-        for attr, w in lin.items():
-            if attr == "":
-                bias[0] += np.float32(w)
-            elif attr in index:
-                weights[index[attr]] += np.float32(w)
-            # weight on an unknown attribute ⇒ rank Undefined ⇒ 0 for all;
-            # encode by an impossible validity demand: weight on padding col
-            else:
-                weights[a_pad - 1] += np.float32(w) if w != 0 else 0.0
+    weights = np.zeros((2 * RANK_SLOTS, a_pad), dtype=np.float32)
+    bias = np.zeros((2 * RANK_SLOTS,), dtype=np.float32)
+    bias[RANK_SLOTS:] = 1.0
+    for j, alt in enumerate(alts):
+        bias[j] = _encode_linear(alt.num, index, weights[j])
+        if alt.den is not None:
+            bias[RANK_SLOTS + j] = _encode_linear(alt.den, index, weights[RANK_SLOTS + j])
 
     return KernelPlan(
-        list(names), sel, op_codes, thresholds, term_active, weights, bias, a_pad, t_pad
+        list(names), sel, op_codes, thresholds, term_role, weights, bias, a_pad, t_pad
     )
 
 
@@ -155,9 +208,9 @@ class BatchedPlan:
     sel: np.ndarray  # [B, T_PAD, A_PAD]
     op_codes: np.ndarray  # [B, T_PAD] i32
     thresholds: np.ndarray  # [B, T_PAD] f32
-    term_active: np.ndarray  # [B, T_PAD] f32
-    weights: np.ndarray  # [B, A_PAD] f32
-    bias: np.ndarray  # [B] f32
+    term_role: np.ndarray  # [B, T_PAD] f32
+    weights: np.ndarray  # [B, 2R, A_PAD] f32
+    bias: np.ndarray  # [B, 2R] f32
     a_pad: int
     t_pad: int
 
@@ -189,9 +242,9 @@ def stack_plans(plans: Sequence[KernelPlan]) -> BatchedPlan:
         sel=np.stack([pt(p.sel) for p in plans]),
         op_codes=np.stack([pt(p.op_codes) for p in plans]),
         thresholds=np.stack([pt(p.thresholds) for p in plans]),
-        term_active=np.stack([pt(p.term_active) for p in plans]),
+        term_role=np.stack([pt(p.term_role) for p in plans]),
         weights=np.stack([p.weights for p in plans]),
-        bias=np.concatenate([p.bias for p in plans]),
+        bias=np.stack([p.bias for p in plans]),
         a_pad=first.a_pad,
         t_pad=t_pad,
     )
@@ -222,16 +275,16 @@ def pad_columns(
     jax.jit, static_argnames=("block_s", "use_kernel", "interpret")
 )
 def _dispatch(
-    attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
+    attrs, valid, admit, sel, op_codes, thresholds, term_role, weights, bias,
     *, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     if use_kernel:
         return matchrank_pallas(
-            attrs, valid, admit, sel, op_codes, thresholds, term_active,
+            attrs, valid, admit, sel, op_codes, thresholds, term_role,
             weights, bias, block_s=block_s, interpret=interpret,
         )
     return matchrank_ref(
-        attrs, valid, sel, op_codes, thresholds, term_active, weights, bias, admit
+        attrs, valid, sel, op_codes, thresholds, term_role, weights, bias, admit
     )
 
 
@@ -239,12 +292,12 @@ def _dispatch(
     jax.jit, static_argnames=("k", "block_s", "use_kernel", "interpret")
 )
 def _dispatch_topk(
-    attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
+    attrs, valid, admit, sel, op_codes, thresholds, term_role, weights, bias,
     *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     """Fused scores + top-k in one jitted program — no host round-trip."""
     mask, score, _, _ = _dispatch(
-        attrs, valid, admit, sel, op_codes, thresholds, term_active, weights,
+        attrs, valid, admit, sel, op_codes, thresholds, term_role, weights,
         bias, block_s=block_s, use_kernel=use_kernel, interpret=interpret,
     )
     vals, idx = jax.lax.top_k(score, k)
@@ -255,16 +308,16 @@ def _dispatch_topk(
     jax.jit, static_argnames=("k", "block_s", "use_kernel", "interpret")
 )
 def _dispatch_batched(
-    attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
+    attrs, valid, admit, sel, op_codes, thresholds, term_role, weights, bias,
     *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
     if use_kernel:
         return matchrank_batched_pallas(
-            attrs, valid, admit, sel, op_codes, thresholds, term_active,
+            attrs, valid, admit, sel, op_codes, thresholds, term_role,
             weights, bias, block_s=block_s, k=k, interpret=interpret,
         )
     return matchrank_batched_ref(
-        attrs, valid, admit, sel, op_codes, thresholds, term_active, weights,
+        attrs, valid, admit, sel, op_codes, thresholds, term_role, weights,
         bias, k=k,
     )
 
@@ -305,10 +358,11 @@ def _matchrank_batched_dense_host(
     Terms are grouped by (column, opcode) — one vectorized compare per
     group serves every request that asked it (broker batches are
     near-duplicate plans differing only in thresholds) — and rank forms
-    by (weights, bias) — one [S, A] matvec per distinct rank expression.
-    Semantics are element-identical to :func:`.ref.matchrank_batched_ref`
-    (fail-closed Undefined terms, Condor rank-Undefined → 0.0, top-k
-    ties → lowest row index).
+    by (weights, bias) — one [S, A] matvec per alternative of each
+    distinct rank expression. Semantics are element-identical to
+    :func:`.ref.matchrank_batched_ref` (fail-closed Undefined terms unless
+    flagged, the first alternative whose gate holds, Condor
+    rank-Undefined → 0.0, top-k ties → lowest row index).
     """
     a_host = np.asarray(attrs, dtype=np.float32)[:s]
     v_raw = np.asarray(valid)[:s]
@@ -326,45 +380,75 @@ def _matchrank_batched_dense_host(
     else:
         mask[:] = np.asarray(admit)[:, :s] > 0.5
 
-    act = batched.term_active > 0.5  # [B, T]
+    role = np.rint(batched.term_role).astype(np.int64)  # [B, T]
     cols = batched.sel.argmax(axis=2)  # [B, T] — one-hot column per term
-    groups: Dict[Tuple[int, int], List[Tuple[int, np.float32]]] = {}
+    groups: Dict[Tuple[int, int], List[Tuple[int, int, np.float32]]] = {}
     for bi in range(b):
-        for t in np.nonzero(act[bi])[0]:
+        for t in np.nonzero(role[bi])[0]:
             key = (int(cols[bi, t]), int(batched.op_codes[bi, t]))
             groups.setdefault(key, []).append(
-                (bi, np.float32(batched.thresholds[bi, t]))
+                (bi, int(role[bi, t]), np.float32(batched.thresholds[bi, t]))
             )
+    gates: Dict[Tuple[int, int], np.ndarray] = {}  # (request, slot) → [S] bool
     for (c, op), members in groups.items():
-        thr = np.array([m[1] for m in members], dtype=np.float32)
+        thr = np.array([m[2] for m in members], dtype=np.float32)
         colv = np.ascontiguousarray(a_host[:, c])  # strided col read once
+        ok = vcol(c)
         # [M, S] — member rows contiguous for the fold below
-        passed = _CMP_OPS[op](colv[None, :], thr[:, None]) & vcol(c)[None, :]
-        for j, (bi, _) in enumerate(members):
-            mask[bi] &= passed[j]
+        passed = _CMP_OPS[op & ~UNDEF_PASSES](colv[None, :], thr[:, None]) & ok[None, :]
+        if op & UNDEF_PASSES:
+            passed |= ~ok[None, :]
+        for j, (bi, rl, _) in enumerate(members):
+            if rl == 1:
+                mask[bi] &= passed[j]
+            else:
+                g = gates.get((bi, rl - 2))
+                gates[(bi, rl - 2)] = passed[j].copy() if g is None else g & passed[j]
 
-    rgroups: Dict[Tuple[bytes, float], List[int]] = {}
+    def value(w: np.ndarray, bias: np.ndarray, j: int) -> np.ndarray:
+        """Alternative j of a rank form: num / den, 0.0 where Undefined."""
+        wn, wd = w[j], w[RANK_SLOTS + j]
+        if (np.abs(wn[na:]) > 0).any() or (np.abs(wd[na:]) > 0).any():
+            # weight on a padding column = the value references an
+            # attribute outside the vocabulary ⇒ Undefined ⇒ 0.0
+            return np.zeros((s,), dtype=np.float32)
+        num = (a_host @ wn[:aw] + bias[j]).astype(np.float32)
+        wcols = np.nonzero(wn[:aw])[0]
+        if wd[:aw].any():
+            den = (a_host @ wd[:aw] + bias[RANK_SLOTS + j]).astype(np.float32)
+            wcols = np.union1d(wcols, np.nonzero(wd[:aw])[0])
+        else:
+            den = np.full((s,), bias[RANK_SLOTS + j], dtype=np.float32)
+        okw = den != 0
+        for c in wcols:
+            okw &= vcol(c)
+        one = den == 1
+        if not one.all():
+            num = np.where(one, num, num / np.where(okw, den, np.float32(1)))
+        return np.where(okw, num, np.float32(0)).astype(np.float32)
+
+    rgroups: Dict[Tuple[bytes, bytes], List[int]] = {}
     for bi in range(b):
-        rkey = (batched.weights[bi].tobytes(), float(batched.bias[bi]))
+        rkey = (batched.weights[bi].tobytes(), batched.bias[bi].tobytes())
         rgroups.setdefault(rkey, []).append(bi)
     score = np.empty((b, s), dtype=np.float32)
-    for (wb, bias), members in rgroups.items():
-        wv = np.frombuffer(wb, dtype=np.float32)
-        if (np.abs(wv[na:]) > 0).any():
-            # weight on a padding column = rank references an attribute
-            # outside the vocabulary ⇒ Undefined ⇒ 0.0 for every row
-            sv = np.zeros((s,), dtype=np.float32)
-        else:
-            w = wv[:aw]
-            sv = (a_host @ w + np.float32(bias)).astype(np.float32)
-            wcols = np.nonzero(w)[0]
-            if wcols.size:
-                okw = vcol(wcols[0]).copy()
-                for c in wcols[1:]:
-                    okw &= vcol(c)
-                sv[~okw] = 0.0
+    for members in rgroups.values():
+        w, bias = batched.weights[members[0]], batched.bias[members[0]]
+        values: Dict[int, np.ndarray] = {}
         for bi in members:
-            score[bi] = sv
+            out = np.zeros((s,), dtype=np.float32)
+            open_ = np.ones((s,), dtype=bool)  # rows no earlier gate took
+            for j in range(RANK_SLOTS):
+                if j not in values:
+                    values[j] = value(w, bias, j)
+                g = gates.get((bi, j))
+                if g is None:  # no gate terms: every open row takes it
+                    out[open_] = values[j][open_]
+                    break
+                take = open_ & g
+                out[take] = values[j][take]
+                open_ &= ~g
+            score[bi] = out
 
     out_score = np.where(mask, score, np.float32(NEG_INF))
     keff = min(k, s)
@@ -436,7 +520,7 @@ def matchrank(
     mask, score, best_s, best_i = _dispatch(
         attrs_p, valid_p, jnp.asarray(admit_p),
         jnp.asarray(plan.sel), jnp.asarray(plan.op_codes),
-        jnp.asarray(plan.thresholds), jnp.asarray(plan.term_active),
+        jnp.asarray(plan.thresholds), jnp.asarray(plan.term_role),
         jnp.asarray(plan.weights), jnp.asarray(plan.bias),
         block_s=block_s, use_kernel=use_kernel, interpret=interpret,
     )
@@ -475,7 +559,7 @@ def matchrank_topk(
     vals, idx = _dispatch_topk(
         attrs_p, valid_p, jnp.asarray(admit_p),
         jnp.asarray(plan.sel), jnp.asarray(plan.op_codes),
-        jnp.asarray(plan.thresholds), jnp.asarray(plan.term_active),
+        jnp.asarray(plan.thresholds), jnp.asarray(plan.term_role),
         jnp.asarray(plan.weights), jnp.asarray(plan.bias),
         k=min(k, s), block_s=block_s, use_kernel=use_kernel,
         interpret=interpret,
@@ -554,7 +638,7 @@ def _batched_launch(
     operands = (
         attrs_p, valid_p, jnp.asarray(admit_p),
         jnp.asarray(batched.sel), jnp.asarray(batched.op_codes),
-        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_active),
+        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_role),
         jnp.asarray(batched.weights), jnp.asarray(batched.bias),
     )
     return operands, dict(k=min(k, s), block_s=block_s, use_kernel=True), s

@@ -143,7 +143,7 @@ def merge_topk_pallas(
     jax.jit, static_argnames=("k", "block_s", "use_kernel", "interpret")
 )
 def _stage1_sharded(
-    attrs, valid, admit, sel, op_codes, thresholds, term_active, weights, bias,
+    attrs, valid, admit, sel, op_codes, thresholds, term_role, weights, bias,
     offsets,
     *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
@@ -154,12 +154,12 @@ def _stage1_sharded(
     def one(a, v, ad):
         if use_kernel:
             _, _, tks, tki = matchrank_batched_pallas(
-                a, v, ad, sel, op_codes, thresholds, term_active, weights,
+                a, v, ad, sel, op_codes, thresholds, term_role, weights,
                 bias, block_s=block_s, k=k, interpret=interpret,
             )
         else:
             _, _, tks, tki = matchrank_batched_ref(
-                a, v, ad, sel, op_codes, thresholds, term_active, weights,
+                a, v, ad, sel, op_codes, thresholds, term_role, weights,
                 bias, k=k,
             )
         return tks, tki
@@ -229,7 +229,7 @@ def sharded_matchrank_topk(
     cand_s, cand_i = _stage1_sharded(
         attrs, valid, jnp.asarray(admit_g),
         jnp.asarray(batched.sel), jnp.asarray(batched.op_codes),
-        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_active),
+        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_role),
         jnp.asarray(batched.weights), jnp.asarray(batched.bias),
         jnp.asarray(np.asarray(offsets, dtype=np.int32)),
         k=k, block_s=block_s, use_kernel=use_kernel, interpret=interpret,

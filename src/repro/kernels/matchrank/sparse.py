@@ -83,7 +83,10 @@ def _below(v: np.float32) -> np.float32:
 def _plan_interval(plan, n_attrs: int):
     """Per-plan interval fold, memoized on the plan object (plans are
     shared across calls via the PlanCache, so the Python term walk is
-    paid once per distinct request shape). Returns None for ``!=``."""
+    paid once per distinct request shape). Returns None for ``!=``, and
+    for a plan that is not :attr:`~.ops.KernelPlan.plain` — terms that
+    Undefined passes, gated rank alternatives or a quotient rank — which
+    neither intervals nor one rank order per epoch can answer."""
     cached = getattr(plan, "_interval_cache", None)
     if cached is not None and cached[0] == n_attrs:
         return cached[1]
@@ -91,12 +94,12 @@ def _plan_interval(plan, n_attrs: int):
     hi = np.full((n_attrs,), np.inf, dtype=np.float32)
     used = np.zeros((n_attrs,), dtype=bool)
     result = None
-    active = np.asarray(plan.term_active) > 0.5
+    active = np.asarray(plan.term_role) > 0.5
     sel = np.asarray(plan.sel)
     ops = np.asarray(plan.op_codes)
     thr = np.asarray(plan.thresholds, dtype=np.float32)
-    ok = True
-    for t in range(sel.shape[0]):
+    ok = plan.plain
+    for t in range(sel.shape[0] if ok else 0):
         if not active[t]:
             continue
         c = int(sel[t].argmax())
@@ -125,11 +128,11 @@ def _plan_interval(plan, n_attrs: int):
             break
         used[c] = True
     if ok:
-        w_full = np.asarray(plan.weights, dtype=np.float32)
+        w_full = np.asarray(plan.weights[0], dtype=np.float32)
         # weight on a padding column = rank references an out-of-vocabulary
         # attribute ⇒ rank Undefined ⇒ 0.0 for every candidate
         undef = bool((w_full[n_attrs:] != 0).any())
-        bias = np.float32(np.asarray(plan.bias).reshape(-1)[0])
+        bias = np.float32(plan.bias[0])
         result = (lo, hi, used, w_full[:n_attrs], bias, undef)
     try:
         plan._interval_cache = (n_attrs, result)

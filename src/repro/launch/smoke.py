@@ -17,6 +17,16 @@ the tier-1 program holds a Mosaic ``tpu_custom_call`` (so the kernel is
 compiled, not interpreted), and whether every ranking equals that of the
 paper-faithful interpreter (a default broker's ``select``) on the same grid.
 
+A second phase then serves the broker's default read ad (requests sent
+with no ad) with the branches of its rank chain in play: per-source
+transfer history for this client on a third of the endpoints, site
+averages on another third, open or half-open breakers on a tenth, all
+published through the GRIS per-source and summary paths. After the
+snapshot's TTL lapses, :data:`DEFAULT_FLUSHES` size flushes of ``batch``
+default-ad requests take the same compiled launch; their rankings must
+equal the interpreter's, and each branch of the chain must rank at least
+:data:`MIN_BRANCH_SHARE` of the ranked rows.
+
 Times are host wall-clock readings of this one run (each flush ends with
 its results on the host), not benchmark metrics.
 """
@@ -35,10 +45,18 @@ from repro.core.classads import ClassAd, parse_classad
 from repro.serve.scheduler import BatchScheduler
 from repro.storage.endpoint import DataGrid, build_demo_grid
 
-__all__ = ["SmokeReport", "build_smoke_grid", "run_smoke"]
+__all__ = ["DefaultAdPhase", "SmokeReport", "build_smoke_grid", "publish_history", "run_smoke"]
 
 GiB = 1 << 30
+MiB = 1 << 20
 CLIENT = "client://smoke"
+#: the least share of ranked rows each branch of the default read ad's
+#: rank chain must take in the default-ad phase
+MIN_BRANCH_SHARE = 0.10
+#: size flushes of requests sent with no ad in the default-ad phase
+DEFAULT_FLUSHES = 4
+#: the chain's branches, by the attribute that ranks a row
+BRANCHES = ("EwmaRDBandwidthToSource", "AvgRDBandwidth", "static")
 
 
 def build_smoke_grid(
@@ -85,6 +103,44 @@ def _requests(rng: np.random.Generator, lfns: List[str], n: int) -> List[Tuple[s
     return out
 
 
+def publish_history(grid: DataGrid, seed: int) -> Dict[str, str]:
+    """Publish what the default read ad's rank chain reads: this client's
+    per-source EWMA on a third of the endpoints, a site summary (average
+    and maximum read bandwidth) on another third, and a breaker this
+    client tripped (open, 1, or half-open, 0.5) on a tenth drawn apart.
+    Bandwidths are whole MiB/s, exact in f32. → endpoint → the branch
+    that ranks it."""
+    rng = np.random.default_rng([seed, 4])
+    urls = list(grid.endpoints)
+    order = rng.permutation(len(urls))
+    third = len(urls) // 3
+    branch = {}
+    for k, i in enumerate(order):
+        gris = grid.endpoints[urls[i]].gris
+        if k < third:
+            gris.publish_source_bandwidth(CLIENT, {
+                "lastRDBandwidth": 0.0, "lastRDurl": "", "lastWRBandwidth": 0.0,
+                "lastWRurl": "",
+                "EwmaRDBandwidthToSource": float(rng.integers(64, 1024) * MiB),
+            })
+            branch[urls[i]] = BRANCHES[0]
+        elif k < 2 * third:
+            gris.publish_bandwidth_summary({
+                "MaxRDBandwidth": float(rng.integers(64, 2048) * MiB),
+                "MinRDBandwidth": 0.0,
+                "AvgRDBandwidth": float(rng.integers(64, 1024) * MiB),
+                "MaxWRBandwidth": 0.0, "MinWRBandwidth": 0.0, "AvgWRBandwidth": 0.0,
+            })
+            branch[urls[i]] = BRANCHES[1]
+        else:
+            branch[urls[i]] = BRANCHES[2]
+    for i in rng.choice(len(urls), size=len(urls) // 10, replace=False):
+        grid.endpoints[urls[i]].gris.publish_source_health(
+            CLIENT, {"breakerOpenToSource": float(rng.choice([1.0, 0.5]))}
+        )
+    return branch
+
+
 def _outcome(result: Any) -> Tuple[str, List[Tuple[str, float]]]:
     """A selection outcome as (error name or "ok", [(endpoint, rank)])."""
     if isinstance(result, BaseException):
@@ -102,6 +158,28 @@ def _same(got, want, rtol: float = 1e-6) -> bool:
 
 
 @dataclass
+class DefaultAdPhase:
+    """The default-ad phase: requests sent with no ad, answered by the
+    stacked kernel, against the interpreter."""
+
+    requests: int
+    guarded_requests: int  # batched_kernel_guarded_requests in the phase
+    kernel_launches: int
+    paths: Dict[str, int]
+    mismatches: List[str] = field(default_factory=list)
+    branch_share: Dict[str, float] = field(default_factory=dict)  # of ranked rows
+
+    @property
+    def ok(self) -> bool:
+        return (
+            self.paths == {"batched_kernel": self.requests}
+            and self.guarded_requests == self.requests
+            and not self.mismatches
+            and all(self.branch_share.get(b, 0.0) >= MIN_BRANCH_SHARE for b in BRANCHES)
+        )
+
+
+@dataclass
 class SmokeReport:
     endpoints: int
     snapshot_shape: Tuple[int, int]
@@ -111,6 +189,7 @@ class SmokeReport:
     kernel_launches: int  # timed flushes' broker.kernel_launch spans with use_kernel
     paths: Dict[str, int]  # kernel_path → requests
     matched: int  # requests with at least one ranked replica
+    default_ads: DefaultAdPhase
     mismatches: List[str] = field(default_factory=list)
     mosaic: bool = False  # the tier-1 program holds a tpu_custom_call
     compile_s: float = 0.0
@@ -121,12 +200,14 @@ class SmokeReport:
 
     @property
     def ok(self) -> bool:
-        """Every request took the kernel tier and matched the interpreter."""
+        """Every request took the kernel tier and matched the interpreter,
+        in both phases."""
         return (
             self.paths == {"batched_kernel": self.requests}
             and self.kernel_requests == self.requests
             and self.kernel_launches == self.timed_flushes
             and not self.mismatches
+            and self.default_ads.ok
         )
 
     def lines(self) -> List[str]:
@@ -148,6 +229,18 @@ class SmokeReport:
             "host spans per timed flush (s): "
             + ", ".join(f"{k} {v}" for k, v in sorted(self.span_s.items())),
             f"peak_bytes_in_use: {self.peak_bytes}",
+        ] + self._default_ad_lines()
+
+    def _default_ad_lines(self) -> List[str]:
+        d = self.default_ads
+        share = ", ".join(f"{b} {d.branch_share.get(b, 0.0):.3f}" for b in BRANCHES)
+        return [
+            f"default-ad phase: {d.requests} requests sent with no ad; kernel paths"
+            f" {d.paths}; guarded plans {d.guarded_requests}; kernel launches"
+            f" {d.kernel_launches}",
+            f"default-ad rankings equal the interpreter's: {not d.mismatches}"
+            f" ({len(d.mismatches)} differ)",
+            f"default-ad ranked rows by branch: {share} (each at least {MIN_BRANCH_SHARE})",
         ]
 
 
@@ -222,17 +315,13 @@ def run_smoke(
     matched = 0
     queries = [q for flight in flights for q in flight]
     for (lfn, req), ticket in zip(queries, tickets):
-        try:
-            got = _outcome(ticket.result())
-        except BrokerError as e:
-            got = _outcome(e)
-        try:
-            want = _outcome(reference.select(lfn, req))
-        except BrokerError as e:
-            want = _outcome(e)
+        got, want = _compare(ticket, reference, lfn, req)
         matched += got[0] == "ok"
         if not _same(got, want):
             mismatches.append(f"{lfn}: kernel {got} != interpreter {want}")
+    kernel_requests = broker.stats["batched_kernel_requests"]
+
+    default_ads = _default_ad_phase(grid, broker, sched, reference, lfns, rng, batch, seed)
 
     stats = jax.devices()[0].memory_stats() or {}
     return SmokeReport(
@@ -240,7 +329,7 @@ def run_smoke(
         snapshot_shape=tuple(attrs.shape),
         requests=len(queries),
         timed_flushes=flushes,
-        kernel_requests=broker.stats["batched_kernel_requests"],
+        kernel_requests=kernel_requests,
         kernel_launches=launches,
         paths=paths,
         matched=matched,
@@ -251,4 +340,65 @@ def run_smoke(
         flush_s=flush_s,
         span_s=span_s,
         peak_bytes=stats.get("peak_bytes_in_use"),
+        default_ads=default_ads,
+    )
+
+
+def _compare(ticket, reference, lfn: str, req: Optional[ClassAd]):
+    """(served outcome, the interpreter's outcome) of one request."""
+    try:
+        got = _outcome(ticket.result())
+    except BrokerError as e:
+        got = _outcome(e)
+    try:
+        want = _outcome(reference.select(lfn, req))
+    except BrokerError as e:
+        want = _outcome(e)
+    return got, want
+
+
+def _default_ad_phase(
+    grid: DataGrid, broker, sched: BatchScheduler, reference, lfns: List[str],
+    rng: np.random.Generator, batch: int, seed: int,
+) -> DefaultAdPhase:
+    """Publish the history the rank chain reads, let the snapshot's TTL
+    lapse, and flush :data:`DEFAULT_FLUSHES` × ``batch`` requests sent with
+    no ad."""
+    branch = publish_history(grid, seed)
+    grid.clock.advance(broker.snapshot_ttl + 1.0)  # the next flush rebuilds
+    guarded0 = broker.stats["batched_kernel_guarded_requests"]
+    broker.tracer.clear()
+    queries: List[Tuple[str, None]] = []
+    tickets = []
+    paths: Dict[str, int] = {}
+    for _ in range(DEFAULT_FLUSHES):
+        flight = [(lfns[int(rng.integers(len(lfns)))], None) for _ in range(batch)]
+        flight_tickets = [sched.submit(lfn, None) for lfn, _ in flight]
+        if not all(t.done for t in flight_tickets):
+            raise RuntimeError("a full batch did not trigger a size flush")
+        for rid in broker.last_request_ids:
+            p = broker.explain(rid).kernel_path
+            paths[p] = paths.get(p, 0) + 1
+        queries.extend(flight)
+        tickets.extend(flight_tickets)
+    launches = sum(
+        bool(sp.args.get("use_kernel"))
+        for sp in broker.tracer.spans("broker.kernel_launch")
+    )
+    mismatches: List[str] = []
+    ranked = {b: 0 for b in BRANCHES}
+    for (lfn, _), ticket in zip(queries, tickets):
+        got, want = _compare(ticket, reference, lfn, None)
+        if not _same(got, want):
+            mismatches.append(f"{lfn}: kernel {got} != interpreter {want}")
+        for url, _ in want[1]:
+            ranked[branch[url]] += 1
+    rows = sum(ranked.values())
+    return DefaultAdPhase(
+        requests=len(queries),
+        guarded_requests=broker.stats["batched_kernel_guarded_requests"] - guarded0,
+        kernel_launches=launches,
+        paths=paths,
+        mismatches=mismatches,
+        branch_share={b: ranked[b] / rows if rows else 0.0 for b in BRANCHES},
     )

@@ -318,6 +318,28 @@ class TestInjectedLintViolations:
         diags = check_kernel_source(doctored, "repro/kernels/matchrank/bad.py")
         assert rules(diags) == ["KRN001"]
 
+    def test_matchrank_rank_slot_blockspecs(self):
+        """The matchrank kernel sizes its rank-form blocks by two module
+        constants, ``2 * RANK_SLOTS * REQ_BLOCK`` rows; the checker must
+        resolve them — the real kernel passes, a slot count that breaks
+        the sublane tiling is flagged."""
+        from repro.analysis import check_kernel_file
+
+        real = os.path.join(SRC, "kernels", "matchrank", "kernel.py")
+        assert check_kernel_file(real) == []
+        doctored = (
+            "import jax.experimental.pallas as pl\n"
+            "REQ_BLOCK = 8\n"
+            "RANK_SLOTS = 3\n"
+            "def launch(nb, a_pad, block_s=512):\n"
+            "    grid = (nb, 4)\n"
+            "    ok = pl.BlockSpec((2 * RANK_SLOTS * REQ_BLOCK, 1), lambda bi, si: (bi, 0))\n"
+            "    bad = pl.BlockSpec((RANK_SLOTS, block_s), lambda bi, si: (bi, si))\n"
+        )
+        diags = check_kernel_source(doctored, "repro/kernels/matchrank/bad.py")
+        assert rules(diags) == ["KRN002"]
+        assert diags[0].span.line == 7
+
 
 class TestCleanTree:
     def test_repo_sources_and_ads_have_zero_findings(self):

@@ -189,14 +189,21 @@ class TestSelectMany:
             "reqdSpace = 0; rank = other.diskTransferRate;"
             "requirements = other.replicaSize > 0;"
         )
+        # a general || ⇒ columnar tier
+        general_or = parse_classad(
+            "reqdSpace = 0; rank = other.diskTransferRate;"
+            "requirements = other.availableSpace > 1M || other.loadFactor < 2;"
+        )
         queries = [
             ("shard-000", conj),
-            ("shard-001", None),  # columnar tier (isUndefined/ifThenElse)
+            ("shard-001", general_or),
             ("shard-002", per_replica),
+            ("shard-001", None),  # the default read ad lowers to the kernel
         ]
         want = [b.select(lfn, req) for lfn, req in queries]
         got = b.select_many(queries)
-        assert b.stats["batched_kernel_requests"] == 1
+        assert b.stats["batched_kernel_requests"] == 2
+        assert b.stats["batched_kernel_guarded_requests"] == 1
         assert b.stats["batched_columnar_requests"] == 1
         assert b.stats["batched_interp_requests"] == 1
         for g_, w in zip(got, want):

@@ -35,6 +35,20 @@ def test_smoke_core_tiny_interpret():
     assert len(report.flush_s) == 2
 
 
+def test_smoke_default_ad_phase_tiny():
+    """The default-ad phase: history, site averages and breakers published,
+    requests sent with no ad, the kernel against the interpreter."""
+    report = run_smoke(endpoints=96, files=48, replicas=4, flushes=1, batch=8, seed=5)
+    d = report.default_ads
+    assert d.requests == 32 and d.paths == {"batched_kernel": 32}
+    assert d.guarded_requests == 32 and d.kernel_launches == 4
+    assert d.mismatches == []
+    assert set(d.branch_share) == {"EwmaRDBandwidthToSource", "AvgRDBandwidth", "static"}
+    assert min(d.branch_share.values()) >= 0.1 and abs(sum(d.branch_share.values()) - 1) < 1e-9
+    assert d.ok and report.ok
+    assert report.kernel_requests == report.requests == 16  # the first phase's own count
+
+
 def test_compile_cache_dir(monkeypatch):
     monkeypatch.setenv(cache.ENV_VAR, "/elsewhere/cache")
     assert cache.compile_cache_dir() == "/elsewhere/cache"

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bwstats import ops as bw_ops
 from repro.kernels.matchrank import ops as mr_ops
+from repro.kernels.matchrank import ref as mr_ref
 from repro.kernels.matchrank.sharded import _stage1_sharded, merge_topk_pallas
 
 S, A_PAD, T_PAD, B = 10_240, 128, 16, 64
@@ -57,13 +58,16 @@ def _compile(fn, *args):
 
 
 def _plan_shapes(sds, b):
+    """Plan operands as ``lower_request`` emits them: rank alternatives in
+    ``RANK_SLOTS`` numerator and denominator rows."""
+    q = 2 * mr_ref.RANK_SLOTS
     return (
         sds((b, T_PAD, A_PAD)),
         sds((b, T_PAD), jnp.int32),
         sds((b, T_PAD)),
         sds((b, T_PAD)),
-        sds((b, A_PAD)),
-        sds((b,)),
+        sds((b, q, A_PAD)),
+        sds((b, q)),
     )
 
 
@@ -87,10 +91,11 @@ def test_matchrank_single_compiles(sds):
     fn = functools.partial(
         mr_ops._dispatch, block_s=512, use_kernel=True, interpret=False
     )
+    q = 2 * mr_ref.RANK_SLOTS
     _compile(
         fn, sds((S, A_PAD)), sds((S, A_PAD)), sds((S,)), sds((T_PAD, A_PAD)),
-        sds((T_PAD,), jnp.int32), sds((T_PAD,)), sds((T_PAD,)), sds((A_PAD,)),
-        sds((1,)),
+        sds((T_PAD,), jnp.int32), sds((T_PAD,)), sds((T_PAD,)), sds((q, A_PAD)),
+        sds((q,)),
     )
 
 
@@ -116,3 +121,13 @@ def test_bwstats_compiles(sds):
         bw_ops._dispatch, alpha=0.25, block_n=256, use_kernel=True, interpret=False
     )
     _compile(fn, sds((128, 4096)), sds((4096,), jnp.int32))
+
+
+@pytest.mark.parametrize("b", [1, 13])
+def test_matchrank_batched_alternatives_compile(sds, b):
+    """The tier-1 program at batch sizes below a full flush (B = 64 is
+    ``test_matchrank_batched_compiles``)."""
+    fn = functools.partial(
+        mr_ops._dispatch_batched, k=1, block_s=512, use_kernel=True, interpret=False
+    )
+    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), sds((b, S)), *_plan_shapes(sds, b))
