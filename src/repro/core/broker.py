@@ -484,6 +484,12 @@ class DataBroker:
                 ("snapshot_delta_refreshes", "sharded snapshots refreshed in place (dirty shards only)"),
                 ("policy_index_builds", "usage-policy row indexes built, one per snapshot epoch lowered against"),
                 ("policy_index_reuses", "requests lowered from an existing policy index"),
+                ("kernel_launches", "stacked kernel launches"),
+                (
+                    "kernel_launches_candidate",
+                    "stacked kernel launches whose candidate columns are fewer"
+                    " than the snapshot's padded rows",
+                ),
                 ("ad_findings", "request-ad analyzer findings recorded"),
             )
         }
@@ -1112,21 +1118,29 @@ class DataBroker:
 
         # ---- tier 1: one stacked kernel launch for the whole sub-batch ----
         if kernel_batch:
-            from repro.kernels.matchrank.ops import matchrank_batched, matchrank_batched_topk
+            from repro.kernels.matchrank.ops import (
+                NO_ROW,
+                admit_matrix,
+                matchrank_batched_topk,
+                matchrank_candidates,
+            )
 
             attrs, valid, n_rows = st.snapshot.device_columns()
-            admit_mat = np.zeros((len(kernel_batch), n_rows), dtype=np.float32)
-            for bi, i in enumerate(kernel_batch):
+            # each request's resident replica rows, in _rows_of order; a row
+            # its usage policy refuses stays an empty slot
+            cand_rows = []
+            for i in kernel_batch:
                 row_ok = admits[i]
-                for pfn in replica_lists[i]:
-                    r = st.row_of.get(pfn.endpoint)
-                    if r is not None and row_ok[r] > 0:
-                        admit_mat[bi, r] = 1.0
+                cand_rows.append(
+                    [r if row_ok[r] > 0 else NO_ROW for r in _rows_of(replica_lists[i], st)]
+                )
             sparse_done = False
             if use_sparse and top_k:
                 from repro.kernels.matchrank.sparse import canonicalize_plans
 
                 from .snapshot_sharded import ShardedSnapshot
+
+                admit_mat = admit_matrix(cand_rows, n_rows)
 
                 na = len(kernel_plans[0].attr_names)
                 iv = canonicalize_plans(kernel_plans, na)
@@ -1179,22 +1193,26 @@ class DataBroker:
                     use_kernel=use_kernel,
                     guarded=sum(guarded),
                 ):
-                    mask, score, _, _ = matchrank_batched(
+                    mask, score, _, _ = matchrank_candidates(
                         attrs,
                         valid,
                         kernel_plans,
-                        admit=admit_mat,
+                        cand_rows,
                         n_rows=n_rows,
                         use_kernel=use_kernel,
                         tracer=self.tracer,
                     )
+                self._ctr["kernel_launches"].inc()
+                if mask.shape[1] < attrs.shape[0]:
+                    self._ctr["kernel_launches_candidate"].inc()
                 for bi, i in enumerate(kernel_batch):
                     results[i] = self._ranked_from_scores(
                         queries[i][0], replica_lists[i], st, mask[bi], score[bi]
                     )
                     recs[i].kernel_path = "batched_kernel"
                     self._fill_batched_audit(
-                        recs[i], st, results[i], mask=mask[bi], score=score[bi]
+                        recs[i], st, results[i], rows=cand_rows[bi], mask=mask[bi],
+                        score=score[bi],
                     )
                     self._ctr["batched_kernel_requests"].inc()
                     if guarded[bi]:
@@ -1205,19 +1223,18 @@ class DataBroker:
             with self.tracer.span("broker.columnar", lfn=queries[i][0]):
                 prog = self.plan_cache.columnar_program(reqs[i], vocab, env=self.env)
                 mask, rank = prog.run(st.table, np)
-                mask = np.asarray(mask, bool) & (admits[i] > 0)
-                row_admit = np.zeros((st.snapshot.n,), bool)
-                for pfn in replica_lists[i]:
-                    r = st.row_of.get(pfn.endpoint)
-                    if r is not None:
-                        row_admit[r] = True
-                mask &= row_admit
-                score = np.asarray(rank, np.float64)
+                rows = list(_rows_of(replica_lists[i], st))
+                at = np.asarray(rows, dtype=np.intp)
+                shape = (st.snapshot.n,)
+                mask = np.broadcast_to(np.asarray(mask, bool), shape)[at] & (admits[i][at] > 0)
+                score = np.broadcast_to(np.asarray(rank, np.float64), shape)[at]
                 results[i] = self._ranked_from_scores(
                     queries[i][0], replica_lists[i], st, mask, score
                 )
             recs[i].kernel_path = "batched_columnar"
-            self._fill_batched_audit(recs[i], st, results[i], mask=mask, score=score)
+            self._fill_batched_audit(
+                recs[i], st, results[i], rows=rows, mask=mask, score=score
+            )
             self._ctr["batched_columnar_requests"].inc()
 
         # ---- tier 3: the paper-faithful interpreter, per request ----
@@ -1257,16 +1274,17 @@ class DataBroker:
     def _ranked_from_scores(
         self, lfn: str, replicas: Sequence[PhysicalFile], st: _SnapshotState, mask, score
     ) -> List[RankedReplica]:
-        """Snapshot rows + per-request scores → the same rank-ordered
-        RankedReplica list the interpreter produces (same tiebreak)."""
+        """Per-candidate (mask, score) → the same rank-ordered RankedReplica
+        list the interpreter produces (same tiebreak). Position j of
+        ``mask`` and ``score`` is the j-th resident row of ``replicas``
+        (:func:`_rows_of` order)."""
         by_row = _rows_of(replicas, st)
-        rows = [r for r in by_row if bool(mask[r])]
-        rows.sort(key=lambda r: (-float(score[r]), _row_name(st, r), r))
-        out = []
-        for r in rows:
-            view = ReplicaView(by_row[r], st.entries[r], st.ads[r])
-            out.append(RankedReplica(view, float(score[r])))
-        return out
+        picked = [(r, float(score[j])) for j, r in enumerate(by_row) if mask[j]]
+        picked.sort(key=lambda rs: (-rs[1], _row_name(st, rs[0]), rs[0]))
+        return [
+            RankedReplica(ReplicaView(by_row[r], st.entries[r], st.ads[r]), sc)
+            for r, sc in picked
+        ]
 
     def _ranked_from_topk(
         self, replicas: Sequence[PhysicalFile], st: _SnapshotState, idx, scores
@@ -1389,17 +1407,25 @@ class DataBroker:
             self._ctr["batched_sharded_requests"].inc()
 
     def _fill_batched_audit(
-        self, rec, st: _SnapshotState, result: List[RankedReplica], mask=None, score=None
+        self,
+        rec,
+        st: _SnapshotState,
+        result: List[RankedReplica],
+        rows=None,
+        mask=None,
+        score=None,
     ) -> None:
         """Per-candidate fates for a snapshot-tier request. Dense tiers
-        pass row-level (mask, score); the sparse tier only probed until k
-        candidates passed, so non-winners are recorded unmatched/unscored."""
+        pass (mask, score) at candidate ``rows`` (position j at
+        ``rows[j]``); the sparse tier only probed until k candidates
+        passed, so non-winners are recorded unmatched/unscored."""
         if mask is not None:
+            pos = {r: j for j, r in enumerate(rows)}
             scores = []
             for ep in rec.candidates:
-                r = st.row_of.get(ep)
-                ok = r is not None and bool(mask[r])
-                scores.append(CandidateScore(ep, float(score[r]) if ok else None, ok))
+                j = pos.get(st.row_of.get(ep))
+                ok = j is not None and bool(mask[j])
+                scores.append(CandidateScore(ep, float(score[j]) if ok else None, ok))
             rec.scores = scores
         else:
             won = {rr.pfn.endpoint: rr.rank for rr in result}

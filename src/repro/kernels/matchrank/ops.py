@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.classads import ClassAd
 from repro.core.compile import (
@@ -48,6 +49,9 @@ __all__ = [
     "matchrank",
     "matchrank_topk",
     "matchrank_batched",
+    "matchrank_candidates",
+    "candidate_bucket",
+    "admit_matrix",
     "matchrank_batched_topk",
     "lower_matchrank_batched",
     "pad_columns",
@@ -304,21 +308,130 @@ def _dispatch_topk(
     return vals, idx
 
 
+#: the smallest candidate-row bucket of a launch (see :func:`candidate_bucket`)
+MIN_CANDIDATES = 8
+#: a candidate slot that holds no row: past every row, so the launch's
+#: scatter drops it (a negative pad would wrap to the last row)
+NO_ROW = np.iinfo(np.int32).max
+
+
+def candidate_bucket(count: int, s_pad: int) -> int:
+    """C, the candidate columns of a launch whose longest candidate list
+    has ``count`` rows: the next power of two, at least
+    :data:`MIN_CANDIDATES`, at most ``s_pad`` (then the columns can hold
+    every row). A few buckets keep the count of compiled programs small."""
+    c = MIN_CANDIDATES
+    while c < count:
+        c *= 2
+    return min(c, s_pad)
+
+
+def _plan_fields(t_pad: int, a_pad: int) -> List[Tuple[str, int, int]]:
+    """(field, start, stop) of each plan operand in a packed launch row;
+    the request's candidate rows follow the last one."""
+    q = 2 * RANK_SLOTS
+    sizes = (
+        ("sel", t_pad * a_pad), ("op_codes", t_pad), ("thresholds", t_pad),
+        ("term_role", t_pad), ("weights", q * a_pad), ("bias", q),
+    )
+    out, o = [], 0
+    for name, n in sizes:
+        out.append((name, o, o + n))
+        o += n
+    return out
+
+
+def _pack_launch(batched: BatchedPlan, cand: np.ndarray) -> np.ndarray:
+    """One i32 row per request: its plan operands (the f32 ones bit for
+    bit) and then its ``[C]`` candidate rows — the launch's single
+    host-to-device transfer."""
+    b = batched.b
+    fields = _plan_fields(batched.t_pad, batched.a_pad)
+    end = fields[-1][2]
+    buf = np.empty((b, end + cand.shape[1]), dtype=np.int32)
+    as_f32 = buf.view(np.float32)
+    for name, lo, hi in fields:
+        dst = buf if name == "op_codes" else as_f32
+        dst[:, lo:hi] = getattr(batched, name).reshape(b, hi - lo)
+    buf[:, end:] = cand
+    return buf
+
+
+def _candidate_matrix(rows: Sequence[Sequence[int]], s_pad: int) -> np.ndarray:
+    """Ragged per-request candidate rows → ``[B, C]`` i32, padded with
+    :data:`NO_ROW`."""
+    c = candidate_bucket(max((len(r) for r in rows), default=0), s_pad)
+    cand = np.full((len(rows), c), NO_ROW, dtype=np.int32)
+    for bi, r in enumerate(rows):
+        cand[bi, : len(r)] = r
+    return cand
+
+
 @functools.partial(
-    jax.jit, static_argnames=("k", "block_s", "use_kernel", "interpret")
+    jax.jit, static_argnames=("t_pad", "k", "block_s", "use_kernel", "interpret")
 )
 def _dispatch_batched(
-    attrs, valid, admit, sel, op_codes, thresholds, term_role, weights, bias,
-    *, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
+    attrs, valid, packed,
+    *, t_pad: int, k: int, block_s: int, use_kernel: bool, interpret: Optional[bool],
 ):
+    """The candidate-row launch: unpack the plans and the ``[B, C]``
+    candidate rows from ``packed`` (:func:`_pack_launch`), scatter the rows
+    into the kernel's ``[B, S_PAD]`` admit pre-mask, run the kernel, and
+    gather mask and score back at the candidate rows. → one i32
+    ``[B, 2C + 2k]`` array: mask (0/1), score bits, top-k score bits,
+    top-k rows."""
+    b, width = packed.shape
+    s_pad, a_pad = attrs.shape
+    fields = _plan_fields(t_pad, a_pad)
+    end = fields[-1][2]
+    # lax primitives, not jnp indexing: this program is traced once per
+    # batch size at start-up, and the tracing is most of a cached compile
+    as_f32 = lax.bitcast_convert_type(lax.slice_in_dim(packed, 0, end, axis=1), jnp.float32)
+    part = {
+        name: lax.slice_in_dim(packed if name == "op_codes" else as_f32, lo, hi, axis=1)
+        for name, lo, hi in fields
+    }
+    cand = lax.slice_in_dim(packed, end, width, axis=1)
+    # each candidate as a row of the flattened [B·S_PAD] admit; an empty
+    # slot points one past its end, where the scatter drops it
+    live = lax.lt(cand, s_pad)
+    flat = lax.select(
+        live,
+        lax.add(cand, lax.mul(lax.broadcasted_iota(jnp.int32, cand.shape, 0), s_pad)),
+        lax.full(cand.shape, b * s_pad, jnp.int32),
+    ).reshape(b, -1, 1)
+    admit = lax.scatter(
+        lax.full((b * s_pad,), 0.0, jnp.float32), flat, lax.full(cand.shape, 1.0, jnp.float32),
+        lax.ScatterDimensionNumbers((), (0,), (0,)),
+        mode=lax.GatherScatterMode.FILL_OR_DROP,
+    ).reshape(b, s_pad)
+    operands = (
+        attrs, valid, admit, part["sel"].reshape(b, t_pad, a_pad), part["op_codes"],
+        part["thresholds"], part["term_role"],
+        part["weights"].reshape(b, 2 * RANK_SLOTS, a_pad), part["bias"],
+    )
     if use_kernel:
-        return matchrank_batched_pallas(
-            attrs, valid, admit, sel, op_codes, thresholds, term_role,
-            weights, bias, block_s=block_s, k=k, interpret=interpret,
+        mask, score, topk_s, topk_i = matchrank_batched_pallas(
+            *operands, block_s=block_s, k=k, interpret=interpret
         )
-    return matchrank_batched_ref(
-        attrs, valid, admit, sel, op_codes, thresholds, term_role, weights,
-        bias, k=k,
+    else:
+        mask, score, topk_s, topk_i = matchrank_batched_ref(*operands, k=k)
+    at = lax.min(flat, lax.full(flat.shape, b * s_pad - 1, jnp.int32))
+
+    def at_rows(x):  # [B, S_PAD] → [B, C] at the candidate rows
+        return lax.gather(
+            x.reshape(b * s_pad), at, lax.GatherDimensionNumbers((), (0,), (0,)), (1,),
+            mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+        )
+
+    cmask = lax.bitwise_and(at_rows(mask), live)
+    cscore = lax.select(live, at_rows(score), lax.full(live.shape, NEG_INF, jnp.float32))
+    return lax.concatenate(
+        [
+            cmask.astype(jnp.int32), lax.bitcast_convert_type(cscore, jnp.int32),
+            lax.bitcast_convert_type(topk_s, jnp.int32), topk_i.astype(jnp.int32),
+        ],
+        1,
     )
 
 
@@ -487,7 +600,7 @@ def _prepare_columns(
     attrs_p, valid_p, s_pad = pad_columns(
         np.asarray(attrs), np.asarray(valid), a_pad, block_s
     )
-    return jnp.asarray(attrs_p), jnp.asarray(valid_p), s, s_pad
+    return _to_device(attrs_p), _to_device(valid_p), s, s_pad
 
 
 def matchrank(
@@ -567,6 +680,89 @@ def matchrank_topk(
     return np.asarray(idx), np.asarray(vals)
 
 
+def matchrank_candidates(
+    attrs: np.ndarray,  # [S, A] (unpadded) or pre-padded [S_PAD, A_PAD]
+    valid: np.ndarray,
+    plans: "BatchedPlan | Sequence[KernelPlan]",
+    rows: Sequence[Sequence[int]],  # per request: its candidate rows
+    *,
+    n_rows: Optional[int] = None,
+    k: int = 1,
+    block_s: int = 512,
+    use_kernel: bool = True,
+    interpret: Optional[bool] = None,
+    tracer: Optional[Any] = None,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Batched fused match+rank+top-k over each request's candidate rows
+    — the served form of the launch.
+
+    Request i is admitted only at ``rows[i]``: distinct rows below the
+    live row count, or :data:`NO_ROW` for a slot that holds none. The
+    lists go in a ``[B, C]`` bucket (:func:`candidate_bucket` of the
+    longest) and the results come back the same way: returns (mask [B,C]
+    bool, score [B,C] f32, topk_idx [B,k] i32, topk_scores [B,k] f32),
+    where column j of request i is ``rows[i][j]``, and empty slots and
+    columns past a request's list hold mask False and score -inf. The
+    top-k is over all rows, as :func:`matchrank_batched` gives it.
+
+    With ``use_kernel`` the plans and candidate rows cross to the device
+    in one packed array and the results come back in one; a ``tracer``
+    (:class:`repro.obs.Tracer`) times the two sides in
+    ``broker.kernel_launch.copy_in`` (plan stacking, packing and the put)
+    and ``broker.kernel_launch.fetch`` (the one copy back, which waits for
+    the device). Without it the grouped host evaluation answers.
+    """
+    span = tracer.span if tracer is not None else _no_span
+    with span("broker.kernel_launch.copy_in"):
+        batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
+        if use_kernel:
+            operands, static, s, c = _candidate_launch(
+                attrs, valid, batched, rows, n_rows, k, block_s
+            )
+    if not use_kernel:
+        # grouped host evaluation — the jnp ref's [B,S,T] einsums are kept
+        # as a parity oracle only (see _matchrank_batched_dense_host)
+        s = attrs.shape[0] if n_rows is None else int(n_rows)
+        cand = _candidate_matrix(rows, s)
+        mask, score, ti, ts = _matchrank_batched_dense_host(
+            attrs, valid, batched, admit_matrix(rows, s), s, k
+        )
+        live = cand < s
+        at = np.minimum(cand, s - 1)
+        cmask = np.take_along_axis(mask, at, axis=1) & live
+        cscore = np.where(live, np.take_along_axis(score, at, axis=1), np.float32(NEG_INF))
+        return cmask, cscore, ti, ts
+    out = _dispatch_batched(*operands, **static, interpret=interpret)
+    with span("broker.kernel_launch.fetch"):
+        out = _to_host(out)
+    kk = static["k"]
+    return (
+        out[:, :c] != 0,
+        out[:, c : 2 * c].view(np.float32),
+        out[:, 2 * c + kk :],
+        out[:, 2 * c : 2 * c + kk].view(np.float32),
+    )
+
+
+def admit_matrix(rows: Sequence[Sequence[int]], n: int) -> np.ndarray:
+    """Per-request candidate rows → the ``[B, n]`` admit pre-mask they
+    stand for (an empty slot, any row ≥ n, admits nothing)."""
+    admit = np.zeros((len(rows), n), dtype=np.float32)
+    for bi, r in enumerate(rows):
+        r = np.asarray(r, dtype=np.int64)
+        admit[bi, r[r < n]] = 1.0
+    return admit
+
+
+def _admitted_rows(admit: Optional[np.ndarray], b: int, s: int) -> List[np.ndarray]:
+    """A dense ``[B, S]`` pre-mask (None: every row) → each request's
+    admitted rows."""
+    if admit is None:
+        return [np.arange(s)] * b
+    a = np.asarray(admit)[:, :s] > 0.5
+    return [np.flatnonzero(a[bi]) for bi in range(b)]
+
+
 def matchrank_batched(
     attrs: np.ndarray,  # [S, A] (unpadded) or pre-padded [S_PAD, A_PAD]
     valid: np.ndarray,
@@ -588,60 +784,55 @@ def matchrank_batched(
     beyond a request's match count hold score -inf (index is meaningless
     there, as in :func:`matchrank_topk`).
 
-    With a ``tracer`` (:class:`repro.obs.Tracer`), two spans time the
-    launch's host side: ``broker.kernel_launch.copy_in`` (plan stacking,
-    operand padding and the host-to-device puts) and
-    ``broker.kernel_launch.fetch`` (the outputs' copy back, which waits
-    for the device). The dispatch is what lies between them.
+    The dense form of :func:`matchrank_candidates`: the admitted rows of
+    each request are its candidates, and the results are laid back out
+    over every row (mask False and score -inf off the candidates, as the
+    kernel gives them there). ``tracer`` spans as there.
     """
-    span = tracer.span if tracer is not None else _no_span
-    with span("broker.kernel_launch.copy_in"):
-        batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
-        if use_kernel:
-            operands, static, s = _batched_launch(
-                attrs, valid, batched, admit, n_rows, k, block_s
-            )
+    b = plans.b if isinstance(plans, BatchedPlan) else len(plans)
+    s = attrs.shape[0] if n_rows is None else int(n_rows)
     if not use_kernel:
-        # grouped host evaluation — the jnp ref's [B,S,T] einsums are kept
-        # as a parity oracle only (see _matchrank_batched_dense_host)
-        s = attrs.shape[0] if n_rows is None else int(n_rows)
+        batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
         return _matchrank_batched_dense_host(attrs, valid, batched, admit, s, k)
-    mask, score, topk_s, topk_i = _dispatch_batched(
-        *operands, **static, interpret=interpret
+    rows = _admitted_rows(admit, b, s)
+    cmask, cscore, ti, ts = matchrank_candidates(
+        attrs, valid, plans, rows, n_rows=n_rows, k=k, block_s=block_s,
+        interpret=interpret, tracer=tracer,
     )
-    with span("broker.kernel_launch.fetch"):
-        return (
-            np.asarray(mask)[:, :s],
-            np.asarray(score)[:, :s],
-            np.asarray(topk_i),
-            np.asarray(topk_s),
-        )
+    mask = np.zeros((b, s), dtype=bool)
+    score = np.full((b, s), NEG_INF, dtype=np.float32)
+    for bi, r in enumerate(rows):
+        mask[bi, r] = cmask[bi, : len(r)]
+        score[bi, r] = cscore[bi, : len(r)]
+    return mask, score, ti, ts
 
 
 def _no_span(name: str):
     return contextlib.nullcontext()
 
 
-def _batched_launch(
-    attrs, valid, batched: BatchedPlan, admit, n_rows, k: int, block_s: int
-) -> Tuple[Tuple[Any, ...], Dict[str, Any], int]:
-    """→ (operands, static arguments, live rows) of the kernel launch that
-    :func:`matchrank_batched` makes."""
+def _to_device(x: np.ndarray) -> jax.Array:
+    """A launch's host-to-device transfer."""
+    return jax.device_put(x)
+
+
+def _to_host(x: jax.Array) -> np.ndarray:
+    """A launch's device-to-host transfer (waits for the device)."""
+    return np.asarray(x)
+
+
+def _candidate_launch(
+    attrs, valid, batched: BatchedPlan, rows, n_rows, k: int, block_s: int
+) -> Tuple[Tuple[Any, ...], Dict[str, Any], int, int]:
+    """→ (operands, static arguments, live rows, C) of the kernel launch
+    that :func:`matchrank_candidates` makes."""
     attrs_p, valid_p, s, s_pad = _prepare_columns(
         attrs, valid, batched.a_pad, block_s, n_rows
     )
-    admit_p = np.zeros((batched.b, s_pad), dtype=np.float32)
-    if admit is None:
-        admit_p[:, :s] = 1.0
-    else:
-        admit_p[:, :s] = np.asarray(admit, dtype=np.float32)[:, :s]
-    operands = (
-        attrs_p, valid_p, jnp.asarray(admit_p),
-        jnp.asarray(batched.sel), jnp.asarray(batched.op_codes),
-        jnp.asarray(batched.thresholds), jnp.asarray(batched.term_role),
-        jnp.asarray(batched.weights), jnp.asarray(batched.bias),
-    )
-    return operands, dict(k=min(k, s), block_s=block_s, use_kernel=True), s
+    cand = _candidate_matrix(rows, s_pad)
+    packed = _to_device(_pack_launch(batched, cand))
+    static = dict(t_pad=batched.t_pad, k=min(k, s), block_s=block_s, use_kernel=True)
+    return (attrs_p, valid_p, packed), static, s, cand.shape[1]
 
 
 def lower_matchrank_batched(
@@ -659,8 +850,9 @@ def lower_matchrank_batched(
     for these operands, lowered and not run — what the backend compiles
     (a Mosaic ``tpu_custom_call`` on a TPU, the interpreter elsewhere)."""
     batched = plans if isinstance(plans, BatchedPlan) else stack_plans(list(plans))
-    operands, static, _ = _batched_launch(
-        attrs, valid, batched, admit, n_rows, k, block_s
+    s = attrs.shape[0] if n_rows is None else int(n_rows)
+    operands, static, _, _ = _candidate_launch(
+        attrs, valid, batched, _admitted_rows(admit, batched.b, s), n_rows, k, block_s
     )
     return _dispatch_batched.lower(*operands, **static, interpret=interpret)
 

@@ -59,19 +59,24 @@ def _counts(broker):
 
 
 def _select_capturing_admit(broker, monkeypatch):
-    """select_many over QUERIES → (results, the admit matrix of the launch)."""
+    """select_many over QUERIES → (results, the admit matrix that the
+    launch's candidate rows stand for)."""
     seen = []
-    orig = mr_ops.matchrank_batched
+    orig = mr_ops.matchrank_candidates
 
-    def capture(*args, **kw):
-        seen.append(np.asarray(kw["admit"]).copy())
-        return orig(*args, **kw)
+    def capture(attrs, valid, plans, rows, **kw):
+        seen.append([list(r) for r in rows])
+        return orig(attrs, valid, plans, rows, **kw)
 
-    monkeypatch.setattr(mr_ops, "matchrank_batched", capture)
+    monkeypatch.setattr(mr_ops, "matchrank_candidates", capture)
     out = broker.select_many(QUERIES)
-    monkeypatch.setattr(mr_ops, "matchrank_batched", orig)
+    monkeypatch.setattr(mr_ops, "matchrank_candidates", orig)
     assert broker.explain(broker.last_request_ids[0]).kernel_path == "batched_kernel"
-    [admit] = seen
+    [rows] = seen
+    n_rows = broker._snap_state.snapshot.device_columns()[2]
+    admit = np.zeros((len(rows), n_rows), np.float32)
+    for q, r in enumerate(rows):
+        admit[q, [x for x in r if x < n_rows]] = 1.0  # empty slots lie past every row
     return out, admit
 
 

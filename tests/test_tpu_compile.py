@@ -78,13 +78,20 @@ def sds(one_chip):
     )
 
 
+def _packed_launch(sds, b, c=mr_ops.MIN_CANDIDATES):
+    """The candidate launch's one packed operand: ``b`` plans and ``c``
+    candidate rows a request, as ``_pack_launch`` lays them out."""
+    return sds((b, mr_ops._plan_fields(T_PAD, A_PAD)[-1][2] + c), jnp.int32)
+
+
 @pytest.mark.parametrize("k", [1, 4])
 def test_matchrank_batched_compiles(sds, k):
     """The tier-1 program ``select_many`` launches (B=64 requests)."""
     fn = functools.partial(
-        mr_ops._dispatch_batched, k=k, block_s=512, use_kernel=True, interpret=False
+        mr_ops._dispatch_batched, t_pad=T_PAD, k=k, block_s=512, use_kernel=True,
+        interpret=False,
     )
-    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), sds((B, S)), *_plan_shapes(sds, B))
+    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), _packed_launch(sds, B))
 
 
 def test_matchrank_single_compiles(sds):
@@ -128,6 +135,7 @@ def test_matchrank_batched_alternatives_compile(sds, b):
     """The tier-1 program at batch sizes below a full flush (B = 64 is
     ``test_matchrank_batched_compiles``)."""
     fn = functools.partial(
-        mr_ops._dispatch_batched, k=1, block_s=512, use_kernel=True, interpret=False
+        mr_ops._dispatch_batched, t_pad=T_PAD, k=1, block_s=512, use_kernel=True,
+        interpret=False,
     )
-    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), sds((b, S)), *_plan_shapes(sds, b))
+    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), _packed_launch(sds, b))
