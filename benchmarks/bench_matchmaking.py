@@ -9,13 +9,14 @@ Phase at fleet scale: one request matched+ranked against S replica ads,
   * kernel    — conjunctive-threshold lowering through the fused
                 matchrank kernel (interpret-mode Pallas on CPU; on TPU the
                 same call runs compiled — see DESIGN.md §3),
-  * batched   — the multi-request engine (DESIGN.md §4): B requests vs one
-                resident snapshot, rank-order sparse top-k on CPU
-                (``match_batched_b{8,64}_s{1k,10k}`` rows, with a
-                batched-vs-sequential speedup row).
+  * batched   — the multi-request launch (DESIGN.md §4): B = 64 requests
+                vs one resident snapshot, answered by the host evaluation
+                the CPU runs in place of the kernel
+                (``match_batched_dense_b64_s10k``).
 
 Rows: (name, µs/call, derived = matches/sec per 1k candidates — for
 batched rows, request·candidates/sec; for speedup rows, the ratio).
+Every figure is a host-CPU timing, not a device number.
 """
 
 import time
@@ -80,7 +81,6 @@ def _time(fn, reps, *, tol=0.25, max_warm=8):
 
 def run():
     rows = []
-    steady_us = {}
     request = parse_classad(REQUEST_SRC)
     for s in (100, 1000, 10000):
         attrs, valid, views = make_world(s)
@@ -105,7 +105,6 @@ def run():
             return int(_np.argmax(_np.where(mask, rank, -_np.inf)))
 
         us_w = _time(steady, max(reps, 20))
-        steady_us[s] = us_w
         plan = lower_request(request, NAMES)
         us_k = _time(lambda: matchrank(attrs, valid, plan), max(reps, 10))
 
@@ -118,69 +117,33 @@ def run():
         rows.append((f"match_kernel_interpret_s{s}", us_k, s / us_k * 1e6))
         rows.append((f"match_speedup_steady_vs_interp_s{s}", 0.0, us_i / us_w))
 
-    # ---- batched engine: snapshot + plan cache + rank-order top-k ----
+    # ---- batched launch: snapshot + plan cache, host evaluation ----
     # The fleet scenario (DESIGN.md §4): B concurrent requests answered
-    # against ONE device-resident snapshot. Snapshot build, plan lowering
-    # and the per-(epoch, rank-weights) sort happen once per GRIS epoch /
-    # request shape — exactly the amortization the engine exists for —
-    # so they sit outside the timed region, like the steady columnar row.
+    # against ONE resident snapshot. Snapshot build and plan lowering
+    # happen once per GRIS epoch / request shape, so they sit outside the
+    # timed region, like the steady columnar row.
     from repro.core.plancache import PlanCache
     from repro.core.snapshot import ReplicaSnapshot
-    from repro.kernels.matchrank.ops import matchrank_batched, matchrank_batched_topk
+    from repro.kernels.matchrank.ops import matchrank_batched
 
-    for s in (1000, 10000):
-        tag = "1k" if s == 1000 else "10k"
-        _, _, views = make_world(s)
-        snap = ReplicaSnapshot([v.entry for v in views])
-        attrs_l, valid_l = snap.logical_columns()
-        pc = PlanCache()
-        for b in (8, 64):
-            batch = [
-                parse_classad(REQUEST_SRC.replace("5G", f"{4 + i % 4}G"))
-                for i in range(b)
-            ]
-            plans = [pc.kernel_plan(r, snap.vocab_key()) for r in batch]
+    s = 10000
+    _, _, views = make_world(s)
+    snap = ReplicaSnapshot([v.entry for v in views])
+    pc = PlanCache()
+    plans64 = [
+        pc.kernel_plan(
+            parse_classad(REQUEST_SRC.replace("5G", f"{4 + i % 4}G")),
+            snap.vocab_key(),
+        )
+        for i in range(64)
+    ]
+    da, dv, dn = snap.device_columns()
 
-            def batched():
-                return matchrank_batched_topk(
-                    attrs_l, valid_l, plans, k=1, rank_order=snap.rank_order
-                )
+    def dense():
+        return matchrank_batched(da, dv, plans64, n_rows=dn, k=1, use_kernel=False)
 
-            us_b = _time(batched, 50)
-            rows.append((f"match_batched_b{b}_s{tag}", us_b, b * s / us_b * 1e6))
-            if b == 64:
-                rows.append(
-                    (
-                        f"match_batched_vs_sequential_b{b}_s{tag}",
-                        0.0,
-                        b * steady_us[s] / us_b,
-                    )
-                )
-        if s == 10000:
-            # the dense batched launch (what the same call runs on TPU;
-            # interpret-free jnp ref on CPU) — kept for the trajectory,
-            # it is why the CPU steady state takes the sparse walk
-            plans64 = [
-                pc.kernel_plan(
-                    parse_classad(REQUEST_SRC.replace("5G", f"{4 + i % 4}G")),
-                    snap.vocab_key(),
-                )
-                for i in range(64)
-            ]
-            da, dv, dn = snap.device_columns()
-
-            def dense():
-                return matchrank_batched(
-                    da, dv, plans64, n_rows=dn, k=1, use_kernel=False
-                )
-
-            us_d = _time(dense, 2, max_warm=3)
-            rows.append((f"match_batched_dense_b64_s{tag}", us_d, 64 * s / us_d * 1e6))
-            # dense fallback must stay within 20x of the sparse walk —
-            # the host path is the safety net when plans don't
-            # canonicalize, so it can't be allowed to rot (us_b still
-            # holds the b=64 sparse figure from the loop above)
-            rows.append(("match_dense_vs_sparse_b64_s10k", 0.0, us_d / us_b))
+    us_d = _time(dense, 2, max_warm=3)
+    rows.append(("match_batched_dense_b64_s10k", us_d, 64 * s / us_d * 1e6))
 
     # LDIF→ClassAd conversion throughput (the §6 'not cumbersome' claim)
     _, _, views = make_world(1000, seed=1)

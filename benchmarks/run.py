@@ -71,7 +71,6 @@ def main() -> None:
         bench_pipeline,
         bench_predictors,
         bench_selection_quality,
-        bench_sharded,
         bench_transfer,
     )
 
@@ -84,7 +83,6 @@ def main() -> None:
         "kernels": bench_kernels,
         "transfer": bench_transfer,
         "analysis": bench_analysis,
-        "sharded": bench_sharded,
     }
 
     from repro.obs import Tracer
@@ -113,9 +111,6 @@ def main() -> None:
     if "match_speedup_steady_vs_interp_s10000" in derived:
         checks.append(("steady-state columnar >=10x interpreter @10k ads",
                        derived["match_speedup_steady_vs_interp_s10000"] >= 10))
-    if "match_batched_vs_sequential_b64_s10k" in derived:
-        checks.append(("batched B=64 engine >=5x sequential columnar-steady @10k ads",
-                       derived["match_batched_vs_sequential_b64_s10k"] >= 5))
     if "selection_gain_predicted_vs_random" in derived:
         checks.append(("history-based selection beats random",
                        derived["selection_gain_predicted_vs_random"] >= 1.0))
@@ -142,18 +137,9 @@ def main() -> None:
     if "analysis_check_ad" in derived:
         checks.append(("ad analyzer checks >=1k ads/sec",
                        derived["analysis_check_ad"] >= 1000))
-    if "match_dense_vs_sparse_b64_s10k" in derived:
-        checks.append(("dense batched fallback <=20x sparse walk @B=64 S=10k",
-                       derived["match_dense_vs_sparse_b64_s10k"] <= 20))
     if "gris_ldif_entries_per_sec" in derived:
         checks.append(("LDIF->ClassAd ingest >=100k entries/sec",
                        derived["gris_ldif_entries_per_sec"] >= 100_000))
-    if "sharded_vs_flat_columnar_b64_s100k_g8" in derived:
-        checks.append(("sharded steady state >=5x flat columnar-steady @S=100k G=8",
-                       derived["sharded_vs_flat_columnar_b64_s100k_g8"] >= 5))
-    if "sharded_delta_vs_full_repush_s100k" in derived:
-        checks.append(("1% delta refresh >=10x faster than full epoch re-push @S=100k",
-                       derived["sharded_delta_vs_full_repush_s100k"] >= 10))
 
     bad = [c for c, ok in checks if not ok]
     for c, ok in checks:

@@ -405,7 +405,6 @@ class DataBroker:
         stripe_k: int = 3,
         snapshot_ttl: float = 5.0,
         batch_use_kernel: bool = False,
-        batch_use_sparse: bool = False,
         snapshot_shards: int = 0,
         shard_key: Optional[Callable[[str], int]] = None,
         plan_cache_size: int = 256,
@@ -431,8 +430,7 @@ class DataBroker:
         # attribute TTL (stale columns would diverge from fresh LDAP reads)
         self.snapshot_ttl = snapshot_ttl
         self.batch_use_kernel = batch_use_kernel
-        self.batch_use_sparse = batch_use_sparse
-        # sharded matchmaking (DESIGN.md §9): partition the snapshot into
+        # sharded snapshots (DESIGN.md §9): partition the snapshot into
         # this many per-registrant shards (0 = flat snapshot). shard_key
         # maps endpoint → bucket; default is the crc32 hash bucketing.
         self.snapshot_shards = int(snapshot_shards)
@@ -475,8 +473,6 @@ class DataBroker:
                     "requests answered by the stacked kernel whose plan has"
                     " guarded terms or fallback rank alternatives",
                 ),
-                ("batched_sparse_requests", "requests answered by sparse top-k"),
-                ("batched_sharded_requests", "requests answered by the sharded walk+merge"),
                 ("batched_columnar_requests", "requests answered columnar per-request"),
                 ("batched_interp_requests", "requests answered by the interpreter"),
                 ("snapshot_builds", "GRIS snapshot (re)builds"),
@@ -493,11 +489,6 @@ class DataBroker:
                 ("ad_findings", "request-ad analyzer findings recorded"),
             )
         }
-        self._ctr_shard_rows = self.metrics.counter(
-            "shard_refresh_rows_total",
-            "rows re-pushed to the device by sharded delta refreshes",
-        )
-        self._shard_hists: Dict[int, Any] = {}
         self._h_gris_query = self.metrics.histogram(
             "broker_gris_query_seconds", "per-endpoint GRIS query latency"
         )
@@ -659,16 +650,6 @@ class DataBroker:
         list, plus the executable ``plan`` and the ``request_id`` of the
         decision record :meth:`explain` retrieves."""
         req = request if request is not None else default_read_request(self.client_url)
-        if self.snapshot_shards > 0 and top_k:
-            # sharded brokers answer sequential selections through the
-            # batched sharded tier so they hit the same snapshot + result
-            # cache (requests needing per-(lfn,replica) attributes can't:
-            # those attrs aren't in the shared snapshot)
-            refs = _referenced_attrs(req.lookup_expr("requirements")) | _referenced_attrs(
-                req.lookup_expr("rank")
-            )
-            if not (refs & _PER_REPLICA_ATTRS):
-                return self.select_many([(lfn, req)], top_k=top_k)[0]
         rec = self.audit.begin(lfn, mode="select", at=self.clock.now())
         rec.top_k = top_k
         self.last_request_id = rec.request_id
@@ -784,27 +765,14 @@ class DataBroker:
         )
         return f"shard-{int(bucket) % self.snapshot_shards:03d}"
 
-    def _shard_hist(self, g: int):
-        """Per-shard rank-walk latency histogram (bounded label set: one
-        child per shard index)."""
-        h = self._shard_hists.get(g)
-        if h is None:
-            h = self.metrics.histogram(
-                "broker_shard_rank_seconds",
-                "per-shard sparse rank-walk latency",
-                shard=str(g),
-            )
-            self._shard_hists[g] = h
-        return h
-
     def _snapshot_state_sharded(
         self, want: Sequence[str], now: float, st: Optional[_SnapshotState]
     ) -> _SnapshotState:
         """Sharded twin of :meth:`_snapshot_state`: endpoints are bucketed
         into per-registrant shards, and a TTL lapse with unchanged
-        membership becomes a **delta refresh** — unchanged shards never
-        leave the device, changed shards re-push in one scatter — instead
-        of a full rebuild (DESIGN.md §9)."""
+        membership becomes a **delta refresh** — only the shards whose
+        entries changed are refilled and re-converted to ads — instead of a
+        full rebuild (DESIGN.md §9)."""
         from .snapshot_sharded import ShardedSnapshot
 
         known: List[str] = list(st.endpoints) if st is not None else []
@@ -855,7 +823,6 @@ class DataBroker:
                     for nm in shard_names
                 )
             ):
-                rows_before = prev.pushed_rows
                 try:
                     changed = prev.refresh(shard_entries)
                     snapshot = prev
@@ -863,7 +830,6 @@ class DataBroker:
                     snapshot = None  # vocab/shape drift: fall through to rebuild
                 if snapshot is not None:
                     self._ctr["snapshot_delta_refreshes"].inc()
-                    self._ctr_shard_rows.inc(int(snapshot.pushed_rows - rows_before))
             if snapshot is None:
                 snapshot = ShardedSnapshot(
                     shard_entries, epoch=prev.epoch + 1 if prev is not None else 0
@@ -919,21 +885,17 @@ class DataBroker:
         *,
         top_k: Optional[int] = None,
         use_kernel: Optional[bool] = None,
-        use_sparse: Optional[bool] = None,
         strict: bool = True,
     ) -> List[Any]:
         """Batched Search+Match: many ``(lfn, request)`` selections against
         ONE device-resident snapshot in (at most) one kernel launch.
 
         Requests whose plans lower to the kernel subset are stacked into a
-        single ``matchrank_batched`` call (or, with ``use_sparse`` and a
-        ``top_k``, answered by the rank-order sparse top-k walk when every
-        plan canonicalizes); requests that only compile to the columnar
-        subset run per-request against the same snapshot table; everything
-        else takes the paper-faithful interpreter — all tiers produce
-        identical selections (tested; the sparse tier may order exact
-        rank-ties at the k-boundary differently, which is why it is
-        opt-in).
+        single candidate launch (``matchrank_candidates``) over the
+        snapshot's device columns, flat or sharded alike; requests that
+        only compile to the columnar subset run per-request against the
+        same snapshot table; everything else takes the paper-faithful
+        interpreter — all tiers produce identical selections (tested).
 
         Every query gets a decision record (``self.last_request_ids``,
         :meth:`explain`) noting its kernel path, plan-cache and snapshot
@@ -946,10 +908,6 @@ class DataBroker:
         request must not poison the batch.
         """
         use_kernel = self.batch_use_kernel if use_kernel is None else use_kernel
-        if use_sparse is None:
-            # sharded snapshots answer through the per-shard walk + merge
-            # tier, which rides the sparse gate
-            use_sparse = self.batch_use_sparse or self.snapshot_shards > 0
         self._ctr["batch_selects"].inc()
         n = len(queries)
         self._h_batch.observe(n)
@@ -1118,12 +1076,7 @@ class DataBroker:
 
         # ---- tier 1: one stacked kernel launch for the whole sub-batch ----
         if kernel_batch:
-            from repro.kernels.matchrank.ops import (
-                NO_ROW,
-                admit_matrix,
-                matchrank_batched_topk,
-                matchrank_candidates,
-            )
+            from repro.kernels.matchrank.ops import NO_ROW, matchrank_candidates
 
             attrs, valid, n_rows = st.snapshot.device_columns()
             # each request's resident replica rows, in _rows_of order; a row
@@ -1134,89 +1087,37 @@ class DataBroker:
                 cand_rows.append(
                     [r if row_ok[r] > 0 else NO_ROW for r in _rows_of(replica_lists[i], st)]
                 )
-            sparse_done = False
-            if use_sparse and top_k:
-                from repro.kernels.matchrank.sparse import canonicalize_plans
-
-                from .snapshot_sharded import ShardedSnapshot
-
-                admit_mat = admit_matrix(cand_rows, n_rows)
-
-                na = len(kernel_plans[0].attr_names)
-                iv = canonicalize_plans(kernel_plans, na)
-                if iv is not None and isinstance(st.snapshot, ShardedSnapshot):
-                    # tier 1a: per-shard walk + hierarchical merge, fronted
-                    # by the per-shard-epoch result cache (DESIGN.md §9)
-                    self._sharded_topk_tier(
-                        st,
-                        iv,
-                        kernel_batch,
-                        replica_lists,
-                        reqs,
-                        recs,
-                        results,
-                        admit_mat,
-                        top_k,
-                        vocab,
-                    )
-                    sparse_done = True
-                elif iv is not None:
-                    l_attrs, l_valid = st.snapshot.logical_columns()
-                    with self.tracer.span(
-                        "broker.sparse_topk",
-                        batch=len(kernel_batch),
-                        rows=st.snapshot.n,
-                        k=top_k,
-                    ):
-                        ti, ts = matchrank_batched_topk(
-                            l_attrs,
-                            l_valid,
-                            kernel_plans,
-                            k=top_k,
-                            admit=admit_mat[:, : st.snapshot.n],
-                            rank_order=st.snapshot.rank_order,
-                        )
-                    for bi, i in enumerate(kernel_batch):
-                        results[i] = self._ranked_from_topk(
-                            replica_lists[i], st, ti[bi], ts[bi]
-                        )
-                        recs[i].kernel_path = "sparse_topk"
-                        self._fill_batched_audit(recs[i], st, results[i])
-                        self._ctr["batched_sparse_requests"].inc()
-                    sparse_done = True
-            if not sparse_done:
-                guarded = [not p.plain for p in kernel_plans]
-                with self.tracer.span(
-                    "broker.kernel_launch",
-                    batch=len(kernel_batch),
-                    rows=n_rows,
+            guarded = [not p.plain for p in kernel_plans]
+            with self.tracer.span(
+                "broker.kernel_launch",
+                batch=len(kernel_batch),
+                rows=n_rows,
+                use_kernel=use_kernel,
+                guarded=sum(guarded),
+            ):
+                mask, score, _, _ = matchrank_candidates(
+                    attrs,
+                    valid,
+                    kernel_plans,
+                    cand_rows,
+                    n_rows=n_rows,
                     use_kernel=use_kernel,
-                    guarded=sum(guarded),
-                ):
-                    mask, score, _, _ = matchrank_candidates(
-                        attrs,
-                        valid,
-                        kernel_plans,
-                        cand_rows,
-                        n_rows=n_rows,
-                        use_kernel=use_kernel,
-                        tracer=self.tracer,
-                    )
-                self._ctr["kernel_launches"].inc()
-                if mask.shape[1] < attrs.shape[0]:
-                    self._ctr["kernel_launches_candidate"].inc()
-                for bi, i in enumerate(kernel_batch):
-                    results[i] = self._ranked_from_scores(
-                        queries[i][0], replica_lists[i], st, mask[bi], score[bi]
-                    )
-                    recs[i].kernel_path = "batched_kernel"
-                    self._fill_batched_audit(
-                        recs[i], st, results[i], rows=cand_rows[bi], mask=mask[bi],
-                        score=score[bi],
-                    )
-                    self._ctr["batched_kernel_requests"].inc()
-                    if guarded[bi]:
-                        self._ctr["batched_kernel_guarded_requests"].inc()
+                    tracer=self.tracer,
+                )
+            self._ctr["kernel_launches"].inc()
+            if mask.shape[1] < attrs.shape[0]:
+                self._ctr["kernel_launches_candidate"].inc()
+            for bi, i in enumerate(kernel_batch):
+                results[i] = self._ranked_from_scores(
+                    queries[i][0], replica_lists[i], st, mask[bi], score[bi]
+                )
+                recs[i].kernel_path = "batched_kernel"
+                self._fill_batched_audit(
+                    recs[i], st, results[i], cand_rows[bi], mask[bi], score[bi]
+                )
+                self._ctr["batched_kernel_requests"].inc()
+                if guarded[bi]:
+                    self._ctr["batched_kernel_guarded_requests"].inc()
 
         # ---- tier 2: columnar programs over the shared snapshot table ----
         for i in columnar:
@@ -1232,9 +1133,7 @@ class DataBroker:
                     queries[i][0], replica_lists[i], st, mask, score
                 )
             recs[i].kernel_path = "batched_columnar"
-            self._fill_batched_audit(
-                recs[i], st, results[i], rows=rows, mask=mask, score=score
-            )
+            self._fill_batched_audit(recs[i], st, results[i], rows, mask, score)
             self._ctr["batched_columnar_requests"].inc()
 
         # ---- tier 3: the paper-faithful interpreter, per request ----
@@ -1286,152 +1185,18 @@ class DataBroker:
             for r, sc in picked
         ]
 
-    def _ranked_from_topk(
-        self, replicas: Sequence[PhysicalFile], st: _SnapshotState, idx, scores
-    ) -> List[RankedReplica]:
-        """Sparse top-k winners (row indices + scores) → RankedReplica
-        list, re-sorted with the dense tiebreak key."""
-        by_row = _rows_of(replicas, st)
-        picked: List[Tuple[int, float]] = []
-        for r, s in zip(idx, scores):
-            r, s = int(r), float(s)
-            if r < 0 or (math.isinf(s) and s < 0):
-                continue  # empty slot past the request's match count
-            if r in by_row:
-                picked.append((r, s))
-        picked.sort(key=lambda rs: (-rs[1], _row_name(st, rs[0]), rs[0]))
-        return [
-            RankedReplica(ReplicaView(by_row[r], st.entries[r], st.ads[r]), s)
-            for r, s in picked
-        ]
-
-    def _sharded_topk_tier(
-        self,
-        st: _SnapshotState,
-        iv: Any,
-        kernel_batch: List[int],
-        replica_lists: Sequence[Optional[List[PhysicalFile]]],
-        reqs: Sequence[Optional[ClassAd]],
-        recs: Sequence[Any],
-        results: List[Any],
-        admit_mat: Any,
-        top_k: int,
-        vocab: Tuple[str, ...],
-    ) -> None:
-        """Tier 1a for sharded snapshots: each query is first looked up in
-        the per-shard-epoch result cache — valid while every shard its
-        candidates live in is unchanged — and only the misses walk the
-        per-shard sparse top-k + hierarchical merge (DESIGN.md §9)."""
-        import numpy as np
-        from contextlib import contextmanager
-
-        from repro.kernels.matchrank.sharded import sharded_sparse_topk
-        from repro.kernels.matchrank.sparse import IntervalBatch
-
-        from .plancache import request_cache_key
-
-        snap = st.snapshot
-        answers: Dict[int, Tuple[Any, Any]] = {}  # batch slot → (ti, ts)
-        shard_sets: List[List[int]] = []
-        keys: List[Tuple] = []
-        miss_bis: List[int] = []
-        for bi, i in enumerate(kernel_batch):
-            rows = [
-                r
-                for pfn in replica_lists[i]
-                if (r := st.row_of.get(pfn.endpoint)) is not None
-            ]
-            shard_sets.append(sorted({snap.shard_of_row(r) for r in rows}))
-            key = (
-                "sharded_topk",
-                recs[i].lfn,
-                int(top_k),
-                tuple(sorted(p.endpoint for p in replica_lists[i])),
-                request_cache_key(reqs[i], vocab, self.env),
-                snap.uid,
-            )
-            keys.append(key)
-            hit, val = self.plan_cache.topk_get(key, snap.shard_epochs)
-            if hit:
-                answers[bi] = val
-            else:
-                miss_bis.append(bi)
-        if miss_bis:
-            m = np.asarray(miss_bis, dtype=np.int64)
-            batch_m = IntervalBatch(
-                lo=iv.lo[m],
-                hi=iv.hi[m],
-                used=iv.used[m],
-                weights=iv.weights[m],
-                bias=iv.bias[m],
-                undef_rank=iv.undef_rank[m],
-            )
-            tracer = self.tracer
-
-            @contextmanager
-            def observe(g):
-                with tracer.span("broker.shard_rank", shard=int(g)) as sp:
-                    yield
-                self._shard_hist(int(g)).observe(sp.duration)
-
-            shards = [snap.shard_logical_columns(g) for g in range(snap.g)]
-            with self.tracer.span(
-                "broker.sharded_topk",
-                batch=len(miss_bis),
-                rows=snap.n,
-                shards=snap.g,
-                k=top_k,
-            ):
-                ti, ts = sharded_sparse_topk(
-                    shards,
-                    batch_m,
-                    k=top_k,
-                    offsets=snap.offsets,
-                    admit=admit_mat[m][:, : snap.n],
-                    rank_order=snap.shard_rank_order,
-                    observe=observe,
-                )
-            for j, bi in enumerate(miss_bis):
-                val = (ti[j].copy(), ts[j].copy())
-                touched = {g: int(snap.shard_epochs[g]) for g in shard_sets[bi]}
-                self.plan_cache.topk_put(keys[bi], touched, val)
-                answers[bi] = val
-        for bi, i in enumerate(kernel_batch):
-            ti_row, ts_row = answers[bi]
-            results[i] = self._ranked_from_topk(replica_lists[i], st, ti_row, ts_row)
-            recs[i].kernel_path = "sharded_topk"
-            recs[i].shards = sorted(
-                {snap.shard_of_row(int(r)) for r in ti_row if int(r) >= 0}
-            )
-            self._fill_batched_audit(recs[i], st, results[i])
-            self._ctr["batched_sharded_requests"].inc()
-
     def _fill_batched_audit(
-        self,
-        rec,
-        st: _SnapshotState,
-        result: List[RankedReplica],
-        rows=None,
-        mask=None,
-        score=None,
+        self, rec, st: _SnapshotState, result: List[RankedReplica], rows, mask, score
     ) -> None:
-        """Per-candidate fates for a snapshot-tier request. Dense tiers
-        pass (mask, score) at candidate ``rows`` (position j at
-        ``rows[j]``); the sparse tier only probed until k candidates
-        passed, so non-winners are recorded unmatched/unscored."""
-        if mask is not None:
-            pos = {r: j for j, r in enumerate(rows)}
-            scores = []
-            for ep in rec.candidates:
-                j = pos.get(st.row_of.get(ep))
-                ok = j is not None and bool(mask[j])
-                scores.append(CandidateScore(ep, float(score[j]) if ok else None, ok))
-            rec.scores = scores
-        else:
-            won = {rr.pfn.endpoint: rr.rank for rr in result}
-            rec.scores = [
-                CandidateScore(ep, won.get(ep), ep in won) for ep in rec.candidates
-            ]
+        """Per-candidate fates for a snapshot-tier request: (mask, score)
+        at candidate ``rows`` (position j at ``rows[j]``)."""
+        pos = {r: j for j, r in enumerate(rows)}
+        scores = []
+        for ep in rec.candidates:
+            j = pos.get(st.row_of.get(ep))
+            ok = j is not None and bool(mask[j])
+            scores.append(CandidateScore(ep, float(score[j]) if ok else None, ok))
+        rec.scores = scores
         rec.chosen = result[0].pfn.endpoint if result else None
 
     # ------------------------------------------------------------------ Access

@@ -644,11 +644,11 @@ def vectorized_match(request: ClassAd, views: Sequence, *, env=None, xp=np):
         sel[idxs] = True
         mask &= np.where(sel, pol, True)
 
-    order = _rank_order(mask, rank, views)
+    order = _ranked_indices(mask, rank, views)
     return [RankedReplica(views[i], float(rank[i])) for i in order]
 
 
-def _rank_order(mask: np.ndarray, rank: np.ndarray, views) -> List[int]:
+def _ranked_indices(mask: np.ndarray, rank: np.ndarray, views) -> List[int]:
     """Descending rank with the interpreter's deterministic tiebreak."""
 
     def name_of(i):

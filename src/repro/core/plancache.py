@@ -57,16 +57,11 @@ class PlanCache:
     def __init__(self, maxsize: int = 256, *, metrics: Any = None):
         self.maxsize = int(maxsize)
         self._entries: "OrderedDict[Tuple, Any]" = OrderedDict()
-        # sharded top-k results: key → (touched {shard: epoch}, value)
-        self._topk: "OrderedDict[Tuple, Tuple[Dict[int, int], Any]]" = OrderedDict()
         self.stats = {
             "hits": 0,
             "misses": 0,
             "evictions": 0,
             "negative_hits": 0,
-            "topk_hits": 0,
-            "topk_misses": 0,
-            "topk_stale": 0,
         }
         # optional mirror into an obs MetricsRegistry (labels: event=...);
         # self.stats stays the source of truth for exact-count consumers
@@ -178,42 +173,8 @@ class PlanCache:
             ),
         )
 
-    # ------------------------------------------- sharded top-k results
-    def topk_get(self, key: Tuple, shard_epochs: Sequence[int]) -> Tuple[bool, Any]:
-        """Look up a cached selection result under per-shard epoch keys.
-
-        A hit requires every shard the result's candidate set *touched*
-        to still be at the epoch it was computed against — so one site's
-        ``update_rows`` invalidates only results that drew candidates
-        from that shard, never the rest of the federation's (DESIGN.md
-        §9 cache keying). Stale entries are dropped eagerly."""
-        entry = self._topk.get(key)
-        if entry is None:
-            self._bump("topk_misses")
-            return False, None
-        touched, val = entry
-        for g, ep in touched.items():
-            if g >= len(shard_epochs) or int(shard_epochs[g]) != ep:
-                del self._topk[key]
-                self._bump("topk_stale")
-                self._bump("topk_misses")
-                return False, None
-        self._topk.move_to_end(key)
-        self._bump("topk_hits")
-        return True, val
-
-    def topk_put(self, key: Tuple, touched: Dict[int, int], val: Any) -> None:
-        """Store a selection result with the {shard: epoch} set its
-        candidates came from."""
-        self._topk[key] = (dict(touched), val)
-        self._topk.move_to_end(key)
-        while len(self._topk) > self.maxsize:
-            self._topk.popitem(last=False)
-            self._bump("evictions")
-
     def clear(self) -> None:
         self._entries.clear()
-        self._topk.clear()
 
     def __len__(self) -> int:
-        return len(self._entries) + len(self._topk)
+        return len(self._entries)

@@ -129,9 +129,6 @@ class ReplicaSnapshot:
             self._fill_row_host(i, e)
         self._attrs_dev = None
         self._valid_dev = None
-        self._rank_orders: Dict[
-            Tuple[bytes, float], Tuple[int, np.ndarray, np.ndarray]
-        ] = {}
         if self._device:
             self._push_all()
 
@@ -161,20 +158,6 @@ class ReplicaSnapshot:
     def host_columns(self) -> Tuple[np.ndarray, np.ndarray, int]:
         return self._attrs, self._valid, self.n
 
-    def logical_columns(self) -> Tuple[np.ndarray, np.ndarray]:
-        """→ contiguous (attrs [n, A] f32, valid [n, A] bool) over the live
-        rows at logical (unpadded) width — the operand shape of the sparse
-        top-k walk, where striding across the padded block would defeat
-        the cache. Materialized once per version."""
-        a = len(self.attr_names)
-        hit = getattr(self, "_logical", None)
-        if hit is not None and hit[0] == self.version:
-            return hit[1], hit[2]
-        attrs = np.ascontiguousarray(self._attrs[: self.n, :a])
-        valid = np.ascontiguousarray(self._valid[: self.n, :a] > 0.5)
-        self._logical = (self.version, attrs, valid)
-        return attrs, valid
-
     def table(self) -> ColumnTable:
         """An f64 :class:`ColumnTable` over the live rows — the operand of
         columnar programs and compiled server policies (numpy semantics
@@ -191,40 +174,6 @@ class ReplicaSnapshot:
     def vocab_key(self) -> Tuple[str, ...]:
         """Hashable vocabulary identity for plan caching."""
         return tuple(self.attr_names)
-
-    def rank_order(
-        self, weights: np.ndarray, bias: float = 0.0
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """→ (order, svals) for a linear rank over the live rows, with the
-        dense ref's Condor semantics: a row where *any* non-zero-weight
-        attribute is invalid scores 0.0 (the whole rank is Undefined, bias
-        included); everywhere else ``attrs @ w + bias``. ``order`` is a
-        *stable* descending argsort (ties → lowest row index, matching the
-        dense top-k).
-
-        Cached per (version, weights, bias) — the sort is paid once per
-        epoch per distinct rank expression, then every sparse top-k walk
-        (:func:`repro.kernels.matchrank.sparse.topk_in_rank_order`)
-        reuses it. Row updates invalidate via the version bump."""
-        w = np.asarray(weights, dtype=np.float32).reshape(-1)
-        a = len(self.attr_names)
-        if w.shape[0] < a:
-            w = np.pad(w, (0, a - w.shape[0]))
-        key = (w[:a].tobytes(), float(bias))
-        hit = self._rank_orders.get(key)
-        if hit is not None and hit[0] == self.version:
-            return hit[1], hit[2]
-        live_a = self._attrs[: self.n, :a]
-        live_v = self._valid[: self.n, :a]
-        w = w[:a]
-        svals = (live_a @ w + np.float32(bias)).astype(np.float32)
-        wactive = w != 0
-        if wactive.any():
-            bad = ~(live_v[:, wactive] > 0.5).all(axis=1)
-            svals[bad] = 0.0
-        order = np.argsort(-svals, kind="stable")
-        self._rank_orders[key] = (self.version, order, svals)
-        return order, svals
 
     # ------------------------------------------------------------ mutation
     def update_rows(self, updates: Mapping[int, Mapping[str, Any]]) -> None:

@@ -1,26 +1,20 @@
-"""Sharded device-resident snapshots: one shard per GRIS/GIIS registrant.
+"""Sharded replica snapshots: one shard per GRIS/GIIS registrant.
 
-The flat :class:`~repro.core.snapshot.ReplicaSnapshot` re-pushes every
-column when an epoch rolls — fine at S=10k, hopeless at the GIIS
-federation scale where one site's dynamic-attribute refresh would force
-re-uploading a million untouched rows. A :class:`ShardedSnapshot`
-partitions the replica rows along the information-service topology:
+The flat :class:`~repro.core.snapshot.ReplicaSnapshot` is rebuilt whole
+when an epoch rolls. A :class:`ShardedSnapshot` partitions the replica
+rows along the information-service topology, so that one site's
+dynamic-attribute refresh touches only that site's rows:
 
-  * rows are grouped into named shards (per-GRIS / per-GIIS-registrant),
-    stacked into ``[G, S_shard, A_PAD]`` blocks over ONE shared attribute
-    vocabulary — the operand shape of the vmapped per-shard matchrank
-    (:mod:`repro.kernels.matchrank.sharded`),
-  * **delta refresh**: ``update_rows``/``refresh`` track dirty shards and
-    re-upload only those — ``shard_epochs[g]`` bumps per dirty shard and
-    ``pushed_rows`` accounts exactly what went to the device, so a 1%%
-    update ships ~1%% of the rows,
-  * per-shard rank-order caches: one site's update re-sorts only its own
-    shard's rows, not the federation,
-  * **double-buffered epoch swap** for free: device blocks are immutable
-    per-shard ``jax.Array``s (replaced, never mutated) and the stacked
-    ``[G, S_shard, A_PAD]`` kernel operand is rebuilt lazily per version,
-    so any in-flight selection holding references to the previous arrays
-    keeps computing against a consistent epoch while the snapshot swaps.
+  * rows are grouped into named shards (per-GRIS / per-GIIS-registrant)
+    over ONE shared attribute vocabulary,
+  * **delta refresh**: ``update_rows``/``refresh`` refill only the shards
+    whose entries changed, and ``shard_epochs[g]`` bumps per changed
+    shard; ``refresh_from_giis`` re-reads only the registrants whose
+    GIIS epoch moved,
+  * :meth:`~ShardedSnapshot.device_columns` is the one device view: the
+    live rows of every shard as the flat snapshot's padded ``[S_PAD,
+    A_PAD]`` block, built lazily once per version. Every broker, flat or
+    sharded, answers through the same candidate launch over it.
 
 The global row space is the shard-major concatenation of live rows (shard
 order = sorted shard names), so brokers keep using plain integer rows;
@@ -29,7 +23,6 @@ order = sorted shard names), so brokers keep using plain integer rows;
 
 from __future__ import annotations
 
-import itertools
 import zlib
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -39,9 +32,6 @@ from .compile import ColumnTable
 from .snapshot import _round_up, entry_row, numeric_attr_names
 
 __all__ = ["ShardedSnapshot", "shard_by_hash"]
-
-_UID = itertools.count(1)
-
 
 def shard_by_hash(key: str, n_shards: int) -> int:
     """Deterministic endpoint→shard bucket (crc32 — platform-stable,
@@ -64,8 +54,8 @@ class ShardedSnapshot:
     block_s:
         Row padding granularity per shard (the kernel's S-block).
     device:
-        Keep the stacked ``[G, S_shard, A_PAD]`` f32 blocks resident as
-        ``jax.Array``s.
+        Hand out :meth:`device_columns` as ``jax.Array``s (numpy blocks
+        otherwise).
     """
 
     def __init__(
@@ -94,8 +84,6 @@ class ShardedSnapshot:
         self.epoch = int(epoch)
         self.version = 0  # bumped on every mutation
         self._device = bool(device)
-        #: identity for result caches (two snapshots must never share keys)
-        self.uid = next(_UID)
 
         self.g = len(self.shard_names)
         self.counts = np.array(
@@ -114,29 +102,13 @@ class ShardedSnapshot:
         for gi in range(self.g):
             self._fill_shard_host(gi)
 
-        #: per-shard delta-refresh counters — the PlanCache's sharded
-        #: result-cache validity key: a cached top-k stays valid iff every
-        #: shard that contributed (or could have contributed) candidates
-        #: still carries the epoch recorded at store time.
+        #: per-shard delta-refresh counters: which shards each
+        #: ``update_rows``/``refresh`` changed
         self.shard_epochs = np.zeros((self.g,), dtype=np.int64)
-        #: device-upload accounting: live rows shipped so far. Proves the
-        #: delta behaviour in tests/benchmarks (``.at[g].set`` replaces the
-        #: whole stacked array object, so identity can't).
-        self.pushed_rows = 0
-        self.push_counts = np.zeros((self.g,), dtype=np.int64)
-        # (w bytes, bias) → per-shard [(shard_epoch, order, svals) | None]
-        self._rank_orders: Dict[
-            Tuple[bytes, float], List[Optional[Tuple[int, np.ndarray, np.ndarray]]]
-        ] = {}
         self._shard_logical: List[Optional[Tuple[int, np.ndarray, np.ndarray]]] = [
             None
         ] * self.g
-        self._attrs_dev = None
-        self._valid_dev = None
-        self._stacked_dev = None  # lazy (version, attrs, valid) kernel stack
-        self._flat_dev = None  # lazy flat-compatible padded block
-        if self._device:
-            self._push_all()
+        self._flat_dev = None  # lazy (version, attrs, valid) padded block
 
     # ------------------------------------------------------------- building
     def _fill_shard_host(self, gi: int) -> None:
@@ -148,31 +120,6 @@ class ShardedSnapshot:
             self._attrs[gi, li] = vals
             self._valid[gi, li] = ok
 
-    def _push_all(self) -> None:
-        import jax.numpy as jnp
-
-        self._attrs_dev = [jnp.asarray(self._attrs[gi]) for gi in range(self.g)]
-        self._valid_dev = [jnp.asarray(self._valid[gi]) for gi in range(self.g)]
-        self.pushed_rows += self.n
-        self.push_counts += 1
-
-    def _push_shards(self, dirty: Sequence[int]) -> None:
-        """Re-upload only the dirty shards. Device blocks are held
-        per-shard (one ``[S_shard, A_PAD]`` array each), so a 1-shard
-        delta ships 1/G of the bytes — the stacked view the vmapped
-        kernel wants is materialized lazily in
-        :meth:`shard_device_columns`, cached per version."""
-        if self._attrs_dev is None or not dirty:
-            return
-        import jax.numpy as jnp
-
-        gidx = sorted(int(g) for g in dirty)
-        for gi in gidx:
-            self._attrs_dev[gi] = jnp.asarray(self._attrs[gi])
-            self._valid_dev[gi] = jnp.asarray(self._valid[gi])
-        self.pushed_rows += int(self.counts[gidx].sum())
-        self.push_counts[gidx] += 1
-
     # ------------------------------------------------------------ accessors
     def shard_of_row(self, row: int) -> int:
         """Global row index → owning shard index."""
@@ -180,31 +127,11 @@ class ShardedSnapshot:
             raise IndexError(f"row {row} outside snapshot (n={self.n})")
         return int(np.searchsorted(self.offsets, row, side="right") - 1)
 
-    def shard_device_columns(self):
-        """→ (attrs [G, S_shard, A_PAD], valid, counts [G]) — the stacked
-        per-shard blocks the vmapped kernel consumes. The stack is built
-        lazily and cached per version: the sparse CPU walk never pays for
-        it, and a delta refresh only re-stacks when the kernel tier next
-        asks (in-flight consumers keep their previous epoch's stack —
-        the double-buffered swap)."""
-        if self._attrs_dev is None:
-            return self._attrs, self._valid, self.counts
-        hit = self._stacked_dev
-        if hit is not None and hit[0] == self.version:
-            return hit[1], hit[2], self.counts
-        import jax.numpy as jnp
-
-        attrs = jnp.stack(self._attrs_dev)
-        valid = jnp.stack(self._valid_dev)
-        self._stacked_dev = (self.version, attrs, valid)
-        return attrs, valid, self.counts
-
     def device_columns(self):
-        """Flat-compatible view → (attrs [S_PAD, A_PAD], valid, n): the
-        live rows of every shard concatenated and re-padded, for callers
-        that speak the flat :class:`ReplicaSnapshot` protocol (the dense
-        batched fallback). Materialized lazily, cached per version — the
-        sharded hot paths never touch it."""
+        """→ (attrs [S_PAD, A_PAD], valid, n): the live rows of every
+        shard concatenated in global row order and padded as the flat
+        :class:`ReplicaSnapshot` pads them — the candidate launch's
+        operand. Materialized lazily, cached per version."""
         hit = self._flat_dev
         if hit is not None and hit[0] == self.version:
             return hit[1], hit[2], self.n
@@ -224,8 +151,8 @@ class ShardedSnapshot:
 
     def shard_logical_columns(self, gi: int) -> Tuple[np.ndarray, np.ndarray]:
         """→ contiguous (attrs [c_g, A] f32, valid [c_g, A] bool) over one
-        shard's live rows at logical width — the sparse walk's operand.
-        Cached per (shard, shard_epoch)."""
+        shard's live rows at logical width. Cached per (shard,
+        shard_epoch)."""
         hit = self._shard_logical[gi]
         if hit is not None and hit[0] == self.shard_epochs[gi]:
             return hit[1], hit[2]
@@ -238,7 +165,7 @@ class ShardedSnapshot:
 
     def logical_columns(self) -> Tuple[np.ndarray, np.ndarray]:
         """Global contiguous (attrs [n, A] f32, valid [n, A] bool) in
-        shard-major row order — the flat-protocol view."""
+        shard-major row order."""
         hit = getattr(self, "_logical", None)
         if hit is not None and hit[0] == self.version:
             return hit[1], hit[2]
@@ -268,41 +195,10 @@ class ShardedSnapshot:
     def vocab_key(self) -> Tuple[str, ...]:
         return tuple(self.attr_names)
 
-    def shard_rank_order(
-        self, gi: int, weights: np.ndarray, bias: float = 0.0
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Per-shard (order, svals) with the flat snapshot's Condor rank
-        semantics, over *local* row indices. Cached per (weights, bias,
-        shard_epoch): one shard's delta refresh re-sorts only its own
-        ``S/G`` rows."""
-        a = len(self.attr_names)
-        w = np.asarray(weights, dtype=np.float32).reshape(-1)
-        if w.shape[0] < a:
-            w = np.pad(w, (0, a - w.shape[0]))
-        w = w[:a]
-        key = (w.tobytes(), float(bias))
-        per = self._rank_orders.get(key)
-        if per is None:
-            per = [None] * self.g
-            self._rank_orders[key] = per
-        hit = per[gi]
-        if hit is not None and hit[0] == self.shard_epochs[gi]:
-            return hit[1], hit[2]
-        attrs, valid = self.shard_logical_columns(gi)
-        svals = (attrs @ w + np.float32(bias)).astype(np.float32)
-        wactive = w != 0
-        if wactive.any():
-            bad = ~valid[:, wactive].all(axis=1)
-            svals[bad] = 0.0
-        order = np.argsort(-svals, kind="stable")
-        per[gi] = (int(self.shard_epochs[gi]), order, svals)
-        return order, svals
-
     # ------------------------------------------------------------ mutation
     def update_rows(self, updates: Mapping[int, Mapping[str, Any]]) -> List[int]:
         """Incremental refresh keyed by *global* row: merge attribute
-        dicts into existing rows, re-upload only the shards touched.
-        Returns the dirty shard indices."""
+        dicts into existing rows. Returns the dirty shard indices."""
         if not updates:
             return []
         from .snapshot import _numeric
@@ -345,7 +241,6 @@ class ShardedSnapshot:
                 self._valid[gi, li] = ok
             dirty[gi] = True
         changed = sorted(dirty)
-        self._push_shards(changed)
         self.shard_epochs[changed] += 1
         self.version += 1
         return changed
@@ -354,8 +249,8 @@ class ShardedSnapshot:
         self, shard_entries: Mapping[str, Sequence[Mapping[str, Any]]]
     ) -> List[str]:
         """Epoch roll with delta detection: compare each shard's new entry
-        list against the resident one; only *changed* shards are refilled
-        and re-uploaded. Returns the changed shard names.
+        list against the resident one; only *changed* shards are refilled.
+        Returns the changed shard names.
 
         Raises ``ValueError`` when the shard set or a shard's row count
         changed, or when a new numeric attribute falls outside the shared
@@ -400,7 +295,6 @@ class ShardedSnapshot:
             self.entries_by_shard[name] = new_lists[name]
             self._fill_shard_host(gi)
             dirty.append(gi)
-        self._push_shards(dirty)
         self.shard_epochs[dirty] += 1
         self.version += 1
         return changed
@@ -435,9 +329,9 @@ class ShardedSnapshot:
 
     def refresh_from_giis(self, giis) -> List[str]:
         """Delta refresh driven by the GIIS's per-registrant epoch
-        counters: only registrants whose epoch moved are re-read, the rest
-        never leave the device. Raises ``ValueError`` (like
-        :meth:`refresh`) when the topology changed structurally."""
+        counters: only registrants whose epoch moved are re-read. Raises
+        ``ValueError`` (like :meth:`refresh`) when the topology changed
+        structurally."""
         prev = getattr(self, "_giis_epochs", {})
         now_epochs = giis.registrant_epochs(refresh=True)
         if sorted(now_epochs) != self.shard_names:
@@ -459,5 +353,5 @@ class ShardedSnapshot:
         return (
             f"ShardedSnapshot(g={self.g}, n={self.n}, a={len(self.attr_names)}, "
             f"pad=[{self.s_shard_pad},{self.a_pad}], epoch={self.epoch}, "
-            f"version={self.version}, pushed_rows={self.pushed_rows})"
+            f"version={self.version})"
         )

@@ -52,7 +52,6 @@ __all__ = [
     "matchrank_candidates",
     "candidate_bucket",
     "admit_matrix",
-    "matchrank_batched_topk",
     "lower_matchrank_batched",
     "pad_columns",
 ]
@@ -98,10 +97,10 @@ class KernelPlan:
 
     @functools.cached_property
     def plain(self) -> bool:
-        """Fail-closed requirement terms and one linear rank — the plans
-        the interval walk and the snapshot's rank orders can answer.
-        Plans are shared read-only through the plan cache, so this is
-        worked out once per plan."""
+        """Fail-closed requirement terms and one linear rank: no guarded
+        term and no fallback rank alternative. The broker counts the
+        launch's other plans as guarded. Plans are shared read-only
+        through the plan cache, so this is worked out once per plan."""
         return (
             not (self.op_codes & UNDEF_PASSES).any()
             and not (self.term_role > 1.5).any()
@@ -465,8 +464,7 @@ def _matchrank_batched_dense_host(
     attrs, valid, batched: BatchedPlan, admit, s: int, k: int
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Host evaluation of the dense batched fallback, tiled by *shared
-    work* instead of materializing the [B, S, T] einsum of the jnp ref
-    (which made the fallback ~370× slower than the sparse walk).
+    work* instead of materializing the [B, S, T] einsum of the jnp ref.
 
     Terms are grouped by (column, opcode) — one vectorized compare per
     group serves every request that asked it (broker batches are
@@ -855,56 +853,3 @@ def lower_matchrank_batched(
         attrs, valid, batched, _admitted_rows(admit, batched.b, s), n_rows, k, block_s
     )
     return _dispatch_batched.lower(*operands, **static, interpret=interpret)
-
-
-def matchrank_batched_topk(
-    attrs: np.ndarray,  # [S, A] (unpadded) or pre-padded [S_PAD, A_PAD]
-    valid: np.ndarray,
-    plans: Sequence[KernelPlan],
-    *,
-    k: int = 1,
-    admit: Optional[np.ndarray] = None,  # [B, S] per-request pre-mask
-    n_rows: Optional[int] = None,
-    rank_order=None,  # Callable[[weights], (order, svals)] — snapshot cache
-    use_sparse: Optional[bool] = None,
-    block_s: int = 512,
-    use_kernel: bool = False,
-    interpret: Optional[bool] = None,
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Batched top-k *selection*: B requests → (topk_idx [B,k],
-    topk_scores [B,k]); slots past a request's match count hold -inf
-    (and index -1 on the sparse path).
-
-    The steady-state CPU fast path answers each request by scanning
-    candidates in precomputed rank-descending order until k pass its
-    interval-canonicalized requirements (expected probes ≈ k/selectivity
-    — see :mod:`.sparse`); plans outside the interval subset, or
-    ``use_sparse=False``, fall back to the dense batched launch. Pass a
-    :meth:`ReplicaSnapshot.rank_order <repro.core.snapshot.ReplicaSnapshot.rank_order>`
-    so the per-(epoch, rank-weights) sort is amortized across calls.
-    """
-    from .sparse import canonicalize_plans, topk_in_rank_order
-
-    plans = list(plans)
-    na = len(plans[0].attr_names)
-    if use_sparse is not False:
-        batch = canonicalize_plans(plans, na)
-        if batch is not None:
-            a_host = np.asarray(attrs, dtype=np.float32)
-            v_host = np.asarray(valid)
-            s = a_host.shape[0] if n_rows is None else int(n_rows)
-            return topk_in_rank_order(
-                a_host[:s, :na],
-                v_host[:s, :na] > 0.5 if v_host.dtype != bool else v_host[:s, :na],
-                batch,
-                k=k,
-                admit=admit,
-                rank_order=rank_order,
-            )
-        if use_sparse:
-            raise CompileError("plan batch not interval-canonicalizable")
-    _, _, ti, ts = matchrank_batched(
-        attrs, valid, plans, admit=admit, n_rows=n_rows, k=k,
-        block_s=block_s, use_kernel=use_kernel, interpret=interpret,
-    )
-    return ti.astype(np.int64), ts

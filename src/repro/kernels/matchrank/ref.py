@@ -131,32 +131,3 @@ def matchrank_ref(
         term_role[None], weights[None], bias[None], k=1,
     )
     return mask[0], score[0], best_s[0], best_i[0]
-
-
-def merge_topk_ref(cand_scores, cand_idx, k: int):
-    """NumPy oracle for the hierarchical merge stage: global top-k over
-    per-shard candidate lists, by k knockout-argmax rounds.
-
-    ``cand_scores``/``cand_idx`` are [B, C] — each request's per-shard
-    top-k lists flattened **shard-major** (shard 0's k candidates, then
-    shard 1's, ...). Because every per-shard list is rank-descending with
-    ties at the lowest local index, the flattened position order equals
-    the global-row order within each score value, so first-maximum
-    knockout reproduces ``lax.top_k``'s tie-break (lowest global row)
-    exactly. Empty slots hold score -inf; their index rides along
-    untouched (callers treat -inf slots as meaningless, like the fused
-    kernel's). Returns (scores [B, k] f32, idx [B, k])."""
-    import numpy as np
-
-    s = np.array(cand_scores, dtype=np.float32, copy=True)
-    idx = np.asarray(cand_idx)
-    b = s.shape[0]
-    rows = np.arange(b)
-    out_s = np.full((b, k), NEG_INF, dtype=np.float32)
-    out_i = np.zeros((b, k), dtype=idx.dtype)
-    for j in range(k):
-        m = np.argmax(s, axis=1)
-        out_s[:, j] = s[rows, m]
-        out_i[:, j] = idx[rows, m]
-        s[rows, m] = NEG_INF
-    return out_s, out_i

@@ -28,8 +28,6 @@ PATHS = (
     "interpreter",       # per-ad ClassAd interpreter (reference semantics)
     "vectorized",        # columnar engine inside a sequential select()
     "batched_kernel",    # stacked matchrank_batched launch (Pallas / ref)
-    "sparse_topk",       # rank-order sparse top-k CPU fast path
-    "sharded_topk",      # per-shard walk + hierarchical merge (DESIGN.md §9)
     "batched_columnar",  # per-request columnar program over the snapshot
     "batched_interp",    # interpreter fallback inside select_many
 )
@@ -64,9 +62,6 @@ class DecisionRecord:
     top_k: Optional[int] = None
     plan_cache: Optional[str] = None  # "hit" | "miss" | None (tier unused)
     snapshot: Optional[str] = None  # "build" | "reuse" | "delta" | None
-    # shard indices that contributed this selection's candidates (sharded
-    # snapshots only — which corners of the federation the answer touched)
-    shards: List[int] = field(default_factory=list)
     error: Optional[str] = None  # BrokerError name when the selection failed
     # request-ad analyzer findings (repro.analysis Diagnostic dicts),
     # recorded when the broker runs with ad_check enabled

@@ -31,13 +31,10 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "ShardingPolicy",
-    "distribute_shards",
-    "shard_axis_mesh",
     "tree_shardings",
     "tree_specs",
 ]
@@ -286,38 +283,6 @@ class ShardingPolicy:
 
     def explain(self) -> List[str]:
         return list(dict.fromkeys(self.fallbacks))
-
-
-# ---------------------------------------------------------------------------
-# shard-axis meshes (sharded matchmaking, DESIGN.md §9)
-# ---------------------------------------------------------------------------
-
-
-def shard_axis_mesh(n_shards: int, *, axis: str = "shard") -> Mesh:
-    """A 1-D mesh over the snapshot's shard axis.
-
-    Uses the largest device count ≤ ``n_shards`` that *divides*
-    ``n_shards``, so the vmapped per-shard matchrank partitions evenly
-    (each device ranks n_shards/devices shards). On a single device
-    (CPU test rigs) this degenerates to a 1-device mesh — same results,
-    batched loop instead of parallel execution.
-    """
-    devices = jax.devices()
-    use = 1
-    for d in range(min(len(devices), max(1, int(n_shards))), 0, -1):
-        if n_shards % d == 0:
-            use = d
-            break
-    return Mesh(np.asarray(devices[:use]), (axis,))
-
-
-def distribute_shards(*arrays, mesh: Mesh, axis: str = "shard"):
-    """Lay stacked ``[G, ...]`` per-shard blocks out along ``mesh``'s
-    shard axis (leading dim sharded, rest replicated). Returns the
-    arrays in input order (a single array when one is passed)."""
-    sharding = NamedSharding(mesh, P(axis))
-    out = tuple(jax.device_put(a, sharding) for a in arrays)
-    return out[0] if len(out) == 1 else out
 
 
 # ---------------------------------------------------------------------------

@@ -300,20 +300,20 @@ class TestInjectedLintViolations:
         )
         assert check_kernel_source(ok, "repro/kernels/ok/kernel.py") == []
 
-    def test_merge_kernel_blockspec_alignment(self):
-        """The hierarchical merge stage (DESIGN.md §9) sizes its output
-        blocks by a module-level constant; the checker must resolve it —
+    def test_module_constant_blockspec_alignment(self):
+        """The matchrank kernel sizes its top-k carry blocks by a
+        module-level constant (``K_PAD``); the checker must resolve it —
         both to keep the real kernel honest and to flag a bad edit."""
         from repro.analysis import check_kernel_file
 
-        real = os.path.join(SRC, "kernels", "matchrank", "sharded.py")
+        real = os.path.join(SRC, "kernels", "matchrank", "kernel.py")
         assert check_kernel_file(real) == []
         doctored = (
             "import jax.experimental.pallas as pl\n"
-            "MERGE_K_PAD = 100\n"  # not 1 and not a lane multiple
+            "K_PAD = 100\n"  # not 1 and not a lane multiple
             "def merge(b, c_pad=256):\n"
             "    grid = (b,)\n"
-            "    out = pl.BlockSpec((1, MERGE_K_PAD), lambda bi: (bi, 0))\n"
+            "    out = pl.BlockSpec((1, K_PAD), lambda bi: (bi, 0))\n"
         )
         diags = check_kernel_source(doctored, "repro/kernels/matchrank/bad.py")
         assert rules(diags) == ["KRN001"]

@@ -1,8 +1,9 @@
 """The candidate-row launch: each request's candidate rows go in, mask and
 score at those rows come out, in one packed transfer each way. It must
-rank exactly as the dense launch over a ``[B, S]`` admit pre-mask and as
-the grouped host evaluation; a broker's flushes must reuse the programs a
-dense warm-up compiled, and make one put and one fetch a launch."""
+rank exactly as the dense launch over a ``[B, S]`` admit pre-mask, as the
+grouped host evaluation and as the ``ref.py`` oracle, top-k included; a
+broker's flushes must reuse the programs a dense warm-up compiled, and
+make one put and one fetch a launch."""
 
 import jax
 import numpy as np
@@ -11,16 +12,19 @@ import pytest
 import repro.kernels.matchrank.ops as mr_ops
 from repro.core.broker import default_read_request
 from repro.core.classads import parse_classad
+from repro.core.snapshot import ReplicaSnapshot
 from repro.kernels.matchrank.kernel import matchrank_batched_pallas
 from repro.kernels.matchrank.ops import (
     NO_ROW,
     _matchrank_batched_dense_host,
+    admit_matrix,
     candidate_bucket,
     lower_request,
     matchrank_batched,
     matchrank_candidates,
     stack_plans,
 )
+from repro.kernels.matchrank.ref import matchrank_batched_ref
 from repro.storage.endpoint import build_demo_grid
 
 CLIENT = "client://reader"
@@ -33,15 +37,15 @@ S, BLOCK_S = 300, 128  # S_PAD = 384
 S_PAD = 384
 
 
-def _columns(seed):
-    """Attributes that are whole multiples of powers of two (exact in f32),
-    a third of the cells Undefined."""
+def _columns(seed, s=S):
+    """``s`` rows of attributes that are whole multiples of powers of two
+    (exact in f32), a third of the cells Undefined."""
     rng = np.random.default_rng(seed)
     attrs = np.stack([
-        rng.integers(0, 64, S) * float(1 << 30), rng.integers(0, 64, S) * float(MiB),
-        rng.choice([0.0, 1.0, 0.5], S), rng.integers(1, 6, S) * 2e8,
-        rng.integers(-4, 64, S) * float(MiB), rng.integers(0, 64, S) * float(MiB),
-        rng.choice([-1.0, 0.0, 0.5, 1.0, 2.0, 7.0], S), rng.integers(0, 64, S) * float(MiB),
+        rng.integers(0, 64, s) * float(1 << 30), rng.integers(0, 64, s) * float(MiB),
+        rng.choice([0.0, 1.0, 0.5], s), rng.integers(1, 6, s) * 2e8,
+        rng.integers(-4, 64, s) * float(MiB), rng.integers(0, 64, s) * float(MiB),
+        rng.choice([-1.0, 0.0, 0.5, 1.0, 2.0, 7.0], s), rng.integers(0, 64, s) * float(MiB),
     ], 1).astype(np.float32)
     return attrs, rng.random(attrs.shape) > 0.3
 
@@ -182,6 +186,147 @@ def test_candidate_bucket():
         8, 8, 8, 16, 16, 32, 128
     ]
     assert candidate_bucket(300, 384) == 384 and candidate_bucket(3, 4) == 4
+
+
+# ----------------------------------------- top-k against the ref.py oracle
+def _oracle(attrs, valid, plans, rows, k):
+    """``ref.py``'s batched oracle over the admit matrix the candidate
+    lists stand for. → (mask [B,S], score [B,S], topk_idx, topk_scores)."""
+    s = attrs.shape[0]
+    bp = stack_plans(plans)
+    ap, vp, s_pad = mr_ops.pad_columns(attrs, valid, bp.a_pad, BLOCK_S)
+    admit = np.zeros((bp.b, s_pad), np.float32)
+    admit[:, :s] = admit_matrix(rows, s)
+    m, sc, ts, ti = matchrank_batched_ref(
+        ap, vp, admit, bp.sel, bp.op_codes, bp.thresholds, bp.term_role,
+        bp.weights, bp.bias, k=k,
+    )
+    return np.asarray(m)[:, :s], np.asarray(sc)[:, :s], np.asarray(ti), np.asarray(ts)
+
+
+def _launch_matches_oracle(attrs, valid, plans, rows, k):
+    """The launch, on the kernel (interpret mode here) and on the host
+    evaluation, against the oracle: mask and score at every candidate
+    column, and the top-k. → the kernel's (mask, score, idx, scores)."""
+    s = attrs.shape[0]
+    m, sc, oi, ot = _oracle(attrs, valid, plans, rows, k)
+    out = None
+    for use_kernel in (True, False):
+        cm, cs, ci, ct = matchrank_candidates(
+            attrs, valid, plans, rows, k=k, block_s=BLOCK_S, use_kernel=use_kernel
+        )
+        for bi, r in enumerate(rows):
+            real = np.array([x < s for x in r], bool)
+            at = np.array([x if x < s else 0 for x in r], np.intp)
+            np.testing.assert_array_equal(cm[bi, : len(r)], real & m[bi, at])
+            want = np.where(real & m[bi, at], sc[bi, at], np.float32(-np.inf))
+            np.testing.assert_array_equal(cs[bi, : len(r)], want)
+            assert not cm[bi, len(r) :].any()
+        np.testing.assert_array_equal(ci, oi)
+        np.testing.assert_array_equal(ct, ot)
+        out = out or (cm, cs, ci, ct)
+    return out
+
+
+TOPK_NAMES = ["x"]
+
+
+class TestCandidateTopK:
+    """The cases the rank-order walk was held to, asked of the launch."""
+
+    @pytest.mark.parametrize("s", [5, 257, 1000])
+    @pytest.mark.parametrize("k", [1, 3])
+    def test_topk_matches_dense(self, s, k):
+        seed = s * 10 + k
+        rng = np.random.default_rng(seed)
+        attrs, valid = _columns(seed, s)
+        plans = _plain_plans(seed, 4) + _default_plans(seed, 2)
+        rows = [
+            [int(r) for r in rng.choice(s, size=int(rng.integers(0, min(s, 8) + 1)), replace=False)]
+            for _ in plans
+        ]
+        _launch_matches_oracle(attrs, valid, plans, rows, k)
+
+    def test_random_admit_as_candidate_lists(self):
+        rng = np.random.default_rng(7)
+        attrs, valid = _columns(7, 400)
+        plans = _plain_plans(7, 4)
+        rows = [np.flatnonzero(rng.random(400) > 0.7).tolist() for _ in plans]
+        cm, _, _, _ = _launch_matches_oracle(attrs, valid, plans, rows, 2)
+        assert cm.shape[1] == candidate_bucket(max(map(len, rows)), 512)
+
+    def test_ne_plan(self):
+        """A ``!=`` term, which the rank-order walk could not canonicalize."""
+        attrs, valid = _columns(8)
+        ne = lower_request(parse_classad(
+            "rank = other.avgRDBandwidth; requirements = other.loadFactor != 0.5;"), VOCAB)
+        rows = [list(range(0, S, 3)), list(range(1, 40))]
+        cm, _, _, _ = _launch_matches_oracle(attrs, valid, [ne, ne], rows, 3)
+        lf = VOCAB.index("loadfactor")
+        for bi, r in enumerate(rows):
+            want = valid[r, lf] & (attrs[r, lf] != 0.5)
+            np.testing.assert_array_equal(cm[bi, : len(r)], want)
+
+    def test_absent_attribute_never_matches(self):
+        attrs, valid = _columns(9)
+        bad = lower_request(parse_classad("requirements = other.noSuchAttr > 1;"), VOCAB)
+        ok = lower_request(parse_classad("rank = other.loadFactor; requirements = true;"), VOCAB)
+        rows = [list(range(20))] * 2
+        cm, _, ci, ct = _launch_matches_oracle(attrs, valid, [bad, ok], rows, 2)
+        assert not cm[0].any() and np.isneginf(ct[0]).all()
+        assert cm[1, :20].any() and np.isfinite(ct[1]).all()
+
+    def test_strict_op_boundaries(self):
+        """``x > 5`` excludes exactly 5.0; ``x >= 5`` includes it."""
+        attrs = np.array([[5.0], [np.nextafter(5.0, 6.0, dtype=np.float32)], [4.0]], np.float32)
+        valid = np.ones((3, 1), bool)
+        gt = lower_request(parse_classad("rank = other.x; requirements = other.x > 5;"), TOPK_NAMES)
+        ge = lower_request(parse_classad("rank = other.x; requirements = other.x >= 5;"), TOPK_NAMES)
+        cm, _, ci, ct = _launch_matches_oracle(attrs, valid, [gt, ge], [[0, 1, 2]] * 2, 3)
+        assert cm[0, :3].tolist() == [False, True, False]
+        assert cm[1, :3].tolist() == [True, True, False]
+        assert ci[0, :1].tolist() == [1] and ci[1, :2].tolist() == [1, 0]
+
+    def test_constant_rank_ties_to_lowest_rows(self):
+        """Every score ties: the top-k names the lowest rows, whatever the
+        order the candidate list gives them in."""
+        rng = np.random.default_rng(10)
+        attrs = np.ones((50, len(VOCAB)), np.float32)
+        valid = np.ones((50, len(VOCAB)), bool)
+        plan = lower_request(parse_classad("rank = 7; requirements = true;"), VOCAB)
+        rows = [rng.permutation(50).tolist(), rng.permutation(np.arange(10, 50)).tolist()]
+        _, _, ci, ct = _launch_matches_oracle(attrs, valid, [plan, plan], rows, 4)
+        assert ci.tolist() == [[0, 1, 2, 3], [10, 11, 12, 13]]
+        assert (ct == 7.0).all()
+
+    def test_update_rows_reaches_next_launch(self):
+        """``update_rows`` on a snapshot shows in the next launch over its
+        ``device_columns()``."""
+        attrs, valid = _columns(11)
+        entries = [
+            {"endpoint": f"ep{i:03d}", **{n: float(attrs[i, j]) for j, n in enumerate(VOCAB) if valid[i, j]}}
+            for i in range(S)
+        ]
+        snap = ReplicaSnapshot(entries, VOCAB)
+        plan = lower_request(parse_classad(
+            "rank = other.diskTransferRate; requirements = true;"), snap.vocab_key())
+        rows = [[3, 10, 200]]
+
+        def launch(use_kernel):
+            a, v, n = snap.device_columns()
+            return matchrank_candidates(a, v, [plan], rows, n_rows=n, use_kernel=use_kernel)
+
+        version = snap.version
+        for use_kernel in (True, False):
+            _, _, ti, _ = launch(use_kernel)
+            assert ti[0, 0] != 10 or snap.entries[10].get("disktransferrate") == max(
+                snap.entries[r].get("disktransferrate", 0.0) for r in rows[0])
+        snap.update_rows({10: {"diskTransferRate": 2.0**40}})
+        assert snap.version == version + 1
+        for use_kernel in (True, False):
+            cm, cs, ti, ts = launch(use_kernel)
+            assert ti[0, 0] == 10 and ts[0, 0] == np.float32(2.0**40)
+            assert cs[0, 1] == np.float32(2.0**40)
 
 
 # ------------------------------------------------ the broker's served launch

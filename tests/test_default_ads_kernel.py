@@ -13,7 +13,6 @@ from repro.core.compile import (
     extract_rank_alternatives,
 )
 from repro.kernels.matchrank.ops import lower_request, matchrank_batched, stack_plans
-from repro.kernels.matchrank.sparse import _plan_interval, canonicalize_plans
 from repro.storage.endpoint import build_demo_grid
 
 CLIENT = "client://reader"
@@ -182,7 +181,7 @@ def test_default_ads_match_interpreter(n, seed, shards, use_kernel):
         for rk in ("predicted", "static", "last")
     ]
     queries = [(lfn, ads[i % len(ads)]) for i, lfn in enumerate(lfns)]
-    top_k = 3 if shards else None  # a sharded broker's top-k rides the sparse gate
+    top_k = 3 if shards else None  # a sharded broker's top-k takes the same launch
     got = served.select_many(queries, top_k=top_k, strict=False)
     assert served.stats["batched_kernel_requests"] == len(queries)
     assert served.stats["batched_kernel_guarded_requests"] == len(queries)
@@ -213,9 +212,11 @@ def test_breakers_and_bandwidth_gate_exclude():
 
 # ------------------------------------------------------ shapes and fallbacks
 def test_plan_interval_none_for_guarded_and_chains():
+    """Guarded terms, quotients and rank chains are not ``plain``: the
+    launch counts them as guarded (``batched_kernel_guarded_requests``)."""
     plain = lower_request(parse_classad(
         "rank = other.diskTransferRate; requirements = other.loadFactor < 3"), VOCAB)
-    assert plain.plain and _plan_interval(plain, len(VOCAB)) is not None
+    assert plain.plain
     for ad in (
         default_read_request(CLIENT),  # guarded terms and a chain
         default_read_request(CLIENT, rank="static"),  # guarded terms, a quotient
@@ -225,8 +226,7 @@ def test_plan_interval_none_for_guarded_and_chains():
     ):
         plan = lower_request(ad, VOCAB)
         assert not plan.plain
-        assert _plan_interval(plan, len(VOCAB)) is None
-        assert canonicalize_plans([plain, plan], len(VOCAB)) is None
+        assert stack_plans([plain, plan]).b == 2  # one launch takes both
 
 
 @pytest.mark.parametrize("b", [1, 5, 64])

@@ -1,6 +1,7 @@
 """matchrank Pallas kernel: shape/dtype sweeps vs the pure-jnp oracle,
 plus end-to-end parity with the ClassAd interpreter."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -30,14 +31,14 @@ needs_hypothesis = pytest.mark.skipif(
 from repro.core.classads import parse_classad
 from repro.core.matchmaker import Matchmaker
 from repro.kernels.matchrank.ops import (
+    NO_ROW,
     lower_request,
     matchrank,
     matchrank_batched,
-    matchrank_batched_topk,
+    matchrank_candidates,
     matchrank_topk,
     stack_plans,
 )
-from repro.kernels.matchrank.sparse import canonicalize_plans
 
 NAMES = ["availablespace", "maxrdbandwidth", "avgrdbandwidth", "loadfactor"]
 
@@ -342,114 +343,114 @@ class TestUnknownAttributeEncodings:
             )
             self._check(req, attrs, valid)
 
-class TestSparseTopK:
-    """The rank-order sparse walk must be selection-identical to the
-    dense batched launch (same scores, same lowest-index tie-break)."""
+class TestKernelTopK:
+    """The kernel's in-tile top-k: the per-request carry across S-blocks
+    must give exactly what ``lax.top_k`` gives over the kernel's own
+    scores — score descending, ties to the lowest row, and -inf slots past
+    a request's matches at the lowest unmatched rows."""
 
-    @pytest.mark.parametrize("s", [5, 257, 1000])
-    @pytest.mark.parametrize("k", [1, 3])
-    def test_matches_dense(self, s, k):
-        rng = np.random.default_rng(s * 10 + k)
-        attrs, valid = random_cols(rng, s, invalid_frac=0.15)
-        plans = [lower_request(r, NAMES) for r in REQUEST_BATCH]
-        ti, ts = matchrank_batched_topk(attrs, valid, plans, k=k)
-        _, _, di, ds = matchrank_batched(attrs, valid, plans, k=k, use_kernel=False)
-        matched = ts > -np.inf
-        np.testing.assert_array_equal(ti[matched], np.asarray(di, np.int64)[matched])
-        np.testing.assert_allclose(ts[matched], np.asarray(ds)[matched], rtol=1e-5)
-        # unmatched slots are explicit on the sparse path
-        assert (ti[~matched] == -1).all()
+    S, BLOCK_S = 700, 256  # three S-blocks: the carry crosses two boundaries
+    TIE_S, TIE_K = 200, 5  # one compiled program for every tie-break draw
 
-    def test_admit_premask(self):
-        rng = np.random.default_rng(7)
-        attrs, valid = random_cols(rng, 400, invalid_frac=0.0)
+    @pytest.mark.parametrize("k", [1, 3, 7])
+    def test_kernel_topk_matches_lax_top_k(self, k):
+        rng = np.random.default_rng(k)
+        attrs, valid = random_cols(rng, self.S, invalid_frac=0.1)
+        attrs[:, 2] = np.round(attrs[:, 2] / 25e6) * 25e6  # many exact ties
+        attrs[:, 3] = np.round(attrs[:, 3])
         plans = [lower_request(r, NAMES) for r in REQUEST_BATCH]
-        admit = rng.random((len(plans), 400)) > 0.7
-        ti, ts = matchrank_batched_topk(attrs, valid, plans, k=2, admit=admit)
-        _, _, di, ds = matchrank_batched(
-            attrs, valid, plans, k=2, admit=admit.astype(np.float32), use_kernel=False
+        _, score, ti, ts = matchrank_batched(
+            attrs, valid, plans, k=k, block_s=self.BLOCK_S, use_kernel=True
         )
-        matched = ts > -np.inf
-        np.testing.assert_array_equal(ti[matched], np.asarray(di, np.int64)[matched])
-        for bi in range(len(plans)):
-            got = ti[bi][ti[bi] >= 0]
-            assert admit[bi][got].all()
+        want_s, want_i = jax.lax.top_k(score, k)
+        np.testing.assert_array_equal(ti, np.asarray(want_i))
+        np.testing.assert_array_equal(ts, np.asarray(want_s))
 
-    def test_ne_term_falls_back_to_dense(self):
-        rng = np.random.default_rng(8)
+    def test_padding_columns_and_empty_slots_never_win(self):
+        """Candidate lists shorter than their bucket, with empty slots
+        mixed in: only real candidate rows fill top-k slots."""
+        rng = np.random.default_rng(5)
         attrs, valid = random_cols(rng, 300, invalid_frac=0.0)
-        ne = lower_request(
-            parse_classad(
-                "rank = other.avgrdbandwidth; requirements = other.loadfactor != 3;"
-            ),
+        plan = lower_request(
+            parse_classad("requirements = true; rank = other.loadfactor"), NAMES
+        )
+        rows = [[17, NO_ROW, 250, NO_ROW, 3], [NO_ROW, 99], [NO_ROW] * 4]
+        cm, cs, ti, ts = matchrank_candidates(
+            attrs, valid, [plan] * 3, rows, k=7, block_s=128, use_kernel=True
+        )
+        assert cm.shape == (3, 8)  # the bucket: columns past each list are padding
+        for bi, r in enumerate(rows):
+            real = [x for x in r if x != NO_ROW]
+            np.testing.assert_array_equal(cm[bi, : len(r)], [x != NO_ROW for x in r])
+            assert not cm[bi, len(r) :].any() and np.isneginf(cs[bi, len(r) :]).all()
+            live = ts[bi] > -np.inf
+            assert live.sum() == len(real)
+            want = sorted(real, key=lambda x: -attrs[x, 3])
+            np.testing.assert_array_equal(ti[bi][live], want)
+            assert NO_ROW not in ti[bi].tolist()
+
+    def test_k_above_match_count_leaves_neg_inf_slots(self):
+        rng = np.random.default_rng(6)
+        attrs, valid = random_cols(rng, 300, invalid_frac=0.0)
+        attrs[[5, 41], 3] = 7.0  # refused by the requirement below
+        plan = lower_request(
+            parse_classad("requirements = other.loadfactor < 6; rank = other.avgrdbandwidth"),
             NAMES,
         )
-        assert canonicalize_plans([ne], len(NAMES)) is None
-        ti, ts = matchrank_batched_topk(attrs, valid, [ne], k=1)
-        _, _, di, ds = matchrank_batched(attrs, valid, [ne], k=1, use_kernel=False)
-        np.testing.assert_array_equal(ti, np.asarray(di, np.int64))
-        from repro.core.compile import CompileError
-
-        with pytest.raises(CompileError):
-            matchrank_batched_topk(attrs, valid, [ne], k=1, use_sparse=True)
-
-    def test_absent_attr_never_matches(self):
-        rng = np.random.default_rng(9)
-        attrs, valid = random_cols(rng, 128, invalid_frac=0.0)
-        bad = lower_request(
-            parse_classad("requirements = other.noSuchAttr > 1;"), NAMES
+        rows = [[5, 40, 41, 290], [7]]
+        _, _, ti, ts = matchrank_candidates(
+            attrs, valid, [plan] * 2, rows, k=7, block_s=128, use_kernel=True
         )
-        ok = lower_request(
-            parse_classad("rank = other.loadfactor; requirements = true;"), NAMES
+        _, _, hi, hs = matchrank_candidates(
+            attrs, valid, [plan] * 2, rows, k=7, block_s=128, use_kernel=False
         )
-        ti, ts = matchrank_batched_topk(attrs, valid, [bad, ok], k=2)
-        assert (ti[0] == -1).all() and np.isneginf(ts[0]).all()
-        assert (ti[1] >= 0).all()
+        np.testing.assert_array_equal(ti, hi)
+        np.testing.assert_array_equal(ts, hs)
+        for bi, r in enumerate(rows):
+            matched = [x for x in r if attrs[x, 3] < 6]
+            n = len(matched)
+            assert np.isfinite(ts[bi, :n]).all() and np.isneginf(ts[bi, n:]).all()
+            assert sorted(ti[bi, :n].tolist()) == sorted(matched)
+            # the empty slots name the lowest unmatched rows, as lax.top_k does
+            rest = [x for x in range(300) if x not in matched][: 7 - n]
+            assert ti[bi, n:].tolist() == rest
 
-    def test_strict_op_boundaries(self):
-        # x > 5 must exclude exactly 5.0; x >= 5 must include it
-        attrs = np.array([[5.0], [np.nextafter(5.0, 6.0, dtype=np.float32)], [4.0]],
-                         np.float32)
-        valid = np.ones((3, 1), bool)
-        names = ["x"]
-        gt = lower_request(parse_classad("rank = other.x; requirements = other.x > 5;"), names)
-        ge = lower_request(parse_classad("rank = other.x; requirements = other.x >= 5;"), names)
-        ti, ts = matchrank_batched_topk(attrs, valid, [gt, ge], k=3)
-        assert set(ti[0][ti[0] >= 0].tolist()) == {1}
-        assert set(ti[1][ti[1] >= 0].tolist()) == {0, 1}
+    def _check_against_stable_sort(self, seed):
+        """Eight requests with drawn thresholds and rank columns over a few
+        distinct values: the kernel's top-k must equal a stable descending
+        sort of scores worked out in numpy."""
+        rng = np.random.default_rng(seed)
+        s, k = self.TIE_S, self.TIE_K
+        attrs = rng.choice([0.0, 1.0, 2.0, 3.0], size=(s, 4)).astype(np.float32)
+        valid = rng.random((s, 4)) > 0.1
+        thr = rng.integers(1, 5, size=8)
+        rank_col = rng.integers(1, 3, size=8)  # maxrdbandwidth or avgrdbandwidth
+        plans = [
+            lower_request(
+                parse_classad(
+                    f"requirements = other.loadfactor < {t}; rank = other.{NAMES[c]}"
+                ),
+                NAMES,
+            )
+            for t, c in zip(thr, rank_col)
+        ]
+        _, _, ti, ts = matchrank_batched(
+            attrs, valid, plans, k=k, block_s=128, use_kernel=True
+        )
+        for bi, (t, c) in enumerate(zip(thr, rank_col)):
+            mask = valid[:, 3] & (attrs[:, 3] < t)
+            rank = np.where(valid[:, c], attrs[:, c], np.float32(0.0))
+            score = np.where(mask, rank, np.float32(-np.inf)).astype(np.float32)
+            want = np.argsort(-score, kind="stable")[:k]
+            np.testing.assert_array_equal(ti[bi], want)
+            np.testing.assert_array_equal(ts[bi], score[want])
 
-    def test_tie_break_is_lowest_index(self):
-        # constant rank => every score ties; both paths must pick the
-        # lowest candidate indices, in order
-        attrs = np.ones((50, 4), np.float32)
-        valid = np.ones((50, 4), bool)
-        plan = lower_request(parse_classad("rank = 7; requirements = true;"), NAMES)
-        ti, ts = matchrank_batched_topk(attrs, valid, [plan], k=4)
-        _, _, di, _ = matchrank_batched(attrs, valid, [plan], k=4, use_kernel=False)
-        np.testing.assert_array_equal(ti[0], [0, 1, 2, 3])
-        np.testing.assert_array_equal(np.asarray(di, np.int64)[0], [0, 1, 2, 3])
-        np.testing.assert_allclose(ts[0], 7.0)
+    def test_matches_stable_sort_seeded(self):
+        for seed in range(12):
+            self._check_against_stable_sort(seed)
 
-    def test_snapshot_rank_order_cache(self):
-        from repro.core.snapshot import ReplicaSnapshot
-
-        rng = np.random.default_rng(11)
-        attrs, valid = random_cols(rng, 300, invalid_frac=0.1)
-        entries = []
-        for i in range(300):
-            e = {"endpoint": f"ep{i:04d}"}
-            e.update({n: float(attrs[i, j]) for j, n in enumerate(NAMES) if valid[i, j]})
-            entries.append(e)
-        snap = ReplicaSnapshot(entries, NAMES)
-        la, lv = snap.logical_columns()
-        plans = [lower_request(r, snap.attr_names) for r in REQUEST_BATCH]
-        ti1, ts1 = matchrank_batched_topk(la, lv, plans, k=2, rank_order=snap.rank_order)
-        ti2, ts2 = matchrank_batched_topk(la, lv, plans, k=2)  # uncached order
-        np.testing.assert_array_equal(ti1, ti2)
-        np.testing.assert_allclose(ts1, ts2, rtol=1e-6)
-        # a row update invalidates the cached order and logical columns
-        snap.update_rows({0: {NAMES[2]: 1e12}})
-        la2, lv2 = snap.logical_columns()
-        assert la2[0, 2] == np.float32(1e12)
-        order, svals = snap.rank_order(np.array([0, 0, 1, 0], np.float32))
-        assert order[0] == 0 and svals[0] == np.float32(1e12)
+    @needs_hypothesis
+    @settings(max_examples=20, deadline=None)
+    @given(st.integers(min_value=0, max_value=2**31 - 1))
+    def test_tie_break_property(self, seed):
+        self._check_against_stable_sort(seed)

@@ -242,27 +242,30 @@ class TestAuditTrail:
         statuses = [b.explain(r).plan_cache for r in b.last_request_ids]
         assert statuses[0] == "miss" and set(statuses[1:]) == {"hit"}
 
-    def test_select_many_sparse_topk_audit_and_parity(self):
-        grid, b = _demo_broker()
+    def test_select_many_kernel_topk_audit(self):
+        """A top-k on the kernel path records its tier, its k, and a score
+        for every candidate, not only the k winners."""
+        grid, b = _demo_broker(batch_use_kernel=True)
         lfns = sorted(grid.catalog.logical_files())[:3]
         req = parse_classad(
             "reqdSpace = 0; rank = other.diskTransferRate;"
             "requirements = other.availableSpace > 1M;"
         )
-        queries = [(l, req) for l in lfns]
-        dense = b.select_many(queries, top_k=2)
-        sparse = b.select_many(queries, top_k=2, use_sparse=True)
-        assert b.stats["batched_sparse_requests"] == 3
-        for d, s in zip(dense, sparse):
-            assert [rr.pfn.endpoint for rr in d] == [rr.pfn.endpoint for rr in s]
-            assert [rr.rank for rr in d] == pytest.approx([rr.rank for rr in s])
-        for rid, res in zip(b.last_request_ids, sparse):
+        full = b.select_many([(l, req) for l in lfns])
+        top2 = b.select_many([(l, req) for l in lfns], top_k=2)
+        assert b.stats["batched_kernel_requests"] == 6
+        for rid, f, res in zip(b.last_request_ids, full, top2):
+            assert [rr.pfn.endpoint for rr in res] == [rr.pfn.endpoint for rr in f][:2]
             rec = b.explain(rid)
-            assert rec.kernel_path == "sparse_topk"
+            assert rec.kernel_path == "batched_kernel"
             assert rec.top_k == 2
             assert rec.chosen == res[0].pfn.endpoint
-            matched = [s for s in rec.scores if s.matched]
-            assert 0 < len(matched) <= 2  # sparse records the probed winners
+            assert [sc.endpoint for sc in rec.scores] == rec.candidates
+            ranks = {rr.pfn.endpoint: rr.rank for rr in f}
+            for sc in rec.scores:
+                assert sc.matched == (sc.endpoint in ranks)
+                if sc.matched:
+                    assert sc.rank == pytest.approx(ranks[sc.endpoint])
 
     def test_select_many_interp_tier_audit(self):
         grid, b = _demo_broker()

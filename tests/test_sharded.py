@@ -1,48 +1,17 @@
-"""Sharded GIIS-scale matchmaking (DESIGN.md §9): ShardedSnapshot layout
-and delta refresh, hierarchical top-k parity vs the flat path (tie-break
-included), per-shard result-cache invalidation, the GIIS bridge, and the
-broker's sharded tier end to end."""
+"""Sharded snapshots (DESIGN.md §9): ShardedSnapshot layout and delta
+refresh, its device columns through the candidate launch against a flat
+snapshot of the same rows (tie-break included), the GIIS bridge, and a
+sharded broker end to end against the interpreter."""
 
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-
-    HAVE_HYPOTHESIS = True
-except ImportError:  # degrade: property tests skip, sweeps still run
-    HAVE_HYPOTHESIS = False
-
-    def given(*a, **k):
-        return lambda f: f
-
-    def settings(*a, **k):
-        return lambda f: f
-
-    class _St:
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _St()
-
-needs_hypothesis = pytest.mark.skipif(
-    not HAVE_HYPOTHESIS, reason="hypothesis not installed (requirements-dev.txt)"
-)
-
 from repro.core.classads import parse_classad
 from repro.core.giis import GIIS
 from repro.core.gris import Clock, StorageGRIS
-from repro.core.plancache import PlanCache, request_cache_key
+from repro.core.snapshot import ReplicaSnapshot
 from repro.core.snapshot_sharded import ShardedSnapshot, shard_by_hash
-from repro.kernels.matchrank.ops import lower_request, matchrank_batched_topk
-from repro.kernels.matchrank.ref import merge_topk_ref
-from repro.kernels.matchrank.sharded import (
-    MERGE_K_PAD,
-    merge_topk_pallas,
-    sharded_matchrank_topk,
-    sharded_sparse_topk,
-)
-from repro.kernels.matchrank.sparse import canonicalize_plans
+from repro.kernels.matchrank.ops import lower_request, matchrank_batched
 from repro.storage.endpoint import build_demo_grid
 
 NAMES = ["availablespace", "maxrdbandwidth", "avgrdbandwidth", "loadfactor"]
@@ -54,7 +23,7 @@ REQ_SRCS = [
     "requirements = other.availableSpace > 2G;",
     "rank = other.avgRDBandwidth - other.loadFactor;"
     "requirements = other.loadFactor < 6;",
-    # impossible: exercises the all-(-inf) merge slots
+    # impossible: every top-k slot is -inf
     "rank = other.avgRDBandwidth; requirements = other.loadFactor > 1e30;",
 ]
 
@@ -95,22 +64,29 @@ def make_plans(snap, srcs=REQ_SRCS):
     return [lower_request(parse_classad(src), snap.attr_names) for src in srcs]
 
 
-def flat_topk(snap, plans, k, admit=None):
-    """Flat dense reference (lax.top_k tie-break) over the global rows."""
-    attrs, valid = snap.logical_columns()
-    return matchrank_batched_topk(
-        attrs, valid, plans, k=k, admit=admit, use_sparse=False, use_kernel=False
+def flat_of(snap):
+    """A flat snapshot over the same entries, in the sharded snapshot's
+    global (shard-major) row order and vocabulary."""
+    entries = [e for name in snap.shard_names for e in snap.entries_by_shard[name]]
+    return ReplicaSnapshot(entries, snap.attr_names)
+
+
+def launch(snap, plans, k, *, admit=None, use_kernel=True):
+    """``matchrank_batched`` over a snapshot's ``device_columns()``."""
+    attrs, valid, n = snap.device_columns()
+    return matchrank_batched(
+        attrs, valid, plans, admit=admit, n_rows=n, k=k, use_kernel=use_kernel
     )
 
 
-def assert_topk_equal(got, want):
-    ti_g, ts_g = got
-    ti_w, ts_w = want
-    np.testing.assert_allclose(ts_g, ts_w, rtol=1e-6)
-    live = ~np.isneginf(np.asarray(ts_w))
-    # exact — tie-break contract, not just score parity
-    np.testing.assert_array_equal(np.asarray(ti_g)[live], np.asarray(ti_w)[live])
-    assert (np.asarray(ti_g)[~live] == -1).all()
+def assert_launch_equal(snap, plans, k, **kw):
+    """The sharded snapshot's launch equals the flat snapshot's bit for
+    bit: mask, score and top-k (tie-break included)."""
+    got = launch(snap, plans, k, **kw)
+    want = launch(flat_of(snap), plans, k, **kw)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    return got
 
 
 class TestShardedSnapshot:
@@ -139,12 +115,11 @@ class TestShardedSnapshot:
 
     def test_update_rows_delta_accounting(self):
         snap = ShardedSnapshot(make_shard_entries(200, 4, seed=2))
-        assert snap.pushed_rows == snap.n
+        assert (snap.shard_epochs == 0).all()
         eps0 = snap.shard_epochs.copy()
         # rows 0..4 live in shard 0 only
         changed = snap.update_rows({r: {"loadFactor": 1.5} for r in range(5)})
         assert changed == [0]
-        assert snap.pushed_rows == snap.n + int(snap.counts[0])
         np.testing.assert_array_equal(snap.shard_epochs[1:], eps0[1:])
         assert snap.shard_epochs[0] == eps0[0] + 1
         j = snap.attr_names.index("loadfactor")
@@ -184,16 +159,17 @@ class TestShardedSnapshot:
     def test_refresh_delta_and_structural_errors(self):
         se = make_shard_entries(60, 3, seed=6)
         snap = ShardedSnapshot(se)
-        pushed = snap.pushed_rows
+        eps0 = snap.shard_epochs.copy()
         # identical content ⇒ no shard changes, epoch still rolls
         assert snap.refresh({k: [dict(e) for e in v] for k, v in se.items()}) == []
-        assert snap.epoch == 1 and snap.pushed_rows == pushed
-        # one changed shard ⇒ only it re-uploads
+        assert snap.epoch == 1
+        np.testing.assert_array_equal(snap.shard_epochs, eps0)
+        # one changed shard ⇒ only it is refilled
         name = snap.shard_names[1]
         se2 = {k: [dict(e) for e in v] for k, v in se.items()}
         se2[name][0]["loadfactor"] = 0.125
         assert snap.refresh(se2) == [name]
-        assert snap.pushed_rows == pushed + int(snap.counts[1])
+        np.testing.assert_array_equal(snap.shard_epochs, eps0 + [0, 1, 0])
         # structural changes refuse the delta path
         with pytest.raises(ValueError):
             snap.refresh({k: v for k, v in se2.items() if k != name})
@@ -206,212 +182,65 @@ class TestShardedSnapshot:
         with pytest.raises(ValueError):
             snap.refresh(drift)
 
-    def test_rank_order_cache_per_shard(self):
-        snap = ShardedSnapshot(make_shard_entries(80, 4, seed=7), device=False)
-        w = np.zeros(len(snap.attr_names), np.float32)
-        w[snap.attr_names.index("avgrdbandwidth")] = 1.0
-        before = [snap.shard_rank_order(g, w) for g in range(4)]
-        snap.update_rows({0: {"avgRDBandwidth": 1.0}})  # dirties shard 0 only
-        after = [snap.shard_rank_order(g, w) for g in range(4)]
-        assert after[0][0] is not before[0][0]
-        for g in range(1, 4):
-            assert after[g][0] is before[g][0]  # untouched shards stay cached
-
     def test_shard_by_hash(self):
         buckets = {shard_by_hash(f"gsiftp://ep{i}", 4) for i in range(64)}
         assert buckets <= set(range(4)) and len(buckets) > 1
         assert shard_by_hash("gsiftp://ep0", 4) == shard_by_hash("gsiftp://ep0", 4)
 
 
-class TestHierarchicalTopKParity:
+class TestShardedSnapshotLaunch:
     @pytest.mark.parametrize("g", [1, 3, 8])
     @pytest.mark.parametrize("s", [100, 1000])
-    def test_kernel_path_matches_flat(self, g, s):
+    def test_kernel_launch_matches_flat(self, g, s):
         snap = ShardedSnapshot(make_shard_entries(s, g, seed=g * 31 + s))
-        plans = make_plans(snap)
-        attrs, valid, counts = snap.shard_device_columns()
-        got = sharded_matchrank_topk(
-            attrs, valid, plans, counts=counts, offsets=snap.offsets, k=5
-        )
-        assert_topk_equal(got, flat_topk(snap, plans, 5))
+        mask, _, _, ts = assert_launch_equal(snap, make_plans(snap), 5)
+        assert mask.shape == (len(REQ_SRCS), s) and mask[:3].any(axis=1).all()
+        assert np.isneginf(ts[3]).all()  # the impossible request
 
     @pytest.mark.parametrize("g", [1, 3, 8])
-    def test_sparse_path_matches_flat_s10k(self, g):
+    def test_host_launch_matches_flat_s10k(self, g):
         snap = ShardedSnapshot(make_shard_entries(10_000, g, seed=g), device=False)
-        plans = make_plans(snap)
-        iv = canonicalize_plans(plans, len(snap.attr_names))
-        assert iv is not None
-        shards = [snap.shard_logical_columns(gi) for gi in range(snap.g)]
-        got = sharded_sparse_topk(
-            shards, iv, k=3, offsets=snap.offsets, rank_order=snap.shard_rank_order
-        )
-        assert_topk_equal(got, flat_topk(snap, plans, 3))
+        assert_launch_equal(snap, make_plans(snap), 3, use_kernel=False)
 
     def test_tie_break_exact_on_equal_ranks(self):
-        """Quantized ranks ⇒ many exact ties; both sharded paths must
-        reproduce lax.top_k's lowest-global-row tie-break."""
+        """Quantized ranks ⇒ many exact ties; the top-k must name the lowest
+        global rows, as a stable sort of the scores does."""
         snap = ShardedSnapshot(make_shard_entries(600, 4, seed=11, ties=True))
         plans = make_plans(snap, REQ_SRCS[:1] * 3)
-        want = flat_topk(snap, plans, 8)
-        attrs, valid, counts = snap.shard_device_columns()
-        assert_topk_equal(
-            sharded_matchrank_topk(
-                attrs, valid, plans, counts=counts, offsets=snap.offsets, k=8
-            ),
-            want,
-        )
-        iv = canonicalize_plans(plans, len(snap.attr_names))
-        shards = [snap.shard_logical_columns(gi) for gi in range(snap.g)]
-        assert_topk_equal(
-            sharded_sparse_topk(
-                shards, iv, k=8, offsets=snap.offsets,
-                rank_order=snap.shard_rank_order,
-            ),
-            want,
-        )
+        _, score, ti, ts = assert_launch_equal(snap, plans, 8)
+        for bi in range(len(plans)):
+            want = np.argsort(-score[bi], kind="stable")[:8]
+            np.testing.assert_array_equal(ti[bi], want)
+            np.testing.assert_array_equal(ts[bi], score[bi][want])
 
     def test_admit_mask_parity(self):
         snap = ShardedSnapshot(make_shard_entries(300, 3, seed=12))
-        plans = make_plans(snap)
         rng = np.random.default_rng(0)
-        admit = rng.random((len(plans), snap.n)) > 0.5
-        attrs, valid, counts = snap.shard_device_columns()
-        got = sharded_matchrank_topk(
-            attrs, valid, plans, counts=counts, offsets=snap.offsets, k=4,
-            admit=admit,
-        )
-        assert_topk_equal(got, flat_topk(snap, plans, 4, admit=admit))
+        admit = (rng.random((len(REQ_SRCS), snap.n)) > 0.5).astype(np.float32)
+        mask, _, _, _ = assert_launch_equal(snap, make_plans(snap), 4, admit=admit)
+        assert not mask[admit == 0].any()
 
-    def test_merge_ref_parity_after_delta(self):
-        """merge_kernel=False swaps stage 2 for the NumPy oracle; a delta
-        refresh in between must not leak the previous epoch's rows."""
-        snap = ShardedSnapshot(make_shard_entries(200, 4, seed=13))
+    def test_parity_after_update_rows_and_refresh(self):
+        """A row update and a shard refresh reach the next launch over
+        ``device_columns()``, and leave it equal to a flat snapshot of the
+        new rows."""
+        se = make_shard_entries(200, 4, seed=13)
+        snap = ShardedSnapshot(se)
         plans = make_plans(snap)
-        snap.update_rows({r: {"avgRDBandwidth": 99e6} for r in range(3)})
-        attrs, valid, counts = snap.shard_device_columns()
-        got = sharded_matchrank_topk(
-            attrs, valid, plans, counts=counts, offsets=snap.offsets, k=5,
-            merge_kernel=False,
-        )
-        assert_topk_equal(got, flat_topk(snap, plans, 5))
-
-
-class TestMergeKernel:
-    def _random_candidates(self, b, g, k, seed=0, dead_rows=()):
-        """Per-shard rank-desc candidate lists (ties → lowest index),
-        flattened shard-major — the merge stage's input contract."""
-        rng = np.random.default_rng(seed)
-        scores = np.empty((b, g * k), np.float32)
-        idx = np.empty((b, g * k), np.int32)
-        for bi in range(b):
-            for gi in range(g):
-                s = np.sort(
-                    rng.choice([0.0, 1.0, 2.5, 7.0, 9.0], size=k).astype(np.float32)
-                )[::-1]
-                n_dead = int(rng.integers(0, k + 1))
-                if n_dead:
-                    s[k - n_dead :] = -np.inf
-                scores[bi, gi * k : (gi + 1) * k] = s
-                idx[bi, gi * k : (gi + 1) * k] = gi * 1000 + np.arange(k)
-        for bi in dead_rows:
-            scores[bi, :] = -np.inf
-        return scores, idx
-
-    @pytest.mark.parametrize("k", [1, 3, 7])
-    def test_kernel_matches_ref(self, k):
-        scores, idx = self._random_candidates(6, 5, k, seed=k, dead_rows=(2,))
-        ts_k, ti_k = merge_topk_pallas(scores, idx, k)
-        ts_r, ti_r = merge_topk_ref(scores, idx, k)
-        np.testing.assert_array_equal(ts_k, ts_r)
-        live = ~np.isneginf(ts_r)
-        np.testing.assert_array_equal(np.asarray(ti_k)[live], ti_r[live])
-
-    def test_candidate_axis_padding(self):
-        # C=10 is nowhere near the 128 lane width: padding must be inert
-        scores, idx = self._random_candidates(3, 2, 5, seed=42)
-        ts_k, ti_k = merge_topk_pallas(scores, idx, 5)
-        ts_r, ti_r = merge_topk_ref(scores, idx, 5)
-        np.testing.assert_array_equal(ts_k, ts_r)
-        assert np.asarray(ts_k).shape == (3, 5)
-
-    def test_k_bound(self):
-        scores, idx = self._random_candidates(1, 1, 2)
-        with pytest.raises(AssertionError):
-            merge_topk_pallas(scores, idx, MERGE_K_PAD + 1)
-
-    def test_merge_matches_flat_stable_topk_seeded(self):
-        """Tie-break contract vs a stable flat sort, without hypothesis:
-        shard-major position order == global row order."""
-        for seed in range(20):
-            self._check_against_stable_sort(seed)
-
-    def _check_against_stable_sort(self, seed):
-        rng = np.random.default_rng(seed)
-        n = int(rng.integers(1, 40))
-        g = int(rng.integers(1, 5))
-        k = int(rng.integers(1, 6))
-        flat = rng.choice([-np.inf, 0.0, 1.0, 2.0, 3.0], size=n).astype(np.float32)
-        bounds = np.linspace(0, n, g + 1).astype(int)
-        parts_s, parts_i = [], []
-        for gi in range(g):
-            seg = flat[bounds[gi] : bounds[gi + 1]]
-            order = np.argsort(-seg, kind="stable")[:k]
-            s = np.full(k, -np.inf, np.float32)
-            i = np.zeros(k, np.int32)
-            s[: len(order)] = seg[order]
-            i[: len(order)] = order + bounds[gi]
-            parts_s.append(s)
-            parts_i.append(i)
-        cand_s = np.concatenate(parts_s)[None, :]
-        cand_i = np.concatenate(parts_i)[None, :]
-        ts, ti = merge_topk_ref(cand_s, cand_i, k)
-        want = np.argsort(-flat, kind="stable")[:k]
-        live = ~np.isneginf(ts[0])
-        np.testing.assert_array_equal(ti[0][live], want[live[: len(want)]])
-        np.testing.assert_array_equal(ts[0][live], flat[want][live[: len(want)]])
-
-    @needs_hypothesis
-    @settings(max_examples=60, deadline=None)
-    @given(st.data())
-    def test_merge_tie_break_property(self, data):
-        seed = data.draw(st.integers(min_value=0, max_value=2**31 - 1))
-        self._check_against_stable_sort(seed)
-
-
-class TestPlanCacheShardedInvalidation:
-    def test_topk_epoch_keys(self):
-        pc = PlanCache()
-        pc.topk_put(("q1",), {0: 0, 2: 5}, "v1")
-        pc.topk_put(("q2",), {1: 3}, "v2")
-        hit, val = pc.topk_get(("q1",), [0, 3, 5])
-        assert hit and val == "v1"
-        # shard 1 moves: q2 (touching shard 1) goes stale, q1 survives
-        hit, _ = pc.topk_get(("q2",), [0, 4, 5])
-        assert not hit and pc.stats["topk_stale"] == 1
-        hit, val = pc.topk_get(("q1",), [0, 4, 5])
-        assert hit and val == "v1"
-        # shard 0 moves: now q1 dies too, and is dropped eagerly
-        hit, _ = pc.topk_get(("q1",), [1, 4, 5])
-        assert not hit
-        assert len(pc._topk) == 0
-
-    def test_update_rows_invalidates_only_touched_shards(self):
-        """The end-to-end contract on real snapshot epochs: a delta in one
-        shard must not evict results whose candidates came entirely from
-        other shards."""
-        snap = ShardedSnapshot(make_shard_entries(100, 4, seed=21), device=False)
-        pc = PlanCache()
-        req = parse_classad(REQ_SRCS[0])
-        key_a = ("sharded_topk", "lfnA") + request_cache_key(req, snap.vocab_key())
-        key_b = ("sharded_topk", "lfnB") + request_cache_key(req, snap.vocab_key())
-        pc.topk_put(key_a, {0: int(snap.shard_epochs[0])}, "from-shard-0")
-        pc.topk_put(key_b, {2: int(snap.shard_epochs[2])}, "from-shard-2")
-        row_in_2 = int(snap.offsets[2])
-        assert snap.update_rows({row_in_2: {"loadFactor": 3.0}}) == [2]
-        hit_a, val_a = pc.topk_get(key_a, snap.shard_epochs)
-        hit_b, _ = pc.topk_get(key_b, snap.shard_epochs)
-        assert hit_a and val_a == "from-shard-0"
-        assert not hit_b and pc.stats["topk_stale"] == 1
+        before = launch(snap, plans, 5)
+        assert snap.update_rows({r: {"avgRDBandwidth": 99e9} for r in range(3)}) == [0]
+        name = snap.shard_names[2]
+        se2 = {k: [dict(e) for e in v] for k, v in snap.entries_by_shard.items()}
+        se2[name][0].update(avgrdbandwidth=98e9, loadfactor=1.0)
+        assert snap.refresh(se2) == [name]
+        _, _, ti, ts = assert_launch_equal(snap, plans, 5)
+        assert not np.array_equal(ti, before[2])
+        row = int(snap.offsets[2])
+        j = snap.attr_names.index("avgrdbandwidth")
+        attrs, _, _ = snap.device_columns()
+        assert np.asarray(attrs)[row, j] == np.float32(98e9)
+        # REQ_SRCS[2] admits loadFactor < 6 and ranks by avgRDBandwidth
+        assert row in ti[2].tolist() and np.float32(98e9 - 1.0) in ts[2]
 
 
 class TestGIISBridge:
@@ -440,10 +269,9 @@ class TestGIISBridge:
         clock, giis, states = self._make_giis()
         snap = ShardedSnapshot.from_giis(giis)
         assert snap.shard_names == ["ep0", "ep1", "ep2"]
-        pushed = snap.pushed_rows
-        # nothing moved ⇒ no shard re-uploads
+        # nothing moved ⇒ no shard is refilled
         assert snap.refresh_from_giis(giis) == []
-        assert snap.pushed_rows == pushed
+        assert (snap.shard_epochs == 0).all()
         # one site's dynamic attribute changes after its TTL
         g1, state1 = states[1]
         state1["avail"] = 7.0 * 1024**3
@@ -451,7 +279,7 @@ class TestGIISBridge:
         clock.advance(6)
         changed = snap.refresh_from_giis(giis)
         assert changed == ["ep1"]
-        assert snap.pushed_rows == pushed + int(snap.counts[1])
+        assert snap.shard_epochs.tolist() == [0, 1, 0]
         j = snap.attr_names.index("availablespace")
         attrs, _ = snap.shard_logical_columns(1)
         assert float(attrs[0, j]) == np.float32(7.0 * 1024**3)
@@ -479,6 +307,16 @@ def _urls(ranked):
     return [r.pfn.url for r in ranked]
 
 
+def _ranking(ranked):
+    return [(r.pfn.url, r.rank) for r in ranked]
+
+
+def _assert_same(got, want):
+    assert [u for u, _ in got] == [u for u, _ in want]
+    for (_, x), (_, y) in zip(got, want):
+        assert abs(x - y) <= 1e-6 * max(1.0, abs(y))
+
+
 class TestShardedBroker:
     def test_parity_with_flat_broker(self, grid):
         flat = grid.broker_for("client://host0")
@@ -486,68 +324,66 @@ class TestShardedBroker:
         queries = [(f"f-00{i}", REQ_KERNEL) for i in range(3)]
         want = flat.select_many(queries, top_k=2)
         got = sh.select_many(queries, top_k=2)
-        assert sh.stats["batched_sharded_requests"] == 3
+        assert sh.stats["batched_kernel_requests"] == 3
         for g_, w in zip(got, want):
-            assert _urls(g_) == _urls(w)
-            for x, y in zip(g_, w):
-                assert abs(x.rank - y.rank) <= 1e-6 * max(1.0, abs(y.rank))
+            _assert_same(_ranking(g_), _ranking(w))
 
     def test_audit_records_shards_and_path(self, grid):
         b = grid.broker_for("client://host0", snapshot_shards=4)
         (res,) = b.select_many([("f-000", REQ_KERNEL)], top_k=2)
         rec = b.audit.get(res.request_id)
-        assert rec.kernel_path == "sharded_topk"
-        assert rec.shards  # which corners of the federation answered
-        snap = b._snap_state.snapshot
-        assert rec.shards == sorted(set(rec.shards))
-        assert all(0 <= s < snap.g for s in rec.shards)
+        assert rec.kernel_path == "batched_kernel"
+        assert b.stats["batched_kernel_requests"] == 1
+        assert isinstance(b._snap_state.snapshot, ShardedSnapshot)
+        assert [s.endpoint for s in rec.scores] == rec.candidates
+        assert rec.chosen == res[0].pfn.endpoint
 
-    def test_select_delegates_to_sharded_tier(self, grid):
-        b = grid.broker_for("client://host0", snapshot_shards=4)
-        got = b.select("f-000", REQ_KERNEL, top_k=2)
-        assert b.stats["batched_sharded_requests"] == 1
+    @pytest.mark.parametrize("top_k", [None, 1, 2])
+    def test_select_many_matches_interpreter(self, grid, top_k):
+        """The kernel (interpret mode here) over a sharded snapshot, against
+        the interpreter's select."""
+        b = grid.broker_for("client://host0", snapshot_shards=4, batch_use_kernel=True)
+        ref = grid.broker_for("client://host0")
+        queries = [(f"f-00{i}", REQ_KERNEL) for i in range(3)]
+        got = b.select_many(queries, top_k=top_k)
+        assert b.stats["batched_kernel_requests"] == 3
+        for (lfn, req), res in zip(queries, got):
+            want = _ranking(ref.select(lfn, req))
+            _assert_same(_ranking(res), want[:top_k] if top_k else want)
+
+    def test_select_equals_flat_broker(self, grid):
+        sh = grid.broker_for("client://host0", snapshot_shards=4)
         flat = grid.broker_for("client://host0")
+        got = sh.select("f-000", REQ_KERNEL, top_k=2)
         want = flat.select("f-000", REQ_KERNEL, top_k=2)
-        assert _urls(got) == _urls(want)
+        _assert_same(_ranking(got), _ranking(want))
+        assert sh.explain(got.request_id).kernel_path == "interpreter"
 
-    def test_per_replica_request_skips_delegation(self, grid):
+    def test_per_replica_request_takes_interp(self, grid):
         b = grid.broker_for("client://host0", snapshot_shards=4)
         req = parse_classad(
             "reqdSpace = 0; rank = other.diskTransferRate;"
             "requirements = other.replicaSize > 0;"
         )
-        got = b.select("f-000", req, top_k=2)
-        assert b.stats["batched_sharded_requests"] == 0
-        assert _urls(got)  # still answered (interpreter tier)
+        (res,) = b.select_many([("f-000", req)], top_k=2)
+        assert b.stats["batched_interp_requests"] == 1
+        assert b.stats["batched_kernel_requests"] == 0
+        assert b.explain(res.request_id).kernel_path == "batched_interp"
+        assert _urls(res)  # still answered
 
-    def test_result_cache_hits_and_shard_invalidation(self, grid):
-        b = grid.broker_for("client://host0", snapshot_shards=4)
-        # prime with every lfn so the snapshot spans all 8 endpoints and
-        # f-000's candidates occupy a strict subset of the shards
-        b.select_many([(f"f-00{i}", REQ_KERNEL) for i in range(3)], top_k=2)
-        misses = b.plan_cache.stats["topk_misses"]
-        (res,) = b.select_many([("f-000", REQ_KERNEL)], top_k=2)
-        assert b.plan_cache.stats["topk_hits"] >= 1
-        assert b.plan_cache.stats["topk_misses"] == misses
-        rec = b.audit.get(res.request_id)
+    def test_update_rows_on_one_shard_shows_in_next_flush(self, grid):
+        b = grid.broker_for("client://host0", snapshot_shards=4, batch_use_kernel=True)
+        queries = [(f"f-00{i}", REQ_KERNEL) for i in range(3)]
+        first = b.select_many(queries)
         st = b._snap_state
-        snap = st.snapshot
-        # the cached entry is keyed by every shard holding a *candidate*
-        # replica (a superset of the final contributors in rec.shards)
-        cand_shards = sorted({snap.shard_of_row(st.row_of[u]) for u in rec.candidates})
-        # dirty a shard holding no candidate: still a hit
-        untouched = sorted(set(range(snap.g)) - set(cand_shards))
-        assert untouched, "fixture should leave at least one candidate-free shard"
-        snap.update_rows({int(snap.offsets[untouched[0]]): {"loadFactor": 1.0}})
-        hits = b.plan_cache.stats["topk_hits"]
-        b.select_many([("f-000", REQ_KERNEL)], top_k=2)
-        assert b.plan_cache.stats["topk_hits"] == hits + 1
-        # dirty a candidate shard: the cached result must die
-        row = int(st.row_of[rec.candidates[0]])
-        snap.update_rows({row: {"loadFactor": 1.0}})
-        stale = b.plan_cache.stats["topk_stale"]
-        b.select_many([("f-000", REQ_KERNEL)], top_k=2)
-        assert b.plan_cache.stats["topk_stale"] == stale + 1
+        loser = first[0][-1].pfn.endpoint  # f-000's last-ranked replica
+        assert first[0][0].pfn.endpoint != loser
+        row = st.row_of[loser]
+        shard = st.snapshot.shard_of_row(row)
+        assert st.snapshot.update_rows({row: {"diskTransferRate": 2.0**40}}) == [shard]
+        (res,) = b.select_many([("f-000", REQ_KERNEL)])
+        assert b.explain(res.request_id).snapshot == "reuse"
+        assert res[0].pfn.endpoint == loser and res[0].rank == 2.0**40
 
     def test_snapshot_delta_refresh_across_ttl(self, grid):
         b = grid.broker_for("client://host0", snapshot_shards=4)

@@ -18,7 +18,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.bwstats import ops as bw_ops
 from repro.kernels.matchrank import ops as mr_ops
 from repro.kernels.matchrank import ref as mr_ref
-from repro.kernels.matchrank.sharded import _stage1_sharded, merge_topk_pallas
 
 S, A_PAD, T_PAD, B = 10_240, 128, 16, 64
 
@@ -84,14 +83,22 @@ def _packed_launch(sds, b, c=mr_ops.MIN_CANDIDATES):
     return sds((b, mr_ops._plan_fields(T_PAD, A_PAD)[-1][2] + c), jnp.int32)
 
 
-@pytest.mark.parametrize("k", [1, 4])
-def test_matchrank_batched_compiles(sds, k):
-    """The tier-1 program ``select_many`` launches (B=64 requests)."""
+@pytest.mark.parametrize(
+    "k,c",
+    [
+        pytest.param(1, mr_ops.MIN_CANDIDATES, id="1"),
+        pytest.param(4, mr_ops.MIN_CANDIDATES, id="4"),
+        pytest.param(3, 16, id="3-c16"),
+    ],
+)
+def test_matchrank_batched_compiles(sds, k, c):
+    """The tier-1 program ``select_many`` launches (B=64 requests, ``c``
+    candidate columns, top-``k``)."""
     fn = functools.partial(
         mr_ops._dispatch_batched, t_pad=T_PAD, k=k, block_s=512, use_kernel=True,
         interpret=False,
     )
-    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), _packed_launch(sds, B))
+    _compile(fn, sds((S, A_PAD)), sds((S, A_PAD)), _packed_launch(sds, B, c))
 
 
 def test_matchrank_single_compiles(sds):
@@ -104,23 +111,6 @@ def test_matchrank_single_compiles(sds):
         sds((T_PAD,), jnp.int32), sds((T_PAD,)), sds((T_PAD,)), sds((q, A_PAD)),
         sds((q,)),
     )
-
-
-def test_sharded_stage1_and_merge_compile(sds):
-    """Per-shard kernel vmapped over G=8 shards, then the merge kernel."""
-    g, s_shard, k = 8, 1536, 4
-
-    def two_stage(*args):
-        cand_s, cand_i = _stage1_sharded(
-            *args, k=k, block_s=512, use_kernel=True, interpret=False
-        )
-        return merge_topk_pallas(cand_s, cand_i, k, interpret=False)
-
-    text = _compile(
-        two_stage, sds((g, s_shard, A_PAD)), sds((g, s_shard, A_PAD)),
-        sds((g, B, s_shard)), *_plan_shapes(sds, B), sds((g,), jnp.int32),
-    )
-    assert text.count("tpu_custom_call") >= 2  # stage 1 and the merge
 
 
 def test_bwstats_compiles(sds):
