@@ -32,6 +32,7 @@ The broker executes the three phases of §5.1.2:
 from __future__ import annotations
 
 import math
+import operator
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Mapping, Optional, Protocol, Sequence, Tuple
@@ -51,7 +52,7 @@ from .classads import (
     parse as parse_expr,
 )
 from .gris import Clock, StorageGRIS
-from .ldif import Entry, entry_to_classad
+from .ldif import Entry, entry_to_classad, policy_sources
 from .matchmaker import Matchmaker, MatchResult
 from .transferplan import (
     TransferFailure,
@@ -107,35 +108,85 @@ def _referenced_attrs(expr: Optional[Expr]) -> set:
 _PER_REPLICA_ATTRS = frozenset({"replicapath", "replicasize"})
 
 
+class _AdSlot:
+    """One snapshot row's ClassAd, made from its entry on the first read
+    and kept. A view of the row holds this slot and not its epoch, so a
+    view kept past its epoch keeps only its own row alive."""
+
+    __slots__ = ("entry", "_ad", "_built")
+
+    def __init__(self, entry: Entry, built: Callable[[], None]):
+        self.entry = entry
+        self._ad: Optional[ClassAd] = None
+        self._built = built
+
+    def ad(self) -> ClassAd:
+        if self._ad is None:
+            self._ad = entry_to_classad(self.entry)
+            self._built()
+        return self._ad
+
+
+class _LazyAds:
+    """One epoch's per-row ClassAds: ``ads[r]`` is row ``r``'s ad, made on
+    its first read (:class:`_AdSlot`) and kept for the epoch, so every view
+    of a row shares one ad. Served selections read none: the policy index
+    reads the policy-source column, the launch the snapshot's columns.
+    ``built`` is called once for each ad made."""
+
+    __slots__ = ("_entries", "_slots", "_built")
+
+    def __init__(self, entries: Sequence[Entry], built: Callable[[], None]):
+        self._entries = entries
+        self._slots: List[Optional[_AdSlot]] = [None] * len(entries)
+        self._built = built
+
+    def __len__(self) -> int:
+        return len(self._slots)
+
+    def __getitem__(self, r: int) -> ClassAd:
+        return self.slot(r).ad()
+
+    def slot(self, r: int) -> _AdSlot:
+        s = self._slots[operator.index(r)]
+        if s is None:
+            s = self._slots[r] = _AdSlot(self._entries[r], self._built)
+        return s
+
+
 @dataclass
 class _SnapshotState:
     """The broker's cached view of one published GRIS epoch: tensor
-    snapshot + per-row ads, shared by every selection until it expires."""
+    snapshot, the rows' entries, their usage-policy sources and their ads
+    (made on first read), shared by every selection until it expires."""
 
     snapshot: Any  # core.snapshot.ReplicaSnapshot
     endpoints: Tuple[str, ...]  # row order
     row_of: Dict[str, int]  # endpoint url → row
     entries: List[Entry]
-    ads: List[ClassAd]
+    #: per row, the ``repr`` of its ad's ``requirements``, or None
+    #: (:func:`~repro.core.ldif.policy_sources`)
+    policy_sources: List[Optional[str]]
+    ads: _LazyAds
     table: Any  # core.compile.ColumnTable (f64, live rows)
     built_at: float
-    #: usage-policy source (``repr`` of a row ad's ``requirements``) → the
-    #: rows carrying it; built by the first request lowered against this
-    #: epoch and dropped with the state (:func:`_policy_index`)
+    #: usage-policy source → the rows carrying it; built by the first
+    #: request lowered against this epoch and dropped with the state
+    #: (:func:`_policy_index`)
     policy_index: Optional[Dict[str, Any]] = None
 
 
-def _policy_index(ads: Sequence[ClassAd]) -> Dict[str, Any]:
-    """Group snapshot rows by their usage-policy source: one ``np.intp``
-    row array per distinct ``requirements``. A row carries at most one
-    policy, so the groups are disjoint."""
+def _policy_index(sources: Sequence[Optional[str]]) -> Dict[str, Any]:
+    """Group snapshot rows by their usage-policy source
+    (:func:`~repro.core.ldif.policy_sources`): one ``np.intp`` row array per distinct
+    ``requirements``. A row carries at most one policy, so the groups are
+    disjoint."""
     import numpy as np
 
     groups: Dict[str, List[int]] = {}
-    for r, ad in enumerate(ads):
-        pexpr = ad.lookup_expr("requirements")
-        if pexpr is not None:
-            groups.setdefault(repr(pexpr), []).append(r)
+    for r, src in enumerate(sources):
+        if src is not None:
+            groups.setdefault(src, []).append(r)
     return {src: np.asarray(rows, dtype=np.intp) for src, rows in groups.items()}
 
 
@@ -179,13 +230,35 @@ class AdValidationError(BrokerError):
     unsatisfiable requirements) that would silently distort selection."""
 
 
-@dataclass
 class ReplicaView:
-    """Search-phase product: a replica plus its GRIS-published state."""
+    """Search-phase product: a replica plus its GRIS-published state.
 
-    pfn: PhysicalFile
-    entry: Entry  # flattened GRIS view (volume + bw summary + per-source)
-    ad: ClassAd  # the converted ClassAd (Match Phase step 1)
+    ``entry`` is the flattened GRIS view (volume + bw summary + per-source)
+    and ``ad`` its converted ClassAd (Match Phase step 1). A view of a
+    snapshot row is given the row's :class:`_AdSlot` in place of the ad,
+    which is then made on the first read of ``ad``."""
+
+    __slots__ = ("pfn", "entry", "_ad")
+
+    def __init__(self, pfn: PhysicalFile, entry: Entry, ad: "ClassAd | _AdSlot"):
+        self.pfn = pfn
+        self.entry = entry
+        self._ad = ad
+
+    @property
+    def ad(self) -> ClassAd:
+        ad = self._ad
+        if ad.__class__ is _AdSlot:
+            ad = self._ad = ad.ad()
+        return ad
+
+    def __eq__(self, other) -> bool:
+        if other.__class__ is not ReplicaView:
+            return NotImplemented
+        return (self.pfn, self.entry, self.ad) == (other.pfn, other.entry, other.ad)
+
+    def __repr__(self) -> str:
+        return f"ReplicaView(pfn={self.pfn!r}, entry={self.entry!r}, ad={self.ad!r})"
 
 
 @dataclass
@@ -478,6 +551,8 @@ class DataBroker:
                 ("snapshot_builds", "GRIS snapshot (re)builds"),
                 ("snapshot_reuses", "GRIS snapshot TTL reuses"),
                 ("snapshot_delta_refreshes", "sharded snapshots refreshed in place (dirty shards only)"),
+                ("snapshot_rows", "rows put into a snapshot state, summed over builds and delta refreshes"),
+                ("snapshot_ads_built", "snapshot rows whose ClassAd was made, on its first read"),
                 ("policy_index_builds", "usage-policy row indexes built, one per snapshot epoch lowered against"),
                 ("policy_index_reuses", "requests lowered from an existing policy index"),
                 ("kernel_launches", "stacked kernel launches"),
@@ -730,8 +805,7 @@ class DataBroker:
                 entry.setdefault("endpoint", ep)
                 rows.append(ep)
                 entries.append(entry)
-        with self.tracer.span("broker.snapshot.ads", rows=len(entries)):
-            ads = [entry_to_classad(entry) for entry in entries]
+        sources, ads = self._row_columns(entries)
         prev = st.snapshot if st is not None else None
         with self.tracer.span("broker.snapshot.columns", rows=len(entries)):
             snapshot = (
@@ -745,6 +819,7 @@ class DataBroker:
             endpoints=tuple(rows),
             row_of={ep: i for i, ep in enumerate(rows)},
             entries=entries,
+            policy_sources=sources,
             ads=ads,
             table=table,
             built_at=now,
@@ -752,6 +827,14 @@ class DataBroker:
         self._snap_state = st
         self._ctr["snapshot_builds"].inc()
         return st
+
+    def _row_columns(self, entries: List[Entry]) -> Tuple[List[Optional[str]], _LazyAds]:
+        """A new state's per-row columns: the usage-policy sources, built
+        now (the ``broker.snapshot.ads`` span), and the ads, made on read."""
+        with self.tracer.span("broker.snapshot.ads", rows=len(entries)):
+            sources = policy_sources(entries)
+        self._ctr["snapshot_rows"].inc(len(entries))
+        return sources, _LazyAds(entries, self._ctr["snapshot_ads_built"].inc)
 
     def _shard_name(self, ep: str) -> str:
         """Endpoint → shard name. Zero-padded so sorted(shard names) is
@@ -771,8 +854,8 @@ class DataBroker:
         """Sharded twin of :meth:`_snapshot_state`: endpoints are bucketed
         into per-registrant shards, and a TTL lapse with unchanged
         membership becomes a **delta refresh** — only the shards whose
-        entries changed are refilled and re-converted to ads — instead of a
-        full rebuild (DESIGN.md §9)."""
+        entries changed are refilled — instead of a full rebuild
+        (DESIGN.md §9)."""
         from .snapshot_sharded import ShardedSnapshot
 
         known: List[str] = list(st.endpoints) if st is not None else []
@@ -797,12 +880,14 @@ class DataBroker:
             from .snapshot import ReplicaSnapshot
 
             empty = ReplicaSnapshot([])
+            sources, ads = self._row_columns([])
             st = _SnapshotState(
                 snapshot=empty,
                 endpoints=(),
                 row_of={},
                 entries=[],
-                ads=[],
+                policy_sources=sources,
+                ads=ads,
                 table=empty.table(),
                 built_at=now,
             )
@@ -813,7 +898,6 @@ class DataBroker:
         shard_names = sorted(shard_entries)
         prev = st.snapshot if st is not None else None
         snapshot = None
-        changed: Optional[List[str]] = None
         with self.tracer.span("broker.snapshot.columns", shards=len(shard_names)):
             if (
                 isinstance(prev, ShardedSnapshot)
@@ -824,13 +908,14 @@ class DataBroker:
                 )
             ):
                 try:
-                    changed = prev.refresh(shard_entries)
+                    prev.refresh(shard_entries)
                     snapshot = prev
                 except ValueError:
                     snapshot = None  # vocab/shape drift: fall through to rebuild
-                if snapshot is not None:
-                    self._ctr["snapshot_delta_refreshes"].inc()
-            if snapshot is None:
+            delta = snapshot is not None
+            if delta:
+                self._ctr["snapshot_delta_refreshes"].inc()
+            else:
                 snapshot = ShardedSnapshot(
                     shard_entries, epoch=prev.epoch + 1 if prev is not None else 0
                 )
@@ -838,32 +923,19 @@ class DataBroker:
 
         rows = [ep for nm in shard_names for ep in by_shard[nm]]
         entries = [e for nm in shard_names for e in shard_entries[nm]]
-        with self.tracer.span("broker.snapshot.ads", rows=len(entries)):
-            if changed is not None and st is not None:
-                # delta: re-convert ads only for shards whose entries moved
-                changed_set = set(changed)
-                ads: List[ClassAd] = []
-                pos = 0
-                for nm in shard_names:
-                    cnt = len(shard_entries[nm])
-                    if nm in changed_set:
-                        ads.extend(entry_to_classad(e) for e in shard_entries[nm])
-                    else:
-                        ads.extend(st.ads[pos : pos + cnt])
-                    pos += cnt
-            else:
-                ads = [entry_to_classad(e) for e in entries]
+        sources, ads = self._row_columns(entries)
         st = _SnapshotState(
             snapshot=snapshot,
             endpoints=tuple(rows),
             row_of={ep: i for i, ep in enumerate(rows)},
             entries=entries,
+            policy_sources=sources,
             ads=ads,
             table=table,
             built_at=now,
         )
         self._snap_state = st
-        if changed is None:
+        if not delta:
             self._ctr["snapshot_builds"].inc()
         return st
 
@@ -1008,7 +1080,7 @@ class DataBroker:
                 groups = st.policy_index
                 sp.set(hit=groups is not None)
                 if groups is None:
-                    groups = st.policy_index = _policy_index(st.ads)
+                    groups = st.policy_index = _policy_index(st.policy_sources)
                     self._ctr["policy_index_builds"].inc()
                 else:
                     self._ctr["policy_index_reuses"].inc()
@@ -1181,7 +1253,7 @@ class DataBroker:
         picked = [(r, float(score[j])) for j, r in enumerate(by_row) if mask[j]]
         picked.sort(key=lambda rs: (-rs[1], _row_name(st, rs[0]), rs[0]))
         return [
-            RankedReplica(ReplicaView(by_row[r], st.entries[r], st.ads[r]), sc)
+            RankedReplica(ReplicaView(by_row[r], st.entries[r], st.ads.slot(r)), sc)
             for r, sc in picked
         ]
 

@@ -35,6 +35,7 @@ __all__ = [
     "FilterSyntaxError",
     "entry_to_classad",
     "classad_to_entry",
+    "policy_sources",
 ]
 
 #: An LDAP entry: attribute → value or list of values. ``dn`` is an attribute.
@@ -389,6 +390,39 @@ def entry_to_classad(entry: Mapping[str, Any], *, expr_attrs: Optional[set] = No
         attrs[kl] = e
         spelling[kl] = k
     return ad
+
+
+def policy_sources(entries: Sequence[Mapping[str, Any]]) -> List[Optional[str]]:
+    """Each entry's usage-policy source: the ``repr`` of the ``requirements``
+    expression that ``entry_to_classad(entry)`` carries, or None, without
+    making the ad. The key matches case-insensitively, the last spelling
+    winning, as a ClassAd's keys do; its value is converted alone by
+    ``entry_to_classad``, so the two cannot disagree."""
+    # a grid's rows share a few key sequences and a few policy strings: find
+    # the key's spelling once for each sequence, convert each string once
+    spelling: Dict[Tuple[str, ...], Optional[str]] = {}
+    by_text: Dict[str, str] = {}
+    out: List[Optional[str]] = []
+    for e in entries:
+        keys = tuple(e)
+        if keys in spelling:
+            key = spelling[keys]
+        else:
+            key = spelling[keys] = next(
+                (k for k in reversed(keys) if k.lower() == "requirements"), None
+            )
+        if key is None:
+            out.append(None)
+            continue
+        val = e[key]
+        if val.__class__ is str:
+            src = by_text.get(val)
+            if src is None:
+                src = by_text[val] = repr(entry_to_classad({key: val})["requirements"])
+        else:
+            src = repr(entry_to_classad({key: val})["requirements"])
+        out.append(src)
+    return out
 
 
 def classad_to_entry(ad: ClassAd, *, dn: Optional[str] = None) -> Entry:

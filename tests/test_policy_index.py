@@ -2,13 +2,24 @@
 once for each snapshot state lowered against, reused by every later request
 of that epoch, dropped with the state, and folded into the same admit
 vectors and rankings as the interpreter. A tiny grid with two distinct
-site policies and rows with none, on the CPU."""
+site policies and rows with none, on the CPU.
+
+The index is built from the entries' policy-source column, never from the
+rows' ads: it must equal the index grouped from every row's converted ad,
+on the benchmark's grids and on hand-made entries."""
+
+import importlib.util
+import json
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 import repro.kernels.matchrank.ops as mr_ops
-from repro.core.classads import parse_classad
+from repro.core.broker import _policy_index
+from repro.core.classads import parse as parse_expr, parse_classad
+from repro.core.ldif import entry_to_classad, policy_sources
 from repro.core.matchmaker import Matchmaker
 from repro.obs import Tracer
 from repro.storage.endpoint import build_demo_grid
@@ -177,3 +188,124 @@ def test_republished_policy_is_honoured(shards, monkeypatch):
             assert admit[q, row["gsiftp://ep002"]] == 0.0
     for (lfn, req), res in zip(QUERIES, got):
         _same(res, broker.select(lfn, req))
+
+
+# ------------------------------------------- the index read from the entries
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _bench_grid_module():
+    """The module that makes the benchmark's grids, ``benchmarks/chip/grid.py``."""
+    name = "bench_chip_grid"
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            name, ROOT / "benchmarks" / "chip" / "grid.py"
+        )
+        mod = sys.modules[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    return sys.modules[name]
+
+
+def _index_from_ads(entries):
+    """The index as grouped from every row's converted ad."""
+    groups = {}
+    for r, e in enumerate(entries):
+        pexpr = entry_to_classad(e).lookup_expr("requirements")
+        if pexpr is not None:
+            groups.setdefault(repr(pexpr), []).append(r)
+    return groups
+
+
+def _assert_same_index(got, want):
+    assert list(got) == list(want)  # the same keys, in first-row order
+    for src, rows in want.items():
+        assert got[src].dtype == np.intp
+        assert got[src].tolist() == rows
+
+
+def _bench_broker(config, shards):
+    """A tiny grid of one of the benchmark's configurations."""
+    grid_mod = _bench_grid_module()
+    with open(ROOT / "benchmarks" / "chip" / "configs" / f"{config}.json") as f:
+        cfg = dict(json.load(f), endpoints=48, files=64)
+    world = grid_mod.build_world(cfg, 2**31 + 11)
+    broker = world.grid.broker_for(
+        grid_mod.CLIENT, batch_use_kernel=False, snapshot_shards=shards
+    )
+    broker.warm_snapshot(world.urls)
+    return broker, [(lfn, _request(sp)) for sp in SPACES for lfn in world.lfns[:8]]
+
+
+class _ExprPolicy:
+    """An endpoint's GRIS as the broker reads it, publishing its policy as a
+    parsed ``Expr`` (a GRIS's schema holds a string there)."""
+
+    def __init__(self, gris, policy):
+        self.gris = gris
+        self.policy = parse_expr(policy)
+
+    def flattened_view(self, source=None):
+        return dict(self.gris.flattened_view(source=source), requirements=self.policy)
+
+
+def _hand_made_broker(shards):
+    """The module's grid with a policy under a mixed-case key, one given as
+    an ``Expr``, a row with both spellings, and rows with none."""
+    grid, broker = _grid(shards)
+    eps = grid.endpoints
+    eps["gsiftp://ep002"].gris.set_static("ReQuirements", TIGHT)
+    eps["gsiftp://ep003"].gris.set_static("REQUIREMENTS", "other.reqdSpace <= 4G")
+    stub = _ExprPolicy(eps["gsiftp://ep005"].gris, "other.reqdSpace <= 3G")
+    resolve = broker.gris_resolver
+    broker.gris_resolver = lambda ep: stub if ep == "gsiftp://ep005" else resolve(ep)
+    broker.warm_snapshot(list(eps))
+    return broker, QUERIES
+
+
+@pytest.mark.parametrize("shards", [0, 2])
+@pytest.mark.parametrize("grid", ["fleet15k", "wlcg_federation", "hand_made"])
+def test_index_from_entries_equals_index_from_ads(grid, shards):
+    broker, queries = (
+        _hand_made_broker(shards) if grid == "hand_made" else _bench_broker(grid, shards)
+    )
+    st = broker._snap_state
+    want = _index_from_ads(st.entries)
+    _assert_same_index(_policy_index(st.policy_sources), want)
+    # rows with a policy and rows with none; four distinct policies by hand
+    assert 0 < sum(map(len, want.values())) < len(st.entries)
+    assert len(want) == (4 if grid == "hand_made" else 1)
+    # the served path groups the same column, and makes no ad to do it
+    broker.select_many(queries, strict=False)
+    assert broker._snap_state is st
+    _assert_same_index(st.policy_index, want)
+    assert broker.stats["snapshot_ads_built"] == 0
+
+
+#: one row each: (entry, what the policy-source column reads there)
+HAND_MADE = {
+    "lower_case": ({"requirements": "other.a < 1"}, "(other.a < 1)"),
+    "mixed_case": ({"name": "x", "ReqUirements": "other.a < 1"}, "(other.a < 1)"),
+    "expr": ({"requirements": parse_expr("other.b >= 2")}, "(other.b >= 2)"),
+    "no_policy": ({"name": "x", "availableSpace": 5}, None),
+    "last_spelling_wins": (
+        {"requirements": "other.a < 1", "name": "x", "REQUIREMENTS": "other.b < 2"},
+        "(other.b < 2)",
+    ),
+    "int_value": ({"requirements": 7}, "7"),
+    "none_value": ({"Requirements": None}, "undefined"),
+    # a str subclass is stored as a string literal, not parsed
+    "str_subclass": ({"requirements": type("Text", (str,), {})("other.a < 1")}, "\"other.a < 1\""),
+}
+
+
+@pytest.mark.parametrize("case", list(HAND_MADE))
+def test_policy_source_of_a_row(case):
+    entry, want = HAND_MADE[case]
+    pexpr = entry_to_classad(entry).lookup_expr("requirements")
+    assert (None if pexpr is None else repr(pexpr)) == want
+    assert policy_sources([entry]) == [want]
+
+
+def test_hand_made_entries_index_as_their_ads():
+    entries = [e for e, _ in HAND_MADE.values()] * 2
+    _assert_same_index(_policy_index(policy_sources(entries)), _index_from_ads(entries))
